@@ -14,6 +14,4 @@ __version__ = "0.1.0"
 # NOTE: deliberately NO eager subpackage imports here — the launcher
 # agent (`python -m distributed_pytorch_tpu.launch`) must stay jax-free
 # (it supervises workers; it must never compete with them for chips or
-# import time).  The runtime-compatibility shims (utils/compat.py:
-# shard_map namespace, axis_size/pcast polyfills) load through the
-# jax-facing modules themselves, each of which imports utils.compat.
+# import time).

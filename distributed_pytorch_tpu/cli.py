@@ -38,6 +38,7 @@ from .parallel import init as dist_init
 from .parallel import strategies as _strat
 from .parallel.mesh import make_mesh
 from .train import TrainConfig, Trainer
+from .utils import compile_cache
 from .utils.logging import get_logger, setup_logging
 
 
@@ -288,6 +289,7 @@ def main(argv: list[str] | None = None) -> int:
         except ValueError as e:
             parser.error(str(e))
 
+    compile_cache.enable()
     # Rendezvous FIRST: jax.distributed.initialize must run before anything
     # touches a backend (even jax.process_index()), mirroring the reference's
     # init-before-everything ordering (main_all_reduce.py:96 precedes all

@@ -136,9 +136,11 @@ def main(argv=None) -> int:
 
     from ..models import transformer as tfm
     from ..serve import ContinuousBatcher
+    from ..utils import compile_cache
     from ..utils.logging import get_logger, setup_logging
     from .replica import BatcherReplica
 
+    compile_cache.enable()
     setup_logging()
     log = get_logger("fleet.daemon")
     rid = args.replica_id

@@ -33,7 +33,11 @@ Two deliberate upgrades over the reference's setup:
 - **TPU process model.** On TPU one *process per host* owns all local chips
   (JAX single-controller-per-host), so ``--nproc-per-node`` defaults to 1 and
   values >1 are for CPU simulation/testing, where each worker is given a
-  disjoint slice of fake devices.
+  disjoint slice of fake devices.  Every worker inherits the agent's device
+  environment, so with >1 workers on a TPU host each would claim every
+  chip and all but the first would fail or hang: the agent refuses that
+  shape unless the environment pins workers to the CPU
+  (``JAX_PLATFORMS=cpu``).
 - **Elastic resize** (``--elastic --min-nodes M --max-nodes N``, round 12).
   Restart-at-the-same-size costs the whole gang for one lost member; elastic
   mode makes a worker loss cost a RESHARD instead.  The agent gains
@@ -470,6 +474,16 @@ class LocalAgent:
         self.agent_port = (agent_port if agent_port is not None
                            else master_port + 1)
         self.elastic = elastic
+        if (nproc_per_node > 1 and os.environ.get(
+                "JAX_PLATFORMS", "").strip().lower() != "cpu"):
+            raise ValueError(
+                f"--nproc-per-node {nproc_per_node} needs workers pinned "
+                f"to the CPU (JAX_PLATFORMS=cpu in the agent's "
+                f"environment): workers inherit the agent's device "
+                f"environment, and on an accelerator host each would "
+                f"claim every local chip.  The supported shape there is "
+                f"one worker per host driving all local chips "
+                f"(scripts/start_ddp.sh)")
         if elastic is not None and nnodes > 1:
             raise ValueError(
                 "elastic resize drives one agent's workers (nnodes=1, the "

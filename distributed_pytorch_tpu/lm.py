@@ -2004,9 +2004,8 @@ def make_lm_1f1b_train_step(cfg: LMTrainConfig, mesh: Mesh):
       in SEPARATE accumulators summed once at the end, so the
       association is pp_size-independent): pp_size=N trains
       bitwise-identically to pp_size=1;
-    - no collective is synthesized by autodiff, so the path runs
-      bit-correct even on legacy runtimes without vma cotangent psums
-      (utils/compat.py) — unlike the wave scheduler.
+    - no collective is synthesized by autodiff — unlike the wave
+      scheduler.
 
     ``overlap=True`` unrolls the clock loop and streams: each chunk's
     ZeRO-3 gathers are emitted at its F/B clocks and its gradient sync
@@ -2029,9 +2028,8 @@ def make_lm_1f1b_train_step(cfg: LMTrainConfig, mesh: Mesh):
     the per-(stage, clock) kind is ``axis_index('pp')``-dependent, and
     SPMD control flow cannot skip per-rank (a varying-predicate cond
     executes both sides), while masking is exactly what makes the step
-    one program, bitwise-provable on a CPU mesh, and legacy-runtime
-    safe.  The bubble fraction the inspector reports therefore measures
-    the TIMETABLE (the thing a per-stage-program MPMD runtime would
+    one program, bitwise-provable on a CPU mesh.  The bubble fraction
+    the inspector reports therefore measures the TIMETABLE (the thing a per-stage-program MPMD runtime would
     execute), not this step's executed idle time; the bench A/B
     (bench.py bench_train_pp) compares pp_size=N against pp_size=1
     through this same builder, so both legs pay the same masking tax
@@ -2231,7 +2229,7 @@ def make_lm_1f1b_train_step(cfg: LMTrainConfig, mesh: Mesh):
             acc_l0 = jax.tree.map(jnp.zeros_like, stacked)
         # carries/accumulators mix with pp-varying (and batch-varying)
         # values inside the clock loop: pre-cast them varying so the
-        # scan carry vma is stable (no-op on legacy runtimes)
+        # scan carry vma is stable
         want_vma = compat.vma_of(
             jnp.zeros((), jnp.float32)) | {PP} | compat.vma_of(micro_t)
 

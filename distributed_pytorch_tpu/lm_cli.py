@@ -24,6 +24,7 @@ from .data import lm_corpus
 from .lm import LMTrainConfig, LMTrainer
 from .models import transformer as tfm
 from .parallel import init as dist_init
+from .utils import compile_cache
 from .utils.logging import get_logger, setup_logging
 
 
@@ -382,6 +383,7 @@ def main(argv: list[str] | None = None) -> int:
     elif args.min_nodes != 1 or args.max_nodes is not None:
         parser.error("--min-nodes/--max-nodes configure --elastic; pass "
                      "it (or drop the bounds)")
+    compile_cache.enable()
     if args.rendezvous == "env":
         dist_init.init_from_env()
     else:

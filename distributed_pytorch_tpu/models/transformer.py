@@ -37,9 +37,6 @@ from jax import lax
 from ..ops import attention as attn_ops
 from ..ops import moe as moe_ops
 from ..parallel import context as ctx
-# load the runtime-compat shims (axis_size/pcast polyfills on
-# legacy jax) before anything in this module traces
-from ..utils import compat as _compat  # noqa: F401
 
 Array = jax.Array
 PyTree = Any

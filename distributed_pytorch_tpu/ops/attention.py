@@ -53,6 +53,34 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# A kernel may use 16 MiB of a v5e core's 128 MiB of VMEM unless it asks
+# for more (the compiler's "scoped vmem limit").
+_SCOPED_VMEM_DEFAULT = 16 * 2**20
+
+
+def _decode_compiler_params(q: Array, cache: Array, block_k: int,
+                            quant: bool):
+    """VMEM request of one decode grid step, or None when the default
+    covers it.
+
+    The pipeline double-buffers every operand tile: K and V tiles of
+    (hkv, block_k, D), the (H, D) query and output tiles, and with int8
+    KV two (hkv, 1, block_k) f32 scale tiles; the scratch (f32 acc (H, D)
+    plus m and l (H, 128)) is single.  At 16 kv heads x 512 x 128 the K/V
+    tiles alone are 4 MiB (int8), 8 MiB (bf16) or 16 MiB (f32): the first
+    two fit the default with room for the body's f32 temporaries (2 MiB
+    is asked for them), float32 does not.
+    """
+    h, d, hkv = q.shape[1], q.shape[-1], cache.shape[1]
+    tiles = (2 * hkv * block_k * d * cache.dtype.itemsize
+             + 2 * h * d * q.dtype.itemsize
+             + (2 * hkv * block_k * 4 if quant else 0))
+    need = 2 * tiles + (h * d + 2 * h * 128) * 4 + 2 * 2**20
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
+
+
 # ---------------------------------------------------------------------------
 # Reference attention (the correctness oracle)
 # ---------------------------------------------------------------------------
@@ -439,11 +467,18 @@ def _decode_kernel_body(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
     overhead, not bandwidth, dominates a fine decode grid.
 
     INT8 KV (``ks_ref``/``vs_ref`` given): the cache tiles arrive as int8
-    with per-row scale tiles (hkv, block_k, 1) on the SAME index maps, so
-    the HBM read per step is ~half the bf16 cache's — dequantization
-    (int8 row x its scale, cast back to the query dtype so the MXU dots
-    stay in the compute dtype) happens HERE, in VMEM, never as a dense
-    bf16 materialization on the hot path.
+    with per-row scale tiles on the SAME index maps, so the HBM read per
+    step is ~half the bf16 cache's — dequantization (int8 row x its
+    scale, cast back to the query dtype so the MXU dots stay in the
+    compute dtype) happens HERE, in VMEM, never as a dense bf16
+    materialization on the hot path.  The scale tiles are LANE-DENSE,
+    (hkv, 1, block_k): a (block_k, 1) f32 tile pads its last dim to 128
+    lanes, which at 16 heads x 512 rows is 4 MiB per tile, 16 MiB for
+    K and V double-buffered — over the 16 MiB scoped VMEM limit before
+    the cache tiles are counted — and makes XLA copy the whole scale
+    array into that padded layout on every call.  Each head's (1,
+    block_k) row is transposed to the (block_k, 1) column here; the
+    multiply is the same elementwise product either way.
     """
     j = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -467,7 +502,7 @@ def _decode_kernel_body(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
             kt = k_ref[0, t]                           # (bk, D)
             if ks_ref is not None:
                 kt = (kt.astype(jnp.float32)
-                      * ks_ref[0, t]).astype(qg.dtype)
+                      * ks_ref[0, t].T).astype(qg.dtype)
             rows.append(jax.lax.dot_general(
                 qg, kt, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32))   # (g, bk)
@@ -491,7 +526,7 @@ def _decode_kernel_body(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
             vt = v_ref[0, t]                           # (bk, D)
             if vs_ref is not None:
                 vt = (vt.astype(jnp.float32)
-                      * vs_ref[0, t]).astype(q_ref.dtype)
+                      * vs_ref[0, t].T).astype(q_ref.dtype)
             pg = p[t * g:(t + 1) * g].astype(vt.dtype)
             pv.append(jax.lax.dot_general(
                 pg, vt, (((1,), (0,)), ((), ())),
@@ -594,8 +629,14 @@ def decode_attention(
                 cache_spec(d), cache_spec(d)]
     inputs = [qf, k_cache, v_cache]
     if quant:
-        in_specs += [cache_spec(1), cache_spec(1)]
-        inputs += [k_scale, v_scale]
+        # (B, Hkv, S, 1) -> (B, Hkv, 1, S): a bitcast (XLA keeps S minor
+        # for a trailing-1 array), and the lane-dense tile the kernel wants
+        scale_spec = pl.BlockSpec(
+            (1, hkv, 1, block_k),
+            lambda bb, j, pos_ref: (bb, 0, 0, live_block(bb, j, pos_ref)))
+        in_specs += [scale_spec, scale_spec]
+        inputs += [k_scale.reshape(b, hkv, 1, s),
+                   v_scale.reshape(b, hkv, 1, s)]
     o = pl.pallas_call(
         functools.partial(_decode_kernel_q if quant else _decode_kernel,
                           sm_scale=sm_scale, block_k=block_k, hkv=hkv, g=g),
@@ -612,6 +653,7 @@ def decode_attention(
             ],
         ),
         out_shape=compat.shape_struct((b, h, d), q.dtype, vma=vma),
+        compiler_params=_decode_compiler_params(qf, k_cache, block_k, quant),
         interpret=interpret,
     )(pos_arr, *inputs)
     return o.reshape(b, h, 1, d)
@@ -779,8 +821,14 @@ def decode_attention_paged(
                 pool_spec(d), pool_spec(d)]
     inputs = [qf, k_pool, v_pool]
     if quant:
-        in_specs += [pool_spec(1), pool_spec(1)]
-        inputs += [k_scale, v_scale]
+        # lane-dense scale tiles, as in ``decode_attention``
+        scale_spec = pl.BlockSpec(
+            (1, hkv, 1, page),
+            lambda bb, j, pos_ref, table_ref: (
+                live_page(bb, j, pos_ref, table_ref), 0, 0, 0))
+        in_specs += [scale_spec, scale_spec]
+        inputs += [k_scale.reshape(p_blocks, hkv, 1, page),
+                   v_scale.reshape(p_blocks, hkv, 1, page)]
     o = pl.pallas_call(
         functools.partial(
             _decode_kernel_paged_q if quant else _decode_kernel_paged,
@@ -798,6 +846,7 @@ def decode_attention_paged(
             ],
         ),
         out_shape=compat.shape_struct((b, h, d), q.dtype, vma=vma),
+        compiler_params=_decode_compiler_params(qf, k_pool, page, quant),
         interpret=interpret,
     )(pos_arr, table, *inputs)
     return o.reshape(b, h, 1, d)

@@ -45,9 +45,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
-# load the runtime-compat shims (axis_size/pcast polyfills on
-# legacy jax) before anything in this module traces
-from ..utils import compat as _compat  # noqa: F401
 from ..parallel import routing as _routing
 
 Array = jax.Array

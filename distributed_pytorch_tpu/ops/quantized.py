@@ -12,8 +12,7 @@ transformer's dense projections:
   epilogue dequant is a rank-1 outer product of scales.
 - ``int8_matmul_xla``: the reference path — quantize both operands,
   one ``lax.dot_general`` on int8 with ``preferred_element_type=
-  jnp.int32`` (exact integer arithmetic), dequantize.  This is also
-  the legacy-runtime fallback: every XLA backend lowers int8 dots.
+  jnp.int32`` (exact integer arithmetic), dequantize.
 - ``int8_matmul``: the Pallas TPU kernel — (m, n, k)-tiled grid with k
   innermost, int32 VMEM accumulator, per-row x per-col scale dequant
   in the epilogue of the last k step.  Bitwise-identical to the XLA

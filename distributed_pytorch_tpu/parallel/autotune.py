@@ -118,12 +118,6 @@ _INT4_ROW_OVERHEAD = 0.5 + 1.0 / 64.0  # (0.5 packed + 4/256 scale)/elem
 # that paid for it was not in the model).
 _QUANT_PASSES = {"int8": 2.0, "int4": 4.0}
 
-# The two-level gather-back runs all_gather_invariant where available;
-# legacy runtimes fall back to an embed + full-width psum over the fast
-# axis (strategies.two_level_psum) — the predictor must account bytes
-# for the program THIS runtime actually emits.
-_GATHER_FALLBACK = strat._all_gather_inv is None
-
 
 # ---------------------------------------------------------------------------
 # profiles
@@ -880,8 +874,7 @@ def _two_level_axis_costs(bucket_elems: list[int], n_ici: int, n_dcn: int,
     bytes) of the two-level reduction over the given f32 bucket element
     counts: reduce-scatter over the fast axis, shard exchange over the
     slow one (stock psum or the int8/int4 ring), gather back
-    (all_gather_invariant, or the legacy embed + full-width psum
-    fallback)."""
+    (all_gather_invariant)."""
     ici_bytes = ici_wire = dcn_bytes = dcn_wire = dcn_quant = 0
     ici_launch = dcn_launch = 0
     for e in bucket_elems:
@@ -892,12 +885,8 @@ def _two_level_axis_costs(bucket_elems: list[int], n_ici: int, n_dcn: int,
             ici_bytes += padded * 4
             ici_wire += padded * 4 * (n_ici - 1) // n_ici
             ici_launch += 1
-            if _GATHER_FALLBACK:
-                ici_bytes += padded * 4      # full-width psum fallback
-                ici_wire += 2 * padded * 4 * (n_ici - 1) // n_ici
-            else:
-                ici_bytes += shard * 4       # all_gather of the shard
-                ici_wire += shard * 4 * (n_ici - 1)
+            ici_bytes += shard * 4       # all_gather of the shard
+            ici_wire += shard * 4 * (n_ici - 1)
             ici_launch += 1
         if n_dcn > 1:
             if compress in ("int8", "int4"):
@@ -1159,18 +1148,11 @@ def price_route(route, census: GradCensus, profile: TopologyProfile, *,
                     acc[hi][1] += 1
                     acc[hi][2] += sum(links[a].alpha_s
                                       for a, _ in active) * 1e3
-                    if _GATHER_FALLBACK:
-                        acc[hi][0] += padded * 4
-                        acc[hi][3] += sum(
-                            2 * padded * 4 * (ni - 1) / ni
-                            * links[a].beta_s_per_byte
-                            for a, ni in active) * 1e3
-                    else:
-                        acc[hi][0] += e * 4
-                        acc[hi][3] += sum(
-                            e * 4 * (ni - 1)
-                            * links[a].beta_s_per_byte
-                            for a, ni in active) * 1e3
+                    acc[hi][0] += e * 4
+                    acc[hi][3] += sum(
+                        e * 4 * (ni - 1)
+                        * links[a].beta_s_per_byte
+                        for a, ni in active) * 1e3
                 e = padded
     if intervals:
         # amortize each hop's per-exchange cost over its window: H

@@ -48,9 +48,6 @@ import numpy as np
 from jax import lax
 
 from ..ops.attention import NEG_INF, attention_reference, flash_attention
-# load the runtime-compat shims (axis_size/pcast polyfills on
-# legacy jax) before anything in this module traces
-from ..utils import compat as _compat  # noqa: F401
 
 Array = jax.Array
 

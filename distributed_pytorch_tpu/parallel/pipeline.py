@@ -222,10 +222,7 @@ def num_ticks(m_micro: int, n: int, interleave: int) -> int:
 # autodiff-through-the-scan: lm.py emits one explicit ``jax.vjp`` per
 # (chunk, microbatch) backward unit in timetable order, with every
 # cross-device reduction written out by hand.  That makes the schedule a
-# first-class program property (the thing the inspector measures) — and,
-# operationally, the whole path runs bit-correct even on legacy runtimes
-# whose shard_map lacks automatic cotangent psums (utils/compat.py), which
-# autodiff-era multi-axis LM paths do not.
+# first-class program property (the thing the inspector measures).
 # ---------------------------------------------------------------------------
 
 

@@ -549,15 +549,7 @@ def execute(plan: HopPlan, tree: PyTree, *,
         else:  # 'ag'
             axis, padded_size, n = stack.pop()
             assert axis == hop.axis, "validated plan cannot mismatch"
-            if _strat._all_gather_inv is not None:
-                cur = _strat._all_gather_inv(cur, hop.axis, axis=0,
-                                             tiled=True)
-            else:
-                me = lax.axis_index(hop.axis)
-                chunk = padded_size // n
-                buf = jnp.zeros((padded_size,), cur.dtype)
-                buf = lax.dynamic_update_slice(buf, cur, (me * chunk,))
-                cur = lax.psum(buf, hop.axis)
+            cur = _strat._all_gather_inv(cur, hop.axis, axis=0, tiled=True)
     summed = cur[:total]
     if scale is not None:
         summed = summed * scale

@@ -39,14 +39,8 @@ from typing import Any, Callable, Protocol
 import jax
 import jax.numpy as jnp
 from jax import lax
-# load the runtime-compat shims (axis_size/pcast polyfills on
-# legacy jax) before anything in this module traces
-from ..utils import compat as _compat  # noqa: F401
-
-try:  # provable varying->invariant gather (jax 0.9: not yet re-exported)
-    from jax._src.lax.parallel import all_gather_invariant as _all_gather_inv
-except ImportError:  # pragma: no cover - future jax: use the public name
-    _all_gather_inv = getattr(lax, "all_gather_invariant", None)
+# provable varying->invariant gather (jax 0.9.0 does not re-export it)
+from jax._src.lax.parallel import all_gather_invariant as _all_gather_inv
 
 PyTree = Any
 

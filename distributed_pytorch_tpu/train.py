@@ -304,8 +304,8 @@ def make_multi_step(cfg: TrainConfig, strategy: strat.Strategy,
     This is the TPU-native answer to per-step dispatch overhead: the
     reference's hot loop makes one eager dispatch per op (SURVEY.md 3.1);
     the single-step path here makes one per step; this makes one per K
-    steps, which matters when the host link has real latency (tunneled or
-    multi-host setups).  RNG per step is ``fold_in(key, step0 + i)`` —
+    steps, which matters when a step is short next to a dispatch and its
+    loss fetch.  RNG per step is ``fold_in(key, step0 + i)`` —
     identical to the single-step path's stream, so loss curves match
     exactly regardless of steps_per_loop.
     """
@@ -647,9 +647,9 @@ def make_multi_step(cfg: TrainConfig, strategy: strat.Strategy,
             # PROVES invariance to the vma checker (a few scalar psums,
             # excluded from the schedule pins by their min_bytes
             # filter).  mets may arrive vma-INVARIANT (derived from
-            # post-psum grads and updated params), and modern runtimes
-            # reject reducing an invariant value — cast varying first
-            # (pass-through where already varying, no-op on legacy).
+            # post-psum grads and updated params), and the runtime
+            # rejects reducing an invariant value — cast varying first
+            # (pass-through where already varying).
             return (params, new_state, opt_state, new_sync,
                     jax.lax.pmean(losses, data_axes),
                     jax.lax.pmean(oks, data_axes),
@@ -961,15 +961,7 @@ class Trainer:
                     self._multi_fn = make_multi_step(
                         self.cfg, self.strategy, self.mesh,
                         fault_sig=self._fault_sig)
-                if compat.AOT_EXECUTION_SAFE:
-                    exe = self._multi_fn.lower(*args).compile()
-                else:
-                    # old runtimes abort EXECUTING a cache-loaded AOT
-                    # executable (utils/compat.py) — run through jit
-                    # there; compile then lands inside the first timed
-                    # step (a metrics skew on legacy hosts, not a
-                    # correctness loss)
-                    exe = self._multi_fn
+                exe = self._multi_fn.lower(*args).compile()
                 self._compiled[key] = exe
             if self._vma_opaque:
                 # new executable, no static vma proof: re-verify

@@ -11,8 +11,8 @@ only attribute-style access routes through ``__getattr__``.
 
 import importlib
 
-_SUBMODULES = ("checkpoint", "compat", "debug", "faults", "logging",
-               "metrics", "sentry", "telemetry", "tracing")
+_SUBMODULES = ("checkpoint", "compat", "compile_cache", "debug", "faults",
+               "logging", "metrics", "sentry", "telemetry", "tracing")
 
 __all__ = list(_SUBMODULES)
 

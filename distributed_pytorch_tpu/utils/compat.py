@@ -1,133 +1,45 @@
-"""JAX version compatibility: run the modern API on older runtimes.
-
-The framework targets current JAX (``jax.shard_map``, the vma/pcast
-varying-axis machinery, ``ShapeDtypeStruct(vma=...)``).  Older runtimes
-(< 0.6) ship ``shard_map`` under ``jax.experimental`` with ``check_rep``
-instead of ``check_vma`` and have no vma tracking at all.  Robustness
-policy (ISSUE 1): degrade gracefully instead of failing at import — a
-worker that cannot even ``import train`` cannot run ANY recovery path.
-
-What degrades where:
-
-- ``shard_map``: the experimental fallback maps ``check_vma`` to
-  ``check_rep=False`` (the old checker predates the vma rules the
-  framework's collectives are written against; numerics are unchanged,
-  only the static replication proof is off — the same trade the
-  ``vma_opaque`` strategies already make deliberately).
-- ``vma_of`` / ``pcast``: without vma tracking, every array reports an
-  empty vma set and pcast is the identity — callers' "make varying"
-  bookkeeping becomes a no-op, which is exactly the old semantics.
-- ``shape_struct``: drops the ``vma=`` kwarg when unsupported.
+"""Short names for the JAX calls the framework's SPMD code is written
+against (``jax.shard_map``, the vma/pcast varying-axis machinery), the
+buffer-donation helper, and the differentiable fusion barrier.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
 
-try:  # modern: top-level shard_map with check_vma
-    from jax import shard_map as _shard_map
-    _MODERN_SHARD_MAP = True
-except ImportError:  # pragma: no cover - exercised only on old runtimes
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _MODERN_SHARD_MAP = False
+shard_map = jax.shard_map
+pcast = jax.lax.pcast
 
-HAS_VMA = hasattr(jax, "typeof")
-
-# Old runtimes (<= 0.4.x) heap-corrupt EXECUTING a train-step executable
-# deserialized from the persistent compilation cache when its inputs are
-# DONATED ("corrupted double-linked list" aborts on the warm-cache run:
-# the loaded executable's input-output aliasing frees buffers it does
-# not own).  Donation and AOT execution consult these flags and degrade
-# on legacy runtimes — donation off costs transient memory, jit-instead-
-# of-AOT moves compile time into the first timed step; neither costs
-# correctness, and the persistent cache stays on for the compile-bound
-# test suite.
-#
-# Donation sites that consult DONATION_SAFE (via ``donate``): the train
-# steps (train.py, lm.py), and serve.py's whole decode hot path — the
-# lockstep block (KV cache + the device-side carry the overlapped
-# dispatch chains on), the speculative block (cache + its staging dict,
-# whose (slots, kv_len) stream buffer is rebuilt every dispatch), the
-# suffix-prefill/chunk/insert/scatter cache writers.  Without donation,
-# each of those dispatches copies the full paged pool per call.
-#
-# JAX_GRAFT_FORCE_DONATION=1/0 overrides the runtime detection — for
-# A/B-measuring donation's effect on hardware, or re-testing the legacy
-# corruption after a runtime upgrade.  When forcing ON where
-# DONATION_SAFE would be False, disable the persistent compilation
-# cache first (that combination IS the corruption).
-AOT_EXECUTION_SAFE = _MODERN_SHARD_MAP
-DONATION_SAFE = _MODERN_SHARD_MAP
-_force = os.environ.get("JAX_GRAFT_FORCE_DONATION")
-if _force is not None:  # pragma: no cover - operator escape hatch
-    DONATION_SAFE = _force.strip().lower() not in ("0", "", "false")
+# Donation sites (via ``donate``): the train steps (train.py, lm.py), and
+# serve.py's whole decode hot path — the lockstep block (KV cache + the
+# device-side carry the overlapped dispatch chains on), the speculative
+# block (cache + its staging dict, whose (slots, kv_len) stream buffer is
+# rebuilt every dispatch), the suffix-prefill/chunk/insert/scatter cache
+# writers.  Without donation, each of those dispatches copies the full
+# paged pool per call.  tests/test_serve.py flips this constant to pin
+# that donation changes no stream.
+DONATION_SAFE = True
 
 
 def donate(*argnums: int) -> tuple:
-    """``donate_argnums`` value honoring DONATION_SAFE: the given indices
-    on modern runtimes, empty (no donation) on legacy ones."""
+    """``donate_argnums`` value: the given indices, or none when a test
+    has turned ``DONATION_SAFE`` off."""
     return tuple(argnums) if DONATION_SAFE else ()
 
 
-if _MODERN_SHARD_MAP:
-    shard_map = _shard_map
-else:  # pragma: no cover - exercised only on old runtimes
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        # check_rep=False: the legacy replication checker predates the
-        # vma rules (psum-of-lists, custom_vjp sync points) and rejects
-        # valid modern programs; correctness is unaffected.
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
 def vma_of(x) -> frozenset:
-    """The array's varying mesh axes (empty set when untracked)."""
-    if HAS_VMA:
-        return jax.typeof(x).vma
-    return frozenset()
-
-
-def pcast(x, axes, to: str = "varying"):
-    """``jax.lax.pcast`` where it exists; identity on untracked runtimes."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to=to)
-    return x  # pragma: no cover - exercised only on old runtimes
+    """The array's varying mesh axes."""
+    return jax.typeof(x).vma
 
 
 def shape_struct(shape, dtype, vma=None):
-    """``ShapeDtypeStruct`` carrying vma only where supported."""
-    if HAS_VMA and vma is not None:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-if not hasattr(jax.lax, "axis_size"):  # pragma: no cover - old runtimes
-    # Polyfill via the classic idiom (psum of a unit constant folds to
-    # the axis size at trace time).  Installed onto jax.lax so the many
-    # call sites need no edits; the package __init__ imports this module
-    # first, so the polyfill is in place before any trace runs.
-    def _axis_size(axis):
-        return jax.lax.psum(1, axis)
-
-    jax.lax.axis_size = _axis_size
-
-if not hasattr(jax.lax, "pcast"):  # pragma: no cover - old runtimes
-    # Identity: legacy runtimes have no vma tracking, so "cast to
-    # varying" has nothing to record.  Collective semantics are
-    # unchanged (the legacy shard_map runs check_rep=False here).
-    def _pcast(x, axes, to="varying"):
-        return x
-
-    jax.lax.pcast = _pcast
+    """``ShapeDtypeStruct`` carrying ``vma`` (None = untracked)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 # -- differentiable fusion barrier (round 10) ------------------------------
 #
-# ``lax.optimization_barrier`` has no autodiff rule on legacy runtimes
-# (NotImplementedError under vjp on 0.4.37), and even where it does, the
-# pipeline chunk body needs the barrier on BOTH passes: the cotangent
+# The pipeline chunk body needs the barrier on BOTH passes: the cotangent
 # chain must get the same compilation boundary as the primal, or the
 # unrolled-backward fusion drifts exactly like the forward one.  The
 # custom_vjp below is the one definition of "identity that XLA may not

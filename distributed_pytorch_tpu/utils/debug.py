@@ -44,6 +44,10 @@ COLLECTIVE_PRIMS = frozenset({
     "psum", "psum2", "pmax", "pmin", "ppermute", "pshuffle", "all_gather",
     "all_to_all", "psum_scatter", "reduce_scatter",
 })
+# jax 0.9.0 binds psum and the two-level gather-back under the names of
+# their vma-typed forms; the schedule keeps the names the pins use.
+_PRIM_ALIASES = {"psum_invariant": "psum",
+                 "all_gather_invariant": "all_gather"}
 
 
 def _eqn_axes(eqn) -> tuple:
@@ -111,6 +115,7 @@ def jaxpr_schedule(jaxpr) -> list[dict]:
     def walk(j, trips: int):
         for eqn in j.eqns:
             name = eqn.primitive.name
+            name = _PRIM_ALIASES.get(name, name)
             if name in COMPUTE_PRIMS:
                 sched.append({"kind": "compute", "prim": name,
                               "axes": (), "bytes": _eqn_bytes(eqn),
@@ -334,12 +339,11 @@ class ConsistencyError(AssertionError):
 
 # Route-grammar hop operations (parallel/routing.Hop.describe()'s part
 # after the ":", bracket suffix stripped) -> the jaxpr primitives that
-# hop lowers to.  "ag" lists psum too: the legacy-runtime gather
-# fallback emits a masked psum instead of all_gather (strategies.py).
+# hop lowers to.
 _HOP_OP_PRIMS = {
     "rs": ("psum_scatter", "reduce_scatter"),
     "slice": (),            # local dynamic_slice — no collective
-    "ag": ("all_gather", "psum"),
+    "ag": ("all_gather",),
     "psum": ("psum", "psum2"),
     "ring": ("ppermute",),
     # round 21: the expert dispatch/combine exchange ('expert:a2a@bits'
@@ -549,8 +553,6 @@ def assert_pipeline_schedule(clocks_or_step, *, n_stages: int,
 
 
 def _leaf_paths(tree: PyTree):
-    # tree_util spelling: present on every supported runtime (the
-    # jax.tree.flatten_with_path alias arrived later than 0.4.x)
     leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
     for path, leaf in leaves:
         yield jax.tree_util.keystr(path), leaf

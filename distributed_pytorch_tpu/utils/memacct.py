@@ -41,10 +41,8 @@ from typing import Any, Callable
 
 import numpy as np
 
-try:  # public on modern runtimes
-    from jax.ad_checkpoint import saved_residuals as _saved_residuals
-except ImportError:  # 0.4.x exposes it under _src only
-    from jax._src.ad_checkpoint import saved_residuals as _saved_residuals
+# jax 0.9.0 exposes it under _src only
+from jax._src.ad_checkpoint import saved_residuals as _saved_residuals
 
 F32 = 4  # bytes; items the stack keeps in f32 regardless of compute dtype
 I32 = 4

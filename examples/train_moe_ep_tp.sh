@@ -3,9 +3,7 @@
 # own 'expert' mesh axis (all_to_all rides it), each expert's FFN width is
 # additionally tensor-sharded, and the batch splits over (data, expert).
 # Needs dp*ep*tp = 8 devices: a pod slice, or a virtual CPU mesh
-# (JAX_PLATFORMS=cpu + the XLA_FLAGS below; note some TPU plugins force
-# their platform via jax.config, in which case set it from Python — see
-# tests/conftest.py).
+# (JAX_PLATFORMS=cpu + the XLA_FLAGS below).
 cd "$(dirname "$0")/.." || exit 1
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 JAX_PLATFORMS=cpu \

@@ -719,8 +719,7 @@ def test_gang_kill_shrink_resume_rejoin_grow(tmp_path, monkeypatch):
 
     Members are single-process-jax workers whose mesh spans WORLD_SIZE
     local fake devices (see resize_worker.py: the exact layout a real
-    gang writes, with bitwise-replica trajectories) — the form of
-    multi-process gang this legacy CPU runtime can actually run."""
+    gang writes, with bitwise-replica trajectories)."""
     import shutil
 
     worker = os.path.join(REPO, "tests", "workers", "resize_worker.py")

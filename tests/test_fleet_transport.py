@@ -35,7 +35,8 @@ from distributed_pytorch_tpu.fleet import (BatcherReplica,
 from distributed_pytorch_tpu.fleet import transport as tp
 from distributed_pytorch_tpu.models import transformer as tfm
 from distributed_pytorch_tpu.serve import ContinuousBatcher
-from distributed_pytorch_tpu.utils import faults, monitor, telemetry
+from distributed_pytorch_tpu.utils import (compile_cache, faults, monitor,
+                                           telemetry)
 
 pytestmark = pytest.mark.fleet
 
@@ -53,11 +54,7 @@ SPEC = {"cfg": CFG_KW, "seed": 0,
 
 # daemons are fresh processes: hand them the suite's persistent compile
 # cache (conftest sets it via jax.config, which does NOT cross exec)
-DAEMON_ENV = {
-    "JAX_COMPILATION_CACHE_DIR": os.path.join(
-        os.path.dirname(__file__), ".jax_cache"),
-    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0.5",
-}
+DAEMON_ENV = compile_cache.child_env(min_compile_secs=0.5)
 
 
 @pytest.fixture(scope="module")

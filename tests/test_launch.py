@@ -36,6 +36,18 @@ def test_worker_specs_env_contract():
     assert env["NODE_RANK"] == "2"
 
 
+def test_several_workers_need_the_cpu_pin(monkeypatch):
+    """Workers inherit the agent's device environment: on an accelerator
+    host each of several would claim every chip, so that shape is refused
+    up front unless the environment pins workers to the CPU."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(ValueError, match="pinned to the CPU"):
+        LocalAgent(["x.py"], nproc_per_node=2, log=_quiet)
+    LocalAgent(["x.py"], nproc_per_node=1, log=_quiet)  # one per host: fine
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    LocalAgent(["x.py"], nproc_per_node=2, log=_quiet)
+
+
 def test_gang_success_and_env_propagation(tmp_path):
     out = tmp_path / "ranks"
     out.mkdir()

@@ -167,14 +167,7 @@ def test_execute_two_level_bitwise_vs_hand_built_lax():
         shard = lax.psum_scatter(padded, "ici", scatter_dimension=0,
                                  tiled=True)
         shard = lax.psum(shard, "dcn")
-        if strat._all_gather_inv is not None:
-            full = strat._all_gather_inv(shard, "ici", axis=0, tiled=True)
-        else:
-            buf = jnp.zeros((padded.size,), shard.dtype)
-            me = lax.axis_index("ici")
-            buf = lax.dynamic_update_slice(buf, shard,
-                                           (me * shard.size,))
-            full = lax.psum(buf, "ici")
+        full = strat._all_gather_inv(shard, "ici", axis=0, tiled=True)
         return ((full[:flat.size] * (1.0 / 8))
                 .reshape(x.shape).astype(x.dtype))
 

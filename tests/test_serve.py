@@ -1284,10 +1284,7 @@ def test_overlap_zero_recompiles(params, kv_dtype):
 def test_overlap_donation_on_off_bitwise(params, monkeypatch):
     """Satellite pin: the clean (greedy f32) serving path is bitwise
     identical with buffer donation forced ON vs OFF — donation is a
-    memory optimization, never a numerics change.  The persistent
-    compilation cache is disabled while donation is forced: legacy
-    runtimes heap-corrupt EXECUTING cache-loaded donated executables
-    (utils/compat.py), and this test must be safe everywhere."""
+    memory optimization, never a numerics change."""
     from distributed_pytorch_tpu.utils import compat
 
     prompts = _ragged_workload(35, 3)
@@ -1300,13 +1297,8 @@ def test_overlap_donation_on_off_bitwise(params, monkeypatch):
 
     monkeypatch.setattr(compat, "DONATION_SAFE", False)
     off = run()
-    cache_dir = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        monkeypatch.setattr(compat, "DONATION_SAFE", True)
-        on = run()
-    finally:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    monkeypatch.setattr(compat, "DONATION_SAFE", True)
+    on = run()
     assert set(on) == set(off)
     for rid in off:
         np.testing.assert_array_equal(on[rid], off[rid])

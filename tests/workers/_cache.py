@@ -1,18 +1,12 @@
 """Shared compile-cache setup for worker subprocesses.
 
-Workers are fresh processes: without pointing them at the suite's
-persistent XLA compilation cache, every integration-test run recompiles
-from scratch (the one-core host makes that the dominant cost).  Mirrors
-tests/conftest.py's settings; call after ``import jax``.
+Workers are fresh processes: without the suite's persistent XLA
+compilation cache, every integration-test run recompiles from scratch.
+Mirrors tests/conftest.py's settings.
 """
 
-import os
+from distributed_pytorch_tpu.utils import compile_cache
 
 
-def enable_compile_cache(jax) -> None:
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+def enable_compile_cache() -> None:
+    compile_cache.enable(min_compile_secs=0.5)

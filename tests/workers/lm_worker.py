@@ -19,7 +19,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 from _cache import enable_compile_cache  # noqa: E402 (same dir)
 
-enable_compile_cache(jax)
+enable_compile_cache()
 
 import numpy as np  # noqa: E402
 

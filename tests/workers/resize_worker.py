@@ -1,10 +1,7 @@
 """Worker for the GANG-LEVEL elastic-resize test (test_elastic.py):
 kill -> shrink -> resume resharded -> rejoin -> grow back.
 
-Gang model (the repo's CPU-simulation idiom, runnable on EVERY runtime
-— legacy 0.4.37 CPU cannot run cross-process jax collectives at all,
-which is why the pre-existing multi-process gang tests fail
-environmentally there): each member is a single-process jax worker that
+Gang model (the repo's CPU-simulation idiom): each member is a single-process jax worker that
 builds its mesh over ``WORLD_SIZE`` local fake devices — the exact mesh
 shape, batch split, and checkpoint LAYOUT a real WORLD_SIZE-member gang
 produces — and trains the canonical global batch.  A correctly
@@ -49,7 +46,7 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 from _cache import enable_compile_cache  # noqa: E402 (same dir)
 
-enable_compile_cache(jax)
+enable_compile_cache()
 
 import time  # noqa: E402
 
