@@ -216,6 +216,27 @@ def _moe_dense(lp: PyTree, h: jax.Array, cfg: tfm.TransformerConfig,
     return out.reshape(b, s, d)
 
 
+def _paged_put(leaf: jax.Array, pids: jax.Array, offs: jax.Array,
+               u: jax.Array) -> jax.Array:
+    """``leaf.at[pids, :, offs].set(u)`` for a (P, hkv, page, W) pool
+    leaf, page ids and row offsets of one shape (...) and rows ``u`` of
+    shape (..., hkv, W): row (pids[i], h, offs[i]) gets u[i, h].
+
+    Written as a scatter along the two LEADING dimensions of the
+    (P * hkv, page, W) view: the TPU compiler then keeps the pool
+    row-major, the layout the paged decode kernel reads, and updates it in
+    place.  A scatter over dimensions 0 and 2 of the leaf itself makes it
+    keep the pool pages, rows, heads, and copy every leaf whole into the
+    kernel's layout on every decode step (tests/test_chip_compile.py).
+    A page id outside the pool is dropped all the same (pid * hkv + h is
+    then outside the view), and duplicates (idle slots all write scratch
+    page 0) land in any order."""
+    n_pages, hkv, page, w = leaf.shape
+    rows = pids[..., None] * hkv + jnp.arange(hkv)
+    return (leaf.reshape(n_pages * hkv, page, w)
+            .at[rows, offs[..., None]].set(u).reshape(leaf.shape))
+
+
 def _forward_cached(params: PyTree, cache: PyTree, tokens: jax.Array,
                     pos: jax.Array, write_at, *,
                     cfg: tfm.TransformerConfig, dtype=None,
@@ -348,8 +369,8 @@ def _forward_cached(params: PyTree, cache: PyTree, tokens: jax.Array,
                 offs = write_at % page
 
                 def put(leaf, u):
-                    return leaf.at[pids, :, offs].set(
-                        u.transpose(0, 2, 1, 3))
+                    return _paged_put(leaf, pids, offs,
+                                      u.transpose(0, 2, 1, 3))
             else:
                 bidx = jnp.arange(tokens.shape[0])[:, None]
 
@@ -366,7 +387,7 @@ def _forward_cached(params: PyTree, cache: PyTree, tokens: jax.Array,
             offs = p_now % page
 
             def put(leaf, u):
-                return leaf.at[pids, :, offs].set(u[:, :, 0])
+                return _paged_put(leaf, pids, offs, u[:, :, 0])
         elif ragged:
             # per-sequence write offsets (vmapped update -> scatter)
             def put(leaf, u):

@@ -15,6 +15,7 @@ described (no TPU compiler installed).
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 # no chip is opened here, so several test processes (xdist workers) may
@@ -23,12 +24,15 @@ os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from distributed_pytorch_tpu.models import transformer as tfm
 from distributed_pytorch_tpu.ops import attention as att
 from distributed_pytorch_tpu.ops import quantized
+from distributed_pytorch_tpu.serve import ContinuousBatcher
 
 # the d2048 LM's attention: 16 heads x 128, batch 4 at sequence 2048
 B, H, D, SEQ = 4, 16, 128, 2048
@@ -124,3 +128,97 @@ def test_int8_matmul_compiles(chip):
         chip, lambda x, w: quantized.int8_matmul(x, w, interpret=False),
         ((8192, 2048), jnp.bfloat16), ((2048, 8192), jnp.bfloat16))
     assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+# the chat cell's server (benchmarks/deployments/paged_64x4096.json) at
+# ERNIE-4.5-0.3B's widths, two layers of it
+POOL = (513, 2, 512, 128)
+
+
+class _Captured(Exception):
+    pass
+
+
+def _decode_block_hlo(chip, monkeypatch, width, kv_dtype):
+    """Optimized HLO of the batcher's own decode block at ``width`` slots
+    over the cell's pool, compiled for the chip.  The block's arguments
+    are taken from a dispatch of a small-pool batcher (one request,
+    admitted inside the block, so nothing else is compiled or run)."""
+    # the kernel asks the backend, which is the CPU here
+    monkeypatch.setattr(att, "_interpret_default", lambda: False)
+    cfg = tfm.TransformerConfig(vocab_size=103424, d_model=1024, n_layers=2,
+                                n_heads=16, head_dim=POOL[3],
+                                n_kv_heads=POOL[1], d_ff=3072)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: tfm.init(jax.random.key(0), cfg)))
+    cb = ContinuousBatcher(params, cfg, slots=width, max_len=4096, paged=True,
+                           pool_pages=9, prompt_buckets=(64, 2048),
+                           dtype=jnp.bfloat16, kv_dtype=kv_dtype, eos_id=None,
+                           compact_tail=False)
+    block = cb._decode_for(width)
+
+    def capture(*args):
+        raise _Captured(args)
+
+    cb._decode_fns[width] = capture
+    cb.submit(np.arange(4, dtype=np.int32), max_new=4)
+    assert cb._occupy_prefilling(0, cb.queue.popleft())
+    with pytest.raises(_Captured) as caught:
+        cb.step()
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        list(caught.value.args[0]))
+    args[1] = jax.tree.map(     # the pool at the cell's size
+        lambda a: jax.ShapeDtypeStruct(POOL[:1] + a.shape[1:], a.dtype,
+                                       sharding=chip), args[1])
+    return block.lower(*args).compile().as_text()
+
+
+def _pool_values(hlo, width):
+    """(opcode, layout) of every value in ``hlo`` shaped like a K/V pool
+    leaf or like its (P * hkv, page, width) view."""
+    p, hkv, page, _ = POOL
+    shapes = (f"{p},{hkv},{page},{width}", f"{p * hkv},{page},{width}")
+    found = re.findall(
+        r"= \w+\[([\d,]+)\]\{([^}]*)\} ([\w-]+)\(", hlo)
+    return [(op, layout) for shape, layout, op in found if shape in shapes]
+
+
+@pytest.mark.parametrize("width,kv_dtype", [(64, None), (32, None),
+                                            (64, "int8")])
+def test_decode_block_keeps_the_pool_in_the_kernels_layout(
+        chip, monkeypatch, width, kv_dtype):
+    """The new token's K/V row is scattered into the pool in place, in the
+    row-major layout ``decode_attention_paged`` reads: no K or V pool leaf
+    is copied at the block's entry, in its loop or at its exit, and the
+    ``while`` carries every one as ``{3,2,1,0``.  Indexing dimensions 0 and
+    2 of the leaf made the compiler keep it pages, rows, heads, and the
+    chat cell spent half its window on 350 copies of 134 MB a block
+    (PERF.md, PR 25).
+
+    Under int8 the (P, hkv, page, 1) scale leaves are still relaid (2 MB
+    each): the compiler tiles that shape (2, 128) over heads and rows
+    however it is written to, the runtime hands it over and the kernel
+    reads it (1, 128) (PERF.md Open questions)."""
+    hlo = _decode_block_hlo(chip, monkeypatch, width, kv_dtype)
+    assert hlo.count("tpu_custom_call") == 2      # one a layer
+    values = _pool_values(hlo, POOL[3])
+    assert values
+    copies = [v for v in values if v[0] in ("copy", "copy-start")
+              and "S(" not in v[1]]     # S(n): a prefetch, same layout
+    assert not copies, copies
+    wrong = sorted({lay for _, lay in values
+                    if not lay.startswith(("3,2,1,0:", "2,1,0:"))})
+    assert not wrong, wrong
+    # the while's operands: 2 layers x K and V, row-major
+    carried = [line for line in hlo.splitlines() if " while(" in line]
+    assert len(carried) == 1
+    dt = "s8" if kv_dtype else "bf16"
+    leaf = f"{dt}[{','.join(map(str, POOL))}]{{"
+    result_type = carried[0].split(" while(")[0]
+    assert result_type.count(leaf) == 4
+    assert result_type.count(leaf + "3,2,1,0:") == 4
+    if kv_dtype:
+        scales = [v for v in _pool_values(hlo, 1) if v[0] == "copy"]
+        assert len(scales) <= 8, scales    # 4 leaves, entry and exit
