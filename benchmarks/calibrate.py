@@ -203,10 +203,10 @@ def witness_serve(cell, kept: list[dict]) -> None:
     cfg, dep = cell["config_file"], cell["deployment"]
     plain = None
     for k in kept:
-        params = weights.make_params(k["row"]["seed"], cfg,
+        params = weights.make_params(cell["family"], k["row"]["seed"], cfg,
                                      jnp.dtype(dep["dtype"]))
         if plain is None:
-            plain = program.build_server(cfg, dep, params, k["row"]["seed"],
+            plain = program.build_server(cell, params, k["row"]["seed"],
                                          inblock_refill=False, overlap=False,
                                          compact_tail=False)
         plain.params = params
@@ -252,10 +252,10 @@ def calibrate_serve(cell, devices, seeds, n_controls, seconds,
         mix = {**mix, "check_tokens": 10 ** 9}
     for i, seed in enumerate(seeds):
         t0 = time.perf_counter()
-        params = weights.make_params(seed, cfg, jnp.dtype(dep["dtype"]))
+        params = weights.make_params(cell["family"], seed, cfg,
+                                     jnp.dtype(dep["dtype"]))
         if cb is None:
-            cb = program.build_server(cfg, dep, params, seed,
-                                      **(options or {}))
+            cb = program.build_server(cell, params, seed, **(options or {}))
             run_serve.warm(cb, mix, cfg["vocab_size"])
         cb.params = params
         book = run_serve.Book()

@@ -2,16 +2,19 @@
 ``BENCHMARK.json``, the look for the chip, the compile cache and its clock,
 the readers of single metrics, and the one result line.
 
-Nothing here names a cell, a configuration, a traffic mix or a metric.
+Nothing here names a cell, a configuration, a family, a traffic mix or a
+metric.
 """
 
 from __future__ import annotations
 
+import glob
 import importlib.util
 import json
 import os
 import sys
 
+import families
 import traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -28,17 +31,47 @@ def load_json(*parts: str) -> dict:
         return json.load(f)
 
 
+def load_benchmark(left_out: bool = False) -> dict:
+    """``BENCHMARK.json``.  With ``left_out`` also the cells that are out of
+    it: ``benchmarks/left_out/<cell>.json`` keeps the entries that bring a
+    cell back and says why it is out, so calibration, rehearsal and the
+    tests of its path still reach it by name.  The driver runs only what
+    ``BENCHMARK.json`` lists."""
+    bench = load_json(ROOT, "BENCHMARK.json")
+    pattern = os.path.join(HERE, "left_out", "*.json")
+    for path in sorted(glob.glob(pattern)) if left_out else ():
+        out = load_json(path)
+        bench["workloads"] += out["workloads"]
+        for kind in ("end_to_end", "per_layer"):
+            have = {m["name"]: m for m in bench[kind]}
+            for m in out[kind]:
+                if m["name"] in have:
+                    have[m["name"]]["workloads"] += m["workloads"]
+                else:
+                    have[m["name"]] = m
+                    bench[kind].append(m)
+    return bench
+
+
 def find_cell(name: str, benchmark: dict | None = None) -> dict:
     """The cell ``name`` with its configuration's and its traffic's files
-    read, and the metrics it reports: everything by name."""
-    bench = benchmark or load_json(ROOT, "BENCHMARK.json")
+    read, its configuration's family loaded, and the metrics it reports:
+    everything by name."""
+    bench = benchmark or load_benchmark()
     cells = {w["name"]: w for w in bench["workloads"]}
+    if name not in cells and benchmark is None:
+        bench = load_benchmark(left_out=True)
+        cells = {w["name"]: w for w in bench["workloads"]}
+        if name in cells:
+            log(f"{name} is left out of BENCHMARK.json: "
+                f"benchmarks/left_out/{name}.json says why")
     if name not in cells:
-        raise SystemExit(f"no workload {name!r} in BENCHMARK.json; "
-                         f"known: {sorted(cells)}")
+        raise SystemExit(f"no workload {name!r} in BENCHMARK.json or "
+                         f"benchmarks/left_out; known: {sorted(cells)}")
     cell = dict(cells[name])
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cell["config_file"] = load_json(ROOT, cfg_entry["file"])
+    cell["family"] = families.of_config(cell["config_file"], cell["config"])
     cell["mix"] = traffic.load(cell["traffic"])
     if "deployment" in cell["mix"]:
         cell["deployment"] = load_json(HERE, "deployments",

@@ -2,7 +2,6 @@
 emit or teacher-force, plus attention over the live context) over the
 device time of the decode programs x the bf16 peak."""
 import readers
-import work
 
 
 def read(ctx):
@@ -10,7 +9,7 @@ def read(ctx):
     runs = readers.program_runs(ctx, with_kernels=True)
     if not runs:
         return None
-    need = work.decode_flops(
+    need = ctx["work"].decode_flops(
         ctx["config"],
         c["window_decode_tokens"] + c.get("inblock_prefill_steps", 0.0),
         c["window_decode_context_tokens"])

@@ -2,7 +2,6 @@
 admitted prompt, less what was teacher-forced inside decode blocks) over
 the device time of the prefill programs x the bf16 peak."""
 import readers
-import work
 
 
 def read(ctx):
@@ -14,7 +13,7 @@ def read(ctx):
         return None
     batch_share = max(0.0, 1.0 - c.get("inblock_prefill_steps", 0.0)
                       / max(sum(prompts), 1))
-    need = batch_share * sum(work.prompt_flops(ctx["config"], n)
+    need = batch_share * sum(ctx["work"].prompt_flops(ctx["config"], n)
                              for n in prompts)
     secs = 1e-9 * sum(d for _, d in runs)
     return readers.share_pct(need / ctx["peak"]["bf16_flops_per_s"], secs)

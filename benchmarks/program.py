@@ -1,33 +1,17 @@
 """The only module of the benchmark that touches the program under test.
 
 It builds the program's own objects (``LMTrainer``, ``ContinuousBatcher``)
-from a configuration file and a traffic mix, hands them the benchmark's
-weights, and reads the program's counters.  The yardstick (``reference``,
-``work``, ``traffic``, ``tracing``, ``checks``) imports none of this.
+from a cell -- its configuration file, its traffic mix and what its family's
+``program.py`` says of the model (``model_config`` and the keywords the family
+adds) -- hands them the benchmark's weights, and reads the program's counters.
+The yardstick (``reference``, ``work``, ``traffic``, ``tracing``, ``checks``)
+imports none of this.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-
-def model_config(cfg: dict):
-    """The program's ``TransformerConfig`` for a configuration file."""
-    from distributed_pytorch_tpu.models import transformer as tfm
-
-    for key, want in (("tie_word_embeddings", True), ("use_bias", False),
-                      ("hidden_act", "silu")):
-        if cfg.get(key, want) != want:
-            raise ValueError(f"the program's block has no {key}="
-                             f"{cfg[key]!r}")
-    return tfm.TransformerConfig(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], rope_theta=cfg["rope_theta"],
-        norm_eps=cfg["rms_norm_eps"])
 
 
 def place_like(tree, like):
@@ -37,14 +21,16 @@ def place_like(tree, like):
                         like)
 
 
-def build_trainer(cfg: dict, mix: dict, devices, seed: int):
-    """``LMTrainer`` over ``devices`` for the mix's job."""
+def build_trainer(cell: dict, devices, seed: int):
+    """``LMTrainer`` over ``devices`` for the cell's job."""
     from distributed_pytorch_tpu.lm import (LMTrainConfig, LMTrainer,
                                             make_lm_mesh)
 
+    fam = cell["family"].program
     dp = len(devices)   # the cell's chips: every training cell is dp today
-    tcfg = LMTrainConfig(model=model_config(cfg), dp=dp,
-                         seed=int(seed) % (1 << 31), **mix["trainer"])
+    tcfg = LMTrainConfig(model=fam.model_config(cell["config_file"]), dp=dp,
+                         seed=int(seed) % (1 << 31),
+                         **{**cell["mix"]["trainer"], **fam.trainer_keywords})
     trainer = LMTrainer(tcfg, make_lm_mesh(tcfg, devices=list(devices)))
     # where each leaf of the state lives, kept for ``reset_trainer``
     trainer.bench_layout = {
@@ -111,16 +97,19 @@ def train_loader(mix: dict, corpus, global_rows: int, seed: int):
     return prefetch(batches(), depth=2), len(loader)
 
 
-def build_server(cfg: dict, deployment: dict, params, seed: int,
-                 **options):
-    """``ContinuousBatcher`` as the deployment file sets it; everything
-    not named there is the server's default (sampling, steps per sync,
-    refill, chaining), EOS off.  ``options`` are for a witness only
-    (``calibrate.py --witness``): no run of a cell passes any."""
+def build_server(cell: dict, params, seed: int, **options):
+    """``ContinuousBatcher`` as the cell's deployment file sets it;
+    everything not named there or by the family is the server's default
+    (sampling, steps per sync, refill, chaining), EOS off.  ``options`` are
+    for a witness only (``calibrate.py --witness``): no run of a cell
+    passes any."""
     from distributed_pytorch_tpu.serve import ContinuousBatcher
 
+    fam, deployment = cell["family"].program, cell["deployment"]
+    options = {**fam.server_keywords, **options}
     return ContinuousBatcher(
-        params, model_config(cfg), slots=deployment["slots"],
+        params, fam.model_config(cell["config_file"]),
+        slots=deployment["slots"],
         max_len=deployment["max_len"], paged=deployment["paged"],
         pool_pages=deployment["pool_pages"],
         prompt_buckets=tuple(deployment["prompt_buckets"]),

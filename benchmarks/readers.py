@@ -4,7 +4,9 @@ metric out of the line); never 0 for a share of a roofline or of a peak.
 
 ``ctx`` (built by ``run_train`` / ``run_serve`` and ``run.execute``):
 ``kind``, ``cell``, ``config``, ``mix``, ``chips``, ``peak`` (the chip's
-peaks), ``setup_s``, ``window_s``, ``counters`` (the program's counters and
+peaks), ``work`` (the family's counting functions: the operations and bytes
+the model's algorithm needs; a reader reaches the model through it alone),
+``setup_s``, ``window_s``, ``counters`` (the program's counters and
 host spans as window deltas, the compile clock, and the work the window
 required), ``memory_peak_bytes``, ``trace`` and ``reduced`` (``--trace 1``
 only: the plain trace and its busy/idle reduction with the window's ``t0``,
@@ -20,6 +22,7 @@ import re
 import numpy as np
 
 import tracing
+import work
 
 
 def window_ns(ctx):
@@ -107,3 +110,18 @@ def share_pct(required_s: float, measured_s: float):
     if not required_s or not measured_s or measured_s <= 0:
         return None
     return 100.0 * required_s / measured_s
+
+
+def kernel_roofline_pct(ctx, kernel: str, calls: float, secs: float,
+                        **numbers):
+    """``<kernel>_roofline``: the least time the chip could take for
+    ``calls`` times what the family's ``work.kernels[kernel]`` counts at
+    ``numbers`` (the larger of FLOPs over the peak and bytes over the
+    bandwidth) over the ``secs`` the kernel took.  None where the family has
+    no such kernel or nothing was measured."""
+    count = ctx["work"].kernels.get(kernel)
+    if count is None or not secs or not calls:
+        return None
+    need = count(ctx["config"], **numbers)
+    least = work.roofline_seconds(need["flops"], need["bytes"], ctx["peak"])
+    return share_pct(calls * least["seconds"], secs)

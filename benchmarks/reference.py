@@ -1,48 +1,38 @@
-"""The plain reference of the dense tied-head decoder, and its control.
+"""The plain reference's driver and its control: what every family shares.
 
-Straightforward ``jax.numpy`` in float32 at matmul precision ``highest``: no
-kernels, no cache, no batching, no sharding.  It imports nothing of the
-program and takes nothing the program has made; it is handed the benchmark's
-own weights (``weights.make_params``) and the tokens the timed path saw.
+A family's ``reference.py`` (``benchmarks/families/<family>/``) holds the
+equations: a layer, the lookup, the head.  This module drives them, in
+float32 at matmul precision ``highest``, with no kernels, no cache, no
+batching and no sharding.  It imports nothing of the program and takes
+nothing the program has made; it is handed the benchmark's own weights
+(``weights.make_params``) and the tokens the timed path saw.  ``model`` below
+is a family's reference module (``cell["family"].reference``).
 
-Layer equations (ERNIE-4.5's published block; every dense config the
-benchmark holds shares them):
+Training: mean cross-entropy over every position, global-norm clipping, then
+AdamW as optax defines it (bias-corrected moments, eps 1e-8 outside the
+root, decoupled decay on every leaf).
 
-    h   = x + Wo . softmax(causal(rope(Wq n1(x)) rope(Wk n1(x))^T / sqrt(dh))) Wv n1(x)
-    out = h + Wdown (silu(Wgate n2(h)) * Wup n2(h))
-    logits = n3(out_L) E^T          (tied table E, no biases anywhere)
-
-with RMSNorm ``n(x) = x / sqrt(mean(x^2) + eps) * scale``, rotary over
-interleaved pairs at base ``rope_theta``, and K/V heads repeated to the query
-heads' count.  Training: mean cross-entropy over every position, global-norm
-clipping, then AdamW as optax defines it (bias-corrected moments, eps 1e-8
-outside the root, decoupled decay on every leaf).
-
-The control is the same code with the operands of every matrix product of
-the dense layers and the head rounded to an int8 grid (absmax scale per row
-of activations and of cotangents, per column of weights), forward and
-backward: the nearest precision below the bfloat16 the configuration
-states, and the step that would tempt a later PR.
+The control is the same code with the operands of every matrix product that
+goes through ``mm`` -- a family routes the products of its dense layers and
+its head through it -- rounded to an int8 grid (absmax scale per row of
+activations and of cotangents, per column of weights), forward and
+backward: the nearest precision below the bfloat16 the configurations
+state, and the step that would tempt a later PR.
 
 Memory and compile time: rows are taken one at a time and *layer by layer*:
 one small program runs a layer forward, one runs its backward (recomputing
 its forward), one the head and loss over a block of positions.  Each
 compiles once, in seconds, and is small enough for the persistent cache
-(the whole 18-layer gradient as one program was 267 MB and took minutes).
-Attention goes in blocks of query rows, so a 4,096-token row at a 103k
-vocabulary fits beside the float32 parameters, gradients and moments on one
-16 GB chip.
+(a whole 18-layer gradient as one program was 267 MB and took minutes).
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
-Q_BLOCK = 1024      # query rows per attention block
 HEAD_BLOCK = 1024   # positions per block of the head
 LENGTH_PAD = 1024   # a served sequence is padded to a multiple of this
 ROWS_PAD = 512      # and its served tokens' logit rows to one of this
@@ -78,7 +68,7 @@ def _mm_int8_bwd(res, dy):
 _mm_int8.defvjp(_mm_int8_fwd, _mm_int8_bwd)
 
 
-def _mm(x, w, quant):
+def mm(x, w, quant):
     """(n, k) @ (k, m), in the control's precision if asked."""
     if quant is None:
         return x @ w
@@ -87,61 +77,14 @@ def _mm(x, w, quant):
     raise ValueError(f"unknown control precision {quant!r}")
 
 
-def rms_norm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def rope(x, pos, theta):
-    """x (S, H, D) at positions pos (S,), interleaved pairs."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos[:, None].astype(jnp.float32) * freqs          # (S, D/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                     -1).reshape(x.shape)
-
-
-def attention(q, k, v):
-    """Causal softmax attention, q (S, H, D), k/v (S, H, D), in blocks of
-    query rows so the (H, S, S) scores never exist whole."""
-    s, _, d = q.shape
-    outs = []
-    for lo in range(0, s, Q_BLOCK):
-        hi = min(lo + Q_BLOCK, s)
-        sc = jnp.einsum("qhd,khd->hqk", q[lo:hi], k[:hi]) / math.sqrt(d)
-        mask = (jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :])
-        sc = jnp.where(mask[None], sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        outs.append(jnp.einsum("hqk,khd->qhd", p, v[:hi]))
-    return jnp.concatenate(outs, 0)
-
-
-def layer(lp, x, pos, cfg, quant):
-    d = x.shape[-1]
-    h, kv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    n = rms_norm(x, lp["attn_norm"], cfg["rms_norm_eps"])
-    q = _mm(n, lp["wq"].reshape(d, h * dh), quant).reshape(-1, h, dh)
-    k = _mm(n, lp["wk"].reshape(d, kv * dh), quant).reshape(-1, kv, dh)
-    v = _mm(n, lp["wv"].reshape(d, kv * dh), quant).reshape(-1, kv, dh)
-    q, k = rope(q, pos, cfg["rope_theta"]), rope(k, pos, cfg["rope_theta"])
-    k, v = jnp.repeat(k, h // kv, 1), jnp.repeat(v, h // kv, 1)
-    o = attention(q, k, v).reshape(-1, h * dh)
-    x = x + _mm(o, lp["wo"].reshape(h * dh, d), quant)
-    n = rms_norm(x, lp["mlp_norm"], cfg["rms_norm_eps"])
-    gate = jax.nn.silu(_mm(n, lp["w_gate"], quant))
-    return x + _mm(gate * _mm(n, lp["w_up"], quant), lp["w_down"], quant)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "quant"))
-def _layer_fwd(lp, x, cfg_items, quant):
+@functools.partial(jax.jit, static_argnames=("layer", "cfg_items", "quant"))
+def _layer_fwd(lp, x, layer, cfg_items, quant):
     with jax.default_matmul_precision("highest"):
         return layer(lp, x, jnp.arange(x.shape[0]), dict(cfg_items), quant)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "quant"))
-def _layer_bwd(lp, x, dy, cfg_items, quant):
+@functools.partial(jax.jit, static_argnames=("layer", "cfg_items", "quant"))
+def _layer_bwd(lp, x, dy, layer, cfg_items, quant):
     """Cotangents (d lp, d x) of one layer at its input ``x``."""
     with jax.default_matmul_precision("highest"):
         _, vjp = jax.vjp(lambda p, h: layer(p, h, jnp.arange(h.shape[0]),
@@ -149,65 +92,70 @@ def _layer_bwd(lp, x, dy, cfg_items, quant):
         return vjp(dy)
 
 
-@functools.partial(jax.jit, static_argnames=("eps",))
-def _final_norm(scale, x, eps):
-    return rms_norm(x, scale, eps)
+@functools.partial(jax.jit, static_argnames=("final", "cfg_items"))
+def _final_norm(hp, x, final, cfg_items):
+    return final(hp, x, dict(cfg_items))
 
 
-def hidden(params, tokens, cfg, quant=None, keep=False):
+def hidden(model, params, tokens, cfg, quant=None, keep=False):
     """Final-norm hidden states (S, d) of one sequence of token ids; with
     ``keep`` also every layer's input (what its backward starts from)."""
     items = cfg_items(cfg)
-    x = params["embed"][tokens]
+    x = model.embed(params, tokens)
     inputs = []
-    for i in range(cfg["num_hidden_layers"]):
+    for key in model.layer_keys(cfg):
         if keep:
             inputs.append(x)
-        x = _layer_fwd(params[f"layer{i}"], x, items, quant)
-    hs = _final_norm(params["final_norm"], x, cfg["rms_norm_eps"])
+        x = _layer_fwd(params[key], x, model.layer, items, quant)
+    hs = _final_norm(model.head_params(params), x, model.final, items)
     return (hs, inputs, x) if keep else hs
 
 
 # -- training -------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("quant", "eps"))
-def _head_block(scale, table, x, targets, quant, eps):
+@functools.partial(jax.jit,
+                   static_argnames=("final", "project", "cfg_items", "quant"))
+def _head_block(hp, x, targets, final, project, cfg_items, quant):
     """Summed cross-entropy of a block of positions from the last layer's
-    output ``x``, and its cotangents on (scale, table, x)."""
-    def ce(scale, table, x):
-        lg = _mm(rms_norm(x, scale, eps), table.T, quant)
+    output ``x``, and its cotangents on (the head's leaves, x)."""
+    cfg = dict(cfg_items)
+
+    def ce(hp, x):
+        lg = project(hp, final(hp, x, cfg), cfg, quant)
         return jnp.sum(jax.nn.logsumexp(lg, -1)
                        - jnp.take_along_axis(lg, targets[:, None], -1)[:, 0])
 
     with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(ce, argnums=(0, 1, 2))(scale, table, x)
+        return jax.value_and_grad(ce, argnums=(0, 1))(hp, x)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_rows(table_grad, tokens, dx):
-    """The embedding lookup's backward: rows of ``dx`` added at ``tokens``."""
+def scatter_rows(table_grad, tokens, dx):
+    """A lookup's backward: rows of ``dx`` added at ``tokens``."""
     return table_grad.at[tokens].add(dx)
 
 
-def _row_grad(params, tokens, targets, cfg, quant):
+def _row_grad(model, params, tokens, targets, cfg, quant):
     """Summed cross-entropy of one row and its gradient, layer by layer."""
     items = cfg_items(cfg)
-    n_layers, eps = cfg["num_hidden_layers"], cfg["rms_norm_eps"]
-    _, inputs, last = hidden(params, tokens, cfg, quant, keep=True)
-    total, d_scale, d_table, d_last = 0.0, 0.0, 0.0, []
+    keys = model.layer_keys(cfg)
+    _, inputs, last = hidden(model, params, tokens, cfg, quant, keep=True)
+    hp = model.head_params(params)
+    total, d_head, d_last = 0.0, None, []
     for lo in range(0, tokens.shape[0], HEAD_BLOCK):
-        ls, (gs, gt, gx) = _head_block(
-            params["final_norm"], params["embed"], last[lo:lo + HEAD_BLOCK],
-            targets[lo:lo + HEAD_BLOCK], quant, eps)
-        total, d_scale, d_table = total + ls, d_scale + gs, d_table + gt
+        ls, (gh, gx) = _head_block(
+            hp, last[lo:lo + HEAD_BLOCK], targets[lo:lo + HEAD_BLOCK],
+            model.final, model.project, items, quant)
+        total = total + ls
+        d_head = gh if d_head is None else jax.tree.map(jnp.add, d_head, gh)
         d_last.append(gx)
-    grads = {"final_norm": d_scale}
+    grads = dict(d_head)
     dx = jnp.concatenate(d_last, 0)
-    for i in reversed(range(n_layers)):
-        grads[f"layer{i}"], dx = _layer_bwd(params[f"layer{i}"], inputs[i],
-                                            dx, items, quant)
+    for i in reversed(range(len(keys))):
+        grads[keys[i]], dx = _layer_bwd(params[keys[i]], inputs[i], dx,
+                                        model.layer, items, quant)
         inputs[i] = None
-    grads["embed"] = _scatter_rows(d_table, tokens, dx)
+    model.embed_backward(grads, params, tokens, dx)
     return total, grads
 
 
@@ -217,12 +165,12 @@ def cfg_items(cfg: dict) -> tuple:
                         and not isinstance(v, bool)))
 
 
-def loss_and_grads(params, tokens, targets, cfg, quant=None):
+def loss_and_grads(model, params, tokens, targets, cfg, quant=None):
     """Mean cross-entropy over every position of (rows, S) tokens and its
     gradient, one row at a time."""
     total, grads = 0.0, None
     for r in range(tokens.shape[0]):
-        ls, g = _row_grad(params, jnp.asarray(tokens[r]),
+        ls, g = _row_grad(model, params, jnp.asarray(tokens[r]),
                           jnp.asarray(targets[r]), cfg, quant)
         total = total + ls
         grads = g if grads is None else _add(grads, g)
@@ -273,7 +221,8 @@ def _adamw(params, grads, mu, nu, t, hp_items):
     return jax.tree.map(upd, params, mu, nu), mu, nu, grads
 
 
-def train_steps(params, batches, cfg, hp, quant=None, grad_fault=None):
+def train_steps(model, params, batches, cfg, hp, quant=None,
+                grad_fault=None):
     """Follow ``len(batches)`` optimizer steps from ``params`` (float32;
     they are used up: the caller makes them anew from the seed where it
     needs the start again, so only one copy is alive beside the moments).
@@ -290,7 +239,7 @@ def train_steps(params, batches, cfg, hp, quant=None, grad_fault=None):
     for t, (tok, tgt) in enumerate(batches, 1):
         if grad_fault is not None:
             tok, tgt = grad_fault(tok, tgt)
-        loss, grads = loss_and_grads(params, tok, tgt, cfg, quant)
+        loss, grads = loss_and_grads(model, params, tok, tgt, cfg, quant)
         params, mu, nu, clipped = _adamw(params, grads, mu, nu,
                                          jnp.float32(t), hp_items)
         if first is None:
@@ -310,12 +259,13 @@ def with_delta_norms(result: dict, start) -> dict:
 
 # -- serving --------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("quant", "n_rows"))
-def _logit_rows(table, hs, start, quant, n_rows):
+@functools.partial(jax.jit,
+                   static_argnames=("project", "cfg_items", "quant", "n_rows"))
+def _logit_rows(hp, hs, start, project, cfg_items, quant, n_rows):
     """Logits of rows [start, start + n_rows) of the hidden states."""
     with jax.default_matmul_precision("highest"):
         rows = jax.lax.dynamic_slice_in_dim(hs, start, n_rows, 0)
-        return _mm(rows, table.T, quant)
+        return project(hp, rows, dict(cfg_items), quant)
 
 
 @jax.jit
@@ -323,13 +273,14 @@ def _as_f32(params):
     return jax.tree.map(lambda p: p.astype(jnp.float32), params)
 
 
-def _served_logits(params, tokens, start, cfg, quant, n_rows):
+def _served_logits(model, params, tokens, start, cfg, quant, n_rows):
     """Row i of the result predicts the token at position start + i + 1."""
-    hs = hidden(params, jnp.asarray(tokens), cfg, quant)
-    return _logit_rows(params["embed"], hs, start, quant, n_rows)
+    hs = hidden(model, params, jnp.asarray(tokens), cfg, quant)
+    return _logit_rows(model.head_params(params), hs, start, model.project,
+                       cfg_items(cfg), quant, n_rows)
 
 
-def served_gaps(params, prompt, served, cfg, with_control=False):
+def served_gaps(model, params, prompt, served, cfg, with_control=False):
     """For one request: by how much each served token's logit lies below
     the reference's best at its position (0 where it is the best).
 
@@ -353,7 +304,7 @@ def served_gaps(params, prompt, served, cfg, with_control=False):
     params = _as_f32(params)
 
     def logits(quant):
-        return _served_logits(params, tokens, start, cfg, quant,
+        return _served_logits(model, params, tokens, start, cfg, quant,
                               n_rows)[:n_out]
 
     lg = logits(None)

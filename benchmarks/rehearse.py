@@ -7,13 +7,15 @@
 The first form runs the cell end to end on the CPU at a tiny size: the same
 ``run.execute`` the chip runs, entered past the look for the chip, with the
 cell's configuration, traffic and deployment shrunk by ``shrink`` (widths,
-lengths and counts only; every code path, the reference and the check stay).
+lengths and counts only, the configuration's to its family's ``tiny``; every
+code path, the reference and the check stay).
 A four-chip cell runs on four virtual CPU devices.  Times it prints are the
 CPU's and mean nothing.
 
 The second form compiles, for a described v5e that is not attached, the
-Pallas kernels of every cell in ``BENCHMARK.json`` at the cell's real shapes
-(flash attention forward and backward for ``train`` mixes, paged decode
+Pallas kernels of every cell in ``BENCHMARK.json`` at the cell's real shapes,
+as the cell's family lists them (``program.kernel_compiles``: for the dense
+family flash attention forward and backward for ``train`` mixes, paged decode
 attention for ``serve`` mixes): what the chip's compiler would refuse (VMEM,
 tiling) shows here.  ``tests/test_rehearsal.py`` keeps both under pytest.
 """
@@ -30,15 +32,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.dirname(HERE))
 
-TINY = {"hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
-        "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
-        "vocab_size": 512}
-
-
 def shrink(cell: dict) -> dict:
-    """The cell at a size the CPU can run in seconds."""
-    cell = copy.deepcopy(cell)
-    cell["config_file"].update(TINY)
+    """The cell at a size the CPU can run in seconds: the configuration at
+    its family's ``tiny`` sizes."""
+    family = cell["family"]     # modules: shared, not copied
+    cell = copy.deepcopy({k: v for k, v in cell.items() if k != "family"})
+    cell["family"] = family
+    cell["config_file"].update(family.weights.tiny)
     mix = cell["mix"]
     mix["trace_seconds"] = 2.0
     if mix["kind"] == "train":
@@ -106,50 +106,17 @@ def compile_cell_kernels(cell: dict, topo) -> dict:
     """Compile the cell's Pallas kernels at its real shapes for one
     described chip; returns {kernel: seconds it took to compile}."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from distributed_pytorch_tpu.ops import attention as attn
-
-    cfg, mix = cell["config_file"], cell["mix"]
     one = SingleDeviceSharding(topo.devices[0])
-    h, kv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-
-    def shape(dims, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
-
     took = {}
-
-    def timed(name, fn, *args):
+    for name, (fn, shapes) in cell["family"].program.kernel_compiles(
+            cell).items():
+        args = [jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+                for dims, dtype in shapes]
         t0 = time.perf_counter()
         jax.jit(fn).lower(*args).compile()
         took[name] = time.perf_counter() - t0
-
-    if mix["kind"] == "train":
-        x = shape((int(mix["rows_per_chip"]), h, int(mix["seq_len"]), dh))
-
-        def fwd_bwd(q, k, v):
-            def f(q, k, v):
-                return attn.flash_attention(
-                    q, k, v, causal=True,
-                    interpret=False).astype(jnp.float32).sum()
-            return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-
-        timed("flash_fwd_bwd", fwd_bwd, x, x, x)
-    else:
-        dep = cell["deployment"]
-        slots, pages = int(dep["slots"]), int(dep["pool_pages"])
-        per_slot = -(-int(dep["max_len"]) // 512)
-        pool = shape((pages, kv, 512, dh))
-
-        def decode(q, k, v, table, pos):
-            return attn.decode_attention_paged(q, k, v, table, pos,
-                                               interpret=False)
-
-        timed("decode_attention_paged", decode, shape((slots, h, 1, dh)), pool,
-              pool, shape((slots, per_slot), jnp.int32),
-              shape((slots,), jnp.int32))
     return took
 
 
@@ -167,7 +134,7 @@ def main(argv=None) -> int:
         import jax
         jax.config.update("jax_enable_compilation_cache", False)
         topo = describe_v5e()
-        bench = harness.load_json(harness.ROOT, "BENCHMARK.json")
+        bench = harness.load_benchmark(left_out=True)
         for w in bench["workloads"]:
             print(w["name"], compile_cell_kernels(
                 harness.find_cell(w["name"], bench), topo), flush=True)

@@ -13,7 +13,8 @@ window's first ``trace_seconds`` (the traffic file's), which is then the
 whole measured window.
 
 The harness knows two kinds of run, ``train`` and ``serve``, from the
-traffic file; nothing in it names a cell.
+traffic file, and reaches the model through the configuration's family
+(``benchmarks/families/``); nothing in it names a cell.
 """
 
 from __future__ import annotations

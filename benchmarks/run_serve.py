@@ -225,7 +225,8 @@ def check(cell: dict, params, served: list[dict],
     for s in served:
         got = np.asarray(s["result"])
         n_prompt = len(s["prompt"])
-        gaps = reference.served_gaps(params, s["prompt"], s["tokens"],
+        gaps = reference.served_gaps(cell["family"].reference, params,
+                                     s["prompt"], s["tokens"],
                                      cell["config_file"], with_control)
         out.append({**gaps, "served": len(s["tokens"]),
                     "wanted_new": s["wanted_new"],
@@ -238,8 +239,9 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
         t_start: float, clock: harness.CompileClock) -> dict:
     cfg, mix, dep = cell["config_file"], cell["mix"], cell["deployment"]
     phase = harness.Phases(clock, t_start)
-    params = weights.make_params(seed, cfg, jnp.dtype(dep["dtype"]))
-    cb = program.build_server(cfg, dep, params, seed)
+    params = weights.make_params(cell["family"], seed, cfg,
+                                 jnp.dtype(dep["dtype"]))
+    cb = program.build_server(cell, params, seed)
     phase("weights and server")
     warm(cb, mix, cfg["vocab_size"])
     phase(f"warm-up of {len(mix['warm_buckets'])} prompt buckets and "
@@ -299,7 +301,8 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
     ctx = {
         "kind": "serve", "cell": cell, "config": cfg, "mix": mix,
         "chips": len(devices), "peak": work.peaks(devices[0].device_kind),
-        "setup_s": setup_s, "window_s": t_close - t_open,
+        "work": cell["family"].work, "setup_s": setup_s,
+        "window_s": t_close - t_open,
         "t_open": t_open, "t_close": t_close, "book": book,
         "window_requests": in_window,
         "counters": {**counters,

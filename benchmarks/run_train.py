@@ -39,7 +39,7 @@ def prepare(cell: dict, devices, seed: int, seconds: float,
                                   est_steps * rows * seq + 1)
     batches, _ = program.train_loader(mix, corpus, rows, seed)
     if trainer is None:
-        trainer = program.build_trainer(cfg, mix, devices, seed)
+        trainer = program.build_trainer(cell, devices, seed)
     return {"trainer": trainer, "batches": batches, "rows": rows, "seq": seq,
             "hp": hyperparams(mix)}
 
@@ -54,9 +54,9 @@ def first_steps(run: dict, cell: dict, seed: int, n_ref: int,
                 n_warm: int) -> dict:
     """Drive the trainer from the seed's weights through ``n_warm`` steps by
     the window's own call and feed; keep what the reference will follow."""
-    cfg = cell["config_file"]
+    cfg, fam = cell["config_file"], cell["family"]
     trainer = run["trainer"]
-    program.reset_trainer(trainer, weights.make_params(seed, cfg))
+    program.reset_trainer(trainer, weights.make_params(fam, seed, cfg))
     kept, losses, grad_norms, delta_norms = [], [], None, None
     for i in range(max(n_warm, n_ref)):
         tok, tgt = next(run["batches"])
@@ -70,7 +70,7 @@ def first_steps(run: dict, cell: dict, seed: int, n_ref: int,
             grad_norms = np.asarray(reference.leaf_norms(mu)) / (
                 1.0 - run["hp"]["b1"])
         if i == n_ref - 1:
-            start = program.place_like(weights.make_params(seed, cfg),
+            start = program.place_like(weights.make_params(fam, seed, cfg),
                                        trainer.params)
             delta_norms = np.asarray(
                 reference.diff_norms(trainer.params, start))
@@ -113,10 +113,12 @@ def follow(cell: dict, seed: int, batches, hp: dict, **fault) -> dict:
     """The reference through the kept batches from the seed's weights:
     losses, first gradient norms, and the norms of the parameters' change.
     ``fault``: ``quant`` (the control's precision) or ``grad_fault``."""
-    cfg = cell["config_file"]
-    done = reference.train_steps(weights.make_params(seed, cfg), batches, cfg,
-                                 hp, **fault)
-    return reference.with_delta_norms(done, weights.make_params(seed, cfg))
+    cfg, fam = cell["config_file"], cell["family"]
+    done = reference.train_steps(fam.reference,
+                                 weights.make_params(fam, seed, cfg), batches,
+                                 cfg, hp, **fault)
+    return reference.with_delta_norms(done,
+                                      weights.make_params(fam, seed, cfg))
 
 
 def check(cell: dict, seed: int, firsts: dict, hp: dict) -> dict:
@@ -166,7 +168,8 @@ def run(cell: dict, devices, seed: int, seconds: float, trace: bool,
     peak = work.peaks(devices[0].device_kind)
     ctx = {
         "kind": "train", "cell": cell, "config": cfg, "mix": mix,
-        "chips": len(devices), "peak": peak, "setup_s": setup_s,
+        "chips": len(devices), "peak": peak, "work": cell["family"].work,
+        "setup_s": setup_s,
         "window_s": win["window_s"], "steps": win["steps"],
         "tokens_per_step": tokens_per_step, "done_at": win["done_at"],
         "t0": win["t0"], "rows": tokens_per_step // int(mix["seq_len"]),

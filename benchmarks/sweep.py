@@ -6,10 +6,13 @@ server sustains without a growing backlog.
         --seconds 30 --out chiprun_out/sweep.json
 
 One process and one warm server; each rate gets the mix's own ramp, a window
-of ``--seconds``, and a full drain.  The cell's rate is then fixed in its
-traffic file below the knee (four fifths as a rule; ``chat_open_loop`` took
-seven tenths and says why under its ``rate`` key); the benchmark itself
-never searches.
+of ``--seconds``, and a full drain.  The knee is the highest rate at which
+nothing waits at the close (``waiting_at_close``: requests due in the window
+whose first token had not been handed out when it closed; the tail of the
+second half, ``ttft_p90_second_half``, says the same) and tokens/s has not
+fallen.  The cell's rate is then fixed in its traffic file at four fifths of
+it (the mix says what was measured under its ``rate`` key); the benchmark
+itself never searches.
 """
 
 from __future__ import annotations
@@ -48,8 +51,9 @@ def main(argv=None) -> int:
     harness.enable_compile_cache()
     clock = harness.CompileClock()
     cfg, dep = cell["config_file"], cell["deployment"]
-    params = weights.make_params(args.seed, cfg, jnp.dtype(dep["dtype"]))
-    cb = program.build_server(cfg, dep, params, args.seed)
+    params = weights.make_params(cell["family"], args.seed, cfg,
+                                 jnp.dtype(dep["dtype"]))
+    cb = program.build_server(cell, params, args.seed)
     run_serve.warm(cb, cell["mix"], cfg["vocab_size"])
     rows = []
     for rate in [float(r) for r in args.rates.split(",")]:
@@ -75,7 +79,11 @@ def main(argv=None) -> int:
                "ttft_p90_second_half": readers.percentile(late, 90),
                "tpot_p90": readers.percentile(readers.tpots_ms(ctx), 90),
                "tokens_per_s": book.window_tokens / (t_close - t_open),
-               "queue_at_close": cb.queue_depth(),
+               # the drive returns once every request due in the window has
+               # its first token, so the server's own queue is empty by then
+               "waiting_at_close": sum(
+                   1 for r in ctx["window_requests"]
+                   if book.first.get(r, t_close) >= t_close),
                "live_at_close": sum(o is not None for o in cb.occupant)}
         harness.log(json.dumps(row))
         rows.append(row)

@@ -163,7 +163,8 @@ def test_a_sound_serving_run_is_correct_and_an_altered_token_is_not(monkeypatch)
 def test_the_int8_control_in_the_servers_place_is_not_correct():
     cell = tiny("lm_serve_chat", SERVE_LIMITS)
     cfg = cell["config_file"]
-    params = weights.make_params(SEED, cfg)
+    model = cell["family"].reference
+    params = weights.make_params(cell["family"], SEED, cfg)
     rng = np.random.default_rng(5)
     worst = 0.0
     for _ in range(3):
@@ -172,11 +173,11 @@ def test_the_int8_control_in_the_servers_place_is_not_correct():
         seq = list(prompt)
         for _ in range(24):
             lg = reference._served_logits(
-                params, np.pad(seq, (0, (-len(seq)) % 256)), len(seq) - 1,
-                cfg, None, 128)[0]
+                model, params, np.pad(seq, (0, (-len(seq)) % 256)),
+                len(seq) - 1, cfg, None, 128)[0]
             seq.append(int(jnp.argmax(lg)))
         served = np.asarray(seq[len(prompt):], np.int32)
-        got = reference.served_gaps(params, prompt, served, cfg,
+        got = reference.served_gaps(model, params, prompt, served, cfg,
                                     with_control=True)
         assert got["gaps"].max() == 0.0
         worst = max(worst, float(got["control_gaps"].max()))

@@ -1,4 +1,5 @@
-"""Every cell of ``BENCHMARK.json`` rehearsed without the chip: the whole
+"""Every cell of ``BENCHMARK.json``, and every cell left out of it
+(``benchmarks/left_out/``), rehearsed without the chip: the whole
 run at a tiny size on the CPU (the four-chip cell on four virtual devices),
 traced, and its kernels compiled at the real shapes for a described v5e.
 A later PR that adds a cell gets both for nothing: the cells are read from
@@ -13,7 +14,7 @@ import harness
 import rehearse
 
 WORKLOADS = [w["name"] for w in
-             harness.load_json(harness.ROOT, "BENCHMARK.json")["workloads"]]
+             harness.load_benchmark(left_out=True)["workloads"]]
 
 
 @pytest.fixture(scope="module")

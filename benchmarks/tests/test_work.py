@@ -1,9 +1,13 @@
-"""The operation and byte functions against hand-worked values."""
+"""The operation and byte functions of the dense family, reached through
+the family as a run reaches them, against hand-worked values."""
 
 import pytest
 
+import families
 import harness
-import work
+import work as chip   # the chip's side: peaks and roofline_seconds
+
+work = families.load("dense_gqa").work
 
 
 @pytest.fixture(scope="module")
@@ -26,7 +30,10 @@ def test_train_flops_per_token_at_4096(ernie):
 
 def test_kv_bytes_per_token(ernie):
     assert work.kv_bytes_per_token(ernie) == 2 * 18 * 2 * 128 * 2 == 18_432
-    assert work.decode_attn_bytes(ernie, 400 * 64) == 18_432 * 400 * 64
+    need = work.kernels["decode_attn"](ernie, context_tokens=400 * 64)
+    assert need["bytes"] == 18_432 * 400 * 64
+    assert chip.roofline_seconds(need["flops"], need["bytes"],
+                                 chip.peaks("TPU v5 lite"))["bound"] == "memory"
 
 
 def test_prompt_flops_is_decode_flops_summed(ernie):
@@ -35,16 +42,16 @@ def test_prompt_flops_is_decode_flops_summed(ernie):
 
 
 def test_flash_attention_is_compute_bound_at_4k(ernie):
-    w = work.flash_attn_work(ernie, rows=2, seq=4096)
+    w = work.kernels["flash_attn"](ernie, rows=2, seq=4096)
     assert w["flops"] == 6 * 4096 * 4096 * 128 * 16 * 2 * 18
-    least = work.roofline_seconds(w["flops"], w["bytes"],
-                                  work.peaks("TPU v5 lite"))
+    least = chip.roofline_seconds(w["flops"], w["bytes"],
+                                  chip.peaks("TPU v5 lite"))
     assert least["bound"] == "compute"
     assert work.train_flops_per_token(ernie, 4096) * 2 * 4096 > w["flops"]
 
 
 def test_peaks_are_the_v5e_and_an_unknown_device_is_an_error():
-    p = work.peaks("TPU v5 lite")
+    p = chip.peaks("TPU v5 lite")
     assert p["bf16_flops_per_s"] == 197e12 and p["hbm_bytes_per_s"] == 819e9
     with pytest.raises(ValueError):
-        work.peaks("cpu")
+        chip.peaks("cpu")

@@ -1,11 +1,10 @@
 """The benchmark's own weights: made on the device, in one jitted call, from
 the seed, in the type they are trained or served in.
 
-The tree has the layout the program's model takes (``embed``,
-``final_norm``, ``layer<i>`` with ``wq`` (d, h, dh) ...): that layout is the
-program's interface.  The values are the benchmark's, so the program and the
-plain reference are handed the same inputs and neither takes anything the
-other has made.
+The tree's layout is the family's (``family.weights.leaf_shapes``: the
+layout the program's model takes is the program's interface).  The values are
+the benchmark's, so the program and the plain reference are handed the same
+inputs and neither takes anything the other has made.
 """
 
 from __future__ import annotations
@@ -24,28 +23,10 @@ def seed_key(seed: int):
     return jax.random.key(int(seed) % (1 << 63))
 
 
-def leaf_shapes(cfg: dict) -> dict:
-    """name -> (shape, fan_in or None for a norm scale)."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, kv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    out = {"embed": ((cfg["vocab_size"], d), "embed"),
-           "final_norm": ((d,), None)}
-    for i in range(cfg["num_hidden_layers"]):
-        out[f"layer{i}"] = {
-            "attn_norm": ((d,), None), "mlp_norm": ((d,), None),
-            "wq": ((d, h, dh), d), "wk": ((d, kv, dh), d),
-            "wv": ((d, kv, dh), d), "wo": ((h, dh, d), h * dh),
-            "w_gate": ((d, f), d), "w_up": ((d, f), d),
-            "w_down": ((f, d), f),
-        }
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "dtype"))
-def _make(key, cfg_items, dtype):
-    cfg = dict(cfg_items)
-    spec = leaf_shapes(cfg)
+@functools.partial(jax.jit,
+                   static_argnames=("leaf_shapes", "cfg_items", "dtype"))
+def _make(key, leaf_shapes, cfg_items, dtype):
+    spec = leaf_shapes(dict(cfg_items))
     is_leaf = lambda x: isinstance(x, tuple) and isinstance(x[0], tuple)
     leaves, treedef = jax.tree.flatten(spec, is_leaf=is_leaf)
     keys = jax.random.split(key, len(leaves))
@@ -63,6 +44,8 @@ def _make(key, cfg_items, dtype):
     return jax.tree.unflatten(treedef, made)
 
 
-def make_params(seed: int, cfg: dict, dtype=jnp.float32):
-    """The parameter tree for ``cfg`` (the configuration file's dict)."""
-    return _make(seed_key(seed), cfg_items(cfg), jnp.dtype(dtype).name)
+def make_params(family, seed: int, cfg: dict, dtype=jnp.float32):
+    """The parameter tree of ``family`` for ``cfg`` (the configuration
+    file's dict)."""
+    return _make(seed_key(seed), family.weights.leaf_shapes, cfg_items(cfg),
+                 jnp.dtype(dtype).name)
