@@ -689,6 +689,9 @@ def _generate_impl(
     decode_kernel: bool | None = None,
     kv_dtype=None,
 ) -> jax.Array:
+    # the cached forward knows one kind of cache row (K and V per kv head),
+    # a tied head and the capacity-routed block
+    tfm.require_servable(cfg, "generate")
     b, s0 = prompt.shape
     # Pallas decode kernel by default on TPU: exact dynamic pos+1 cache-read
     # bounds make the static segment bounds below redundant (one compiled
@@ -799,6 +802,7 @@ def _spec_prefill(params, prompt, cfg, dtype, max_len_pad):
     return ``(cache, (B, vocab) last-position logits)`` (each caller
     derives its own first token — argmax or a warped sample — and done
     mask from the logits)."""
+    tfm.require_servable(cfg, "speculative decoding")
     b, s0 = prompt.shape
     cache = init_cache(cfg, b, max_len_pad, dtype=dtype or jnp.float32,
                        kv_heads=params["layer0"]["wk"].shape[1])

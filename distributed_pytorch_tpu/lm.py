@@ -54,6 +54,12 @@ DCN = "dcn"  # outer factor of the data axis on multislice meshes
 PP = "pp"    # interleaved-1F1B stage axis (round 10; distinct from the
              # wave scheduler's 'pipe' — see make_lm_1f1b_train_step)
 IGNORE = IGNORE_INDEX  # target id excluded from the loss (padding)
+# What the step of a model with the dropless routed layer (ops/moe.py) adds
+# to ``LMTrainer.last_metrics`` after [grad-norm, param-norm], summed over
+# the step's layers and chips (the load figure: the worst layer's): picks
+# routed to experts held here, the largest expert's rows over the mean
+# expert's, picks that reached no product (0 by construction).
+MOE_METRICS = ("moe.rows_here", "moe.load_max_over_mean", "moe.dropped")
 
 
 @dataclass
@@ -506,6 +512,27 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
             dcn=cfg.dcn_size > 1 and (cfg.grad_accum == 1
                                       or cfg.pp_size > 0),
             pp=cfg.pp_size > 0)
+    unpiped = cfg.model.training_only()
+    if unpiped and (cfg.pp > 1 or cfg.pp_size > 0):
+        # the pipeline runners build the dense block on a tied table
+        raise ValueError("pipeline parallelism (pp / pp_size) does not "
+                         "implement " + "; ".join(unpiped))
+    if "window" in cfg.model.attn_kinds and cfg.sp > 1:
+        raise ValueError("windowed attention layers do not compose with "
+                         "sp > 1: ring attention has no window")
+    if cfg.model.moe_dropless:
+        # one chip's share, on the plain step: the dropless routed layer
+        # has no exchange yet, and its counters ride the plain grad step
+        bad = [f"{k}={getattr(cfg, k)}" for k, ok in (
+            ("ep", cfg.ep == 1), ("tp", cfg.tp == 1),
+            ("sync_every", cfg.sync_every == 1),
+            ("dcn_size", cfg.dcn_size == 1),
+            ("grad_accum", cfg.grad_accum == 1)) if not ok]
+        if bad:
+            raise ValueError(
+                "the dropless routed layer (moe_dropless=True) runs with "
+                "ep=1, tp=1, sync_every=1, dcn_size=1 and grad_accum=1; "
+                "got " + ", ".join(bad))
     if cfg.ep > 1:
         if cfg.pp > 1:
             raise ValueError("the dedicated 'expert' axis does not compose "
@@ -1292,14 +1319,15 @@ def _build_local_loss(cfg: LMTrainConfig, specs, *, dcn_sync: bool,
                        loss_impl=cfg.loss_impl, loss_chunk=cfg.loss_chunk,
                        tp_axis=tp_axis if cfg.tp > 1 else None,
                        tp_size=cfg.tp)
-        (ce_sum, _), aux = tfm.apply(
+        out = tfm.apply(
             params, tokens, cfg=cfg.model, dtype=dtype,
             seq_axis=seq_axis, seq_layout=cfg.seq_layout,
             tp_axis=tp_axis, pos=pos,
             ep_axis=EXPERT if cfg.ep > 1 else None,
             return_aux=True, boundary=boundary,
             matmul_dtype=cfg.matmul_dtype, remat=cfg.remat,
-            head_fn=head)
+            head_fn=head, return_stats=cfg.model.moe_dropless)
+        (ce_sum, _), aux = out[:2]
         # Global mean over every shard's tokens; the batch shards over
         # (data, expert), so 'expert' reduces like a data axis ('model'
         # shards compute identical values, no reduction needed there).
@@ -1309,7 +1337,16 @@ def _build_local_loss(cfg: LMTrainConfig, specs, *, dcn_sync: bool,
         # microbatch grads is exactly the unaccumulated step's gradient.
         ce_sum = jax.lax.psum(ce_sum, reduce_axes)
         aux = jax.lax.pmean(aux, reduce_axes)  # pmean'd over MODEL
-        return ce_sum / jnp.maximum(n_total, 1) + aux_w * aux
+        loss = ce_sum / jnp.maximum(n_total, 1) + aux_w * aux
+        if cfg.model.moe_dropless:
+            # the routed layers' counters of the whole step, beside the
+            # loss (value_and_grad's aux): MOE_METRICS' order
+            st = out[2]
+            return loss, jnp.stack([
+                jax.lax.psum(st["rows_here"], reduce_axes),
+                jax.lax.pmax(st["load_max_over_mean"], reduce_axes),
+                jax.lax.psum(st["dropped"], reduce_axes)])
+        return loss
 
     if stateful:
         def local_loss_st(params, residual, tokens, targets, n_total,
@@ -1333,11 +1370,14 @@ def _make_grad_step(cfg: LMTrainConfig, mesh: Mesh):
                                    dcn_sync=cfg.dcn_size > 1)
     bspec = _lm_batch_spec(cfg)
     if cfg.dcn_compress is None or cfg.dcn_size <= 1:
+        # a dropless model's loss comes with its counters: ((loss, stats),
+        # grads) in place of (loss, grads)
+        counted = cfg.model.moe_dropless
         return shard_map(
-            jax.value_and_grad(local_loss),
+            jax.value_and_grad(local_loss, has_aux=counted),
             mesh=mesh,
             in_specs=(specs, bspec, bspec, P(), P()),
-            out_specs=(P(), specs),
+            out_specs=((P(), P()) if counted else P(), specs),
             # check_vma stays ON: the automatic psum of cotangents for
             # axis-invariant params (the fused DP/SP gradient sync)
             # depends on it.
@@ -1785,6 +1825,9 @@ def make_lm_train_step(cfg: LMTrainConfig, mesh: Mesh):
 
     def _finish(params, opt_state, loss, grads, step_no, fault_arm):
         # chaos taps (trace-time no-ops unplanned) + sentry health flag
+        moe_stats = None
+        if cfg.model.moe_dropless:
+            loss, moe_stats = loss
         grads = faults.tap_grads(grads, step_no, fault_arm)
         loss = faults.tap_loss(loss, step_no, fault_arm)
         gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
@@ -1797,6 +1840,8 @@ def make_lm_train_step(cfg: LMTrainConfig, mesh: Mesh):
         # param global-norm — always emitted, so telemetry on/off never
         # changes the compiled program
         met = _step_metrics(gsq, params)
+        if moe_stats is not None:   # MOE_METRICS, after the two norms
+            met = jnp.concatenate([met, moe_stats])
         return params, opt_state, loss, ok, met
 
     if compress:
@@ -2412,6 +2457,9 @@ def make_lm_multi_step(cfg: LMTrainConfig, mesh: Mesh):
         raise ValueError("make_lm_multi_step does not thread the "
                          "stateful sync-state (EF residual) carry; with "
                          "dcn_compress use make_lm_train_step")
+    if cfg.model.moe_dropless:
+        raise ValueError("make_lm_multi_step does not carry the dropless "
+                         "routed layer's counters; use make_lm_train_step")
     tx = make_optimizer(cfg)
     grad_step = _make_grad_step(cfg, mesh)
 
@@ -2977,7 +3025,9 @@ class LMTrainer:
         if tel is not None:
             telemetry.emit_train_steps(
                 tel, t0, self._step - 1, 1, loss, self.last_ok,
-                self.last_metrics, span_name="lm_train_step", defer=True)
+                self.last_metrics, span_name="lm_train_step", defer=True,
+                extra_gauges=MOE_METRICS if self.cfg.model.moe_dropless
+                else ())
             self._emit_cache_size(tel, self.step_fn)
         return loss
 

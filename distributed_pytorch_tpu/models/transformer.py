@@ -73,12 +73,55 @@ class TransformerConfig:
     # actually crosses a mesh axis (the EP / tensor-axis call sites).
     moe_dispatch_bits: str = "f32"
     moe_a2a_chunks: int = 1
+    # -- what further architectures need; the defaults are the block above --
+    # False: the output projection is a table of its own, params["lm_head"]
+    tie_embeddings: bool = True
+    # Per-layer attention kinds (ATTN_KINDS), one name a layer, e.g.
+    # ("global_nope", "window", "window", "window").  () = every layer
+    # "global" with its leaves flat in the layer's dict (the dense tree);
+    # otherwise a layer keeps wq/wk/wv/wo under "attn_<kind>", so the tree
+    # itself says which kind a layer is.  ``attn_window``: the width of the
+    # "window" kind (a query sees that many keys, its own included).
+    attn_kinds: tuple[str, ...] = ()
+    attn_window: int = 0
+    # The dropless routed layer (ops/moe.moe_dropless_apply): EVERY layer is
+    # routed, top ``moe_top_k`` of the router's ``n_experts`` outputs, softmax
+    # over the picks, no capacity.  This chip holds ``moe_experts_held``
+    # experts (None = all) from ``moe_first_expert`` on and computes their
+    # part of the result.  ``moe_act``: the gate branch ('silu' SwiGLU,
+    # 'relu' ReGLU); ``moe_router_input``: 'mlp_norm' (the experts' own
+    # input) or 'attn_norm' (the attention's normed input: a router placed
+    # before attention).  ``d_ff`` is one expert's width.
+    moe_dropless: bool = False
+    moe_experts_held: int | None = None
+    moe_first_expert: int = 0
+    moe_act: str = "silu"
+    moe_router_input: str = "mlp_norm"
 
     def __post_init__(self):
         kv = self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
         if self.n_heads % kv:
             raise ValueError(f"n_heads {self.n_heads} not divisible by "
                              f"n_kv_heads {kv}")
+        if self.attn_kinds:
+            unknown = set(self.attn_kinds) - set(ATTN_KINDS)
+            if unknown or len(self.attn_kinds) != self.n_layers:
+                raise ValueError(
+                    f"attn_kinds must name one of {sorted(ATTN_KINDS)} for "
+                    f"each of the {self.n_layers} layers, got "
+                    f"{self.attn_kinds!r}")
+            if "window" in self.attn_kinds and self.attn_window < 1:
+                raise ValueError("a 'window' layer needs attn_window >= 1")
+        if self.moe_dropless and not 1 <= self.moe_top_k <= self.n_experts:
+            raise ValueError(
+                f"moe_dropless routes top {self.moe_top_k} of "
+                f"n_experts={self.n_experts}: need 1 <= top_k <= n_experts")
+        if self.moe_act not in ("silu", "relu"):
+            raise ValueError(f"moe_act must be 'silu' or 'relu', got "
+                             f"{self.moe_act!r}")
+        if self.moe_router_input not in ("mlp_norm", "attn_norm"):
+            raise ValueError(f"moe_router_input must be 'mlp_norm' or "
+                             f"'attn_norm', got {self.moe_router_input!r}")
         if self.moe_dispatch_bits not in ("f32", "int8", "int4"):
             raise ValueError(
                 f"moe_dispatch_bits must be f32, int8, or int4, got "
@@ -96,7 +139,46 @@ class TransformerConfig:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
 
     def is_moe_layer(self, i: int) -> bool:
+        if self.moe_dropless:
+            return True
         return self.n_experts > 0 and i % self.moe_every == self.moe_every - 1
+
+    def attn_kind(self, i: int) -> str:
+        return self.attn_kinds[i] if self.attn_kinds else "global"
+
+    def training_only(self) -> list[str]:
+        """The mechanisms of this configuration that only the training
+        path implements (decode and serving refuse them by these names)."""
+        found = []
+        if "window" in self.attn_kinds:
+            found.append("windowed attention layers (attn_kinds 'window': "
+                         "no windowed cache rows or decode kernel)")
+        if "global_nope" in self.attn_kinds:
+            found.append("attention layers without rotary (attn_kinds "
+                         "'global_nope')")
+        if not self.tie_embeddings:
+            found.append("an untied output head (tie_embeddings=False)")
+        if self.moe_dropless:
+            found.append("the dropless routed layer (moe_dropless=True: no "
+                         "grouped product in decode)")
+        return found
+
+
+# attention kind -> (rotary on q and k, windowed)
+ATTN_KINDS = {"global": (True, False), "global_nope": (False, False),
+              "window": (True, True)}
+
+
+def require_servable(cfg: TransformerConfig, where: str) -> None:
+    """``generate`` and ``ContinuousBatcher`` compute the dense block and
+    the capacity-routed one; a configuration with anything else is refused
+    by the name of the missing mechanism rather than computed as something
+    it is not."""
+    missing = cfg.training_only()
+    if missing:
+        raise NotImplementedError(
+            f"{where} does not implement " + "; ".join(missing)
+            + ": this configuration runs on the training path only")
 
 
 # Named size presets, in the spirit of the reference's cfg dict
@@ -119,23 +201,27 @@ def init(key: Array, cfg: TransformerConfig) -> PyTree:
         return (jax.random.normal(key, shape, jnp.float32)
                 / math.sqrt(fan_in))
 
-    keys = iter(jax.random.split(key, 2 + 7 * cfg.n_layers))
+    keys = iter(jax.random.split(
+        key, 2 + 7 * cfg.n_layers + (not cfg.tie_embeddings)))
     params: dict = {
         "embed": jax.random.normal(next(keys), (cfg.vocab_size, d),
                                    jnp.float32) * 0.02,
         "final_norm": jnp.ones((d,), jnp.float32),
     }
     for i in range(cfg.n_layers):
-        layer = {
-            "attn_norm": jnp.ones((d,), jnp.float32),
+        attn = {
             "wq": dense(next(keys), (d, h, dh), d),
             "wk": dense(next(keys), (d, kv, dh), d),
             "wv": dense(next(keys), (d, kv, dh), d),
             "wo": dense(next(keys), (h, dh, d), h * dh),
-            "mlp_norm": jnp.ones((d,), jnp.float32),
         }
+        layer = {"attn_norm": jnp.ones((d,), jnp.float32),
+                 **_under_kind(cfg, i, attn),
+                 "mlp_norm": jnp.ones((d,), jnp.float32)}
         if cfg.is_moe_layer(i):
-            layer["moe"] = moe_ops.moe_init(next(keys), d, f, cfg.n_experts)
+            layer["moe"] = moe_ops.moe_init(
+                next(keys), d, f, cfg.n_experts,
+                held=cfg.moe_experts_held if cfg.moe_dropless else None)
         else:
             layer.update(
                 w_gate=dense(next(keys), (d, f), d),
@@ -143,7 +229,16 @@ def init(key: Array, cfg: TransformerConfig) -> PyTree:
                 w_down=dense(next(keys), (f, d), f),
             )
         params[f"layer{i}"] = layer
+    if not cfg.tie_embeddings:
+        params["lm_head"] = jax.random.normal(
+            next(keys), (cfg.vocab_size, d), jnp.float32) * 0.02
     return params
+
+
+def _under_kind(cfg: TransformerConfig, i: int, attn: dict) -> dict:
+    """A layer's attention leaves as the tree keeps them: flat for the
+    dense model, under ``attn_<kind>`` where layers differ in kind."""
+    return {"attn_" + cfg.attn_kind(i): attn} if cfg.attn_kinds else attn
 
 
 def shard_specs(cfg: TransformerConfig, *, tp_axis: str = "model",
@@ -160,13 +255,16 @@ def shard_specs(cfg: TransformerConfig, *, tp_axis: str = "model",
     from jax.sharding import PartitionSpec as P
 
     specs: dict = {"embed": P(), "final_norm": P()}
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P()
     for i in range(cfg.n_layers):
         layer = {
             "attn_norm": P(),
-            "wq": P(None, tp_axis, None),
-            "wk": P(None, tp_axis, None),
-            "wv": P(None, tp_axis, None),
-            "wo": P(tp_axis, None, None),
+            **_under_kind(cfg, i, {
+                "wq": P(None, tp_axis, None),
+                "wk": P(None, tp_axis, None),
+                "wv": P(None, tp_axis, None),
+                "wo": P(tp_axis, None, None)}),
             "mlp_norm": P(),
         }
         if cfg.is_moe_layer(i):
@@ -202,6 +300,8 @@ def sync_group_index(cfg: TransformerConfig) -> dict[str, int]:
     machinery (parallel/strategies.OverlapSync via train-side wiring) and
     by lm.py's streaming ZeRO-3 gather placement."""
     idx = {"embed": 0, "final_norm": cfg.n_layers + 1}
+    if not cfg.tie_embeddings:   # the head's own table is read last
+        idx["lm_head"] = cfg.n_layers + 1
     for i in range(cfg.n_layers):
         idx[f"layer{i}"] = i + 1
     return idx
@@ -245,7 +345,9 @@ def block(
     ep_axis: str | None = None,
     matmul_dtype: str | None = None,
     save_attn: bool = False,
-) -> tuple[Array, Array]:
+    kind: str = "global",
+    with_stats: bool = False,
+) -> tuple[Array, Array] | tuple[Array, Array, dict | None]:
     """One transformer block: (layer params, (B, S, D)) -> (x, moe aux).
 
     The single implementation of the layer body, shared by ``apply`` and
@@ -270,9 +372,21 @@ def block(
     ``attn_out``/``attn_lse`` checkpoint names (ops/attention.py) that a
     ``save_only_these_names`` policy pins — attention stays saved while
     the MLP recomputes.  ``False`` traces the historical kernel call.
+
+    ``kind``: the layer's attention kind (``ATTN_KINDS``: rotary or not,
+    windowed or not; ``cfg.attn_kind(i)``).  ``with_stats``: also return
+    the dropless routed layer's counters (``ops/moe.py``; None for any
+    other layer) as a third result.
     """
     b, s, d = x.shape
     q8 = matmul_dtype == "int8"
+    rope, windowed = ATTN_KINDS[kind]
+    window = cfg.attn_window if windowed else None
+    if window is not None and seq_axis is not None:
+        raise NotImplementedError(
+            "windowed attention layers under sequence parallelism: ring "
+            "attention has no window")
+    ap = lp["attn_" + kind] if cfg.attn_kinds else lp   # attention leaves
 
     def proj2d(h2: Array, w2: Array) -> Array:
         from ..ops import quantized as qz
@@ -288,14 +402,15 @@ def block(
             out = proj2d(hf, w.reshape(d, heads * dh).astype(h.dtype))
             return out.reshape(b, s, heads, dh).transpose(0, 2, 1, 3)
 
-        q, k, v = (head_proj(lp["wq"]), head_proj(lp["wk"]),
-                   head_proj(lp["wv"]))
+        q, k, v = (head_proj(ap["wq"]), head_proj(ap["wk"]),
+                   head_proj(ap["wv"]))
     else:
-        q = jnp.einsum("bsd,dhk->bhsk", h, lp["wq"].astype(h.dtype))
-        k = jnp.einsum("bsd,dhk->bhsk", h, lp["wk"].astype(h.dtype))
-        v = jnp.einsum("bsd,dhk->bhsk", h, lp["wv"].astype(h.dtype))
-    q = rotary(q, pos, cfg.rope_theta)
-    k = rotary(k, pos, cfg.rope_theta)
+        q = jnp.einsum("bsd,dhk->bhsk", h, ap["wq"].astype(h.dtype))
+        k = jnp.einsum("bsd,dhk->bhsk", h, ap["wk"].astype(h.dtype))
+        v = jnp.einsum("bsd,dhk->bhsk", h, ap["wv"].astype(h.dtype))
+    if rope:
+        q = rotary(q, pos, cfg.rope_theta)
+        k = rotary(k, pos, cfg.rope_theta)
     if cfg.kv_heads != cfg.n_heads:
         # GQA: q heads share repeated K/V heads (params and decode cache stay
         # kv_heads-sized; the repeat is a view XLA folds into the attention)
@@ -309,24 +424,40 @@ def block(
     elif attn_impl == "flash":
         if save_attn:
             o, _ = attn_ops.flash_attention(q, k, v, causal=True,
-                                            with_lse=True)
+                                            with_lse=True, window=window)
         else:
-            o = attn_ops.flash_attention(q, k, v, causal=True)
+            o = attn_ops.flash_attention(q, k, v, causal=True, window=window)
     else:
-        o = attn_ops.attention_reference(q, k, v, causal=True)
+        o = attn_ops.attention_reference(q, k, v, causal=True, window=window)
     if q8:
         of = o.transpose(0, 2, 1, 3).reshape(b * s, -1)
-        o = proj2d(of, lp["wo"].reshape(-1, d).astype(o.dtype)
+        o = proj2d(of, ap["wo"].reshape(-1, d).astype(o.dtype)
                    ).reshape(b, s, d)
     else:
-        o = jnp.einsum("bhsk,hkd->bsd", o, lp["wo"].astype(o.dtype))
+        o = jnp.einsum("bhsk,hkd->bsd", o, ap["wo"].astype(o.dtype))
     if tp_axis is not None:
         o = lax.psum(o, tp_axis)  # Megatron row-parallel reduction 1
     x = x + o
     # -- MLP ---------------------------------------------------------------
+    h_attn = h    # what a router placed before attention reads
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if is_moe:
+    stats = None
+    if is_moe and cfg.moe_dropless:
+        if ep_axis is not None or (tp_axis is not None
+                                   and lax.axis_size(tp_axis) > 1):
+            raise NotImplementedError(
+                "the dropless routed layer over an expert or tensor axis: "
+                "it has no exchange yet (ops/moe.moe_dropless_apply); run "
+                "it with ep=1 and tp=1")
+        router_in = (h_attn.reshape(b * s, d)
+                     if cfg.moe_router_input == "attn_norm" else None)
+        down, stats = moe_ops.moe_dropless_apply(
+            lp["moe"], h.reshape(b * s, d), top_k=cfg.moe_top_k,
+            first_expert=cfg.moe_first_expert, router_input=router_in,
+            act=cfg.moe_act)
+        down = down.reshape(b, s, d)
+    elif is_moe:
         hf = h.reshape(b * s, d)
         if ep_axis is not None:
             # EP x TP (dedicated expert axis): every tp rank routes the
@@ -389,6 +520,8 @@ def block(
         down = (gate * up) @ lp["w_down"].astype(h.dtype)
     if tp_axis is not None:
         down = lax.psum(down, tp_axis)  # Megatron reduction 2
+    if with_stats:
+        return x + down, aux, stats
     return x + down, aux
 
 
@@ -409,7 +542,8 @@ def apply(
     boundary=None,                 # layer-group hook (sync_group_index)
     matmul_dtype: str | None = None,  # "int8": quantized dense projections
     remat: str | None = None,      # None/"none" | "full" | "selective"
-    head_fn=None,                  # (h, embed) -> loss head replacement
+    head_fn=None,                  # (h, table) -> loss head replacement
+    return_stats: bool = False,    # with return_aux: the routed counters too
 ) -> Array | tuple[Array, Array]:
     """Forward pass: (B, S) int32 tokens -> (B, S, vocab) float32 logits.
 
@@ -443,12 +577,18 @@ def apply(
     remat backward.  ``None``/``"none"`` traces the historical graph
     bit-for-bit.
 
-    ``head_fn``: when given, called as ``head_fn(h, params["embed"])`` on
-    the final-norm hidden states in place of the logits matmul and its
-    result returned where logits would be — the seam lm.py routes the
-    unified head loss through (ops/losses.py head_loss), keeping the tied
-    embedding the BOUNDARY-transformed one (under streaming ZeRO-3 the
-    gathered copy, not the caller's shard).
+    ``head_fn``: when given, called as ``head_fn(h, table)`` on the
+    final-norm hidden states in place of the logits matmul and its result
+    returned where logits would be — the seam lm.py routes the unified
+    head loss through (ops/losses.py head_loss).  ``table`` is the output
+    table: the tied embedding, the BOUNDARY-transformed one (under
+    streaming ZeRO-3 the gathered copy, not the caller's shard), or
+    ``params["lm_head"]`` where the model has a head of its own
+    (``tie_embeddings=False``).
+
+    ``return_stats`` (with ``return_aux``): the result is ``(logits, aux,
+    stats)``, ``stats`` the dropless routed layers' counters merged over
+    the layers (``ops/moe.merge_stats``), None for a model without them.
     """
     if remat not in (None, "none", "full", "selective"):
         raise ValueError(
@@ -462,6 +602,7 @@ def apply(
     if pos is None:
         pos = pos0 + jnp.arange(x.shape[1])
     aux_total = jnp.zeros((), jnp.float32)
+    stats_total = None
 
     use_remat = remat in ("full", "selective")
     remat_policy = (jax.checkpoint_policies.save_only_these_names(
@@ -477,7 +618,8 @@ def apply(
                 pos=pos_in, attn_impl=attn_impl, seq_axis=seq_axis,
                 seq_layout=seq_layout, tp_axis=tp_axis, ep_axis=ep_axis,
                 matmul_dtype=matmul_dtype,
-                save_attn=remat == "selective")
+                save_attn=remat == "selective", kind=cfg.attn_kind(_i),
+                with_stats=cfg.moe_dropless)
 
         if use_remat:
             # prevent_cse=False: inside jit/shard_map the CSE concern
@@ -485,16 +627,23 @@ def apply(
             # as the pipeline stage remat, parallel/pipeline.py)
             run = jax.checkpoint(run, policy=remat_policy,
                                  prevent_cse=False)
-        x, aux = run(params[f"layer{i}"], x, pos)
+        if cfg.moe_dropless:
+            x, aux, stats = run(params[f"layer{i}"], x, pos)
+            stats_total = moe_ops.merge_stats(stats_total, stats)
+        else:
+            x, aux = run(params[f"layer{i}"], x, pos)
         aux_total = aux_total + aux
 
     if boundary is not None:
         params = boundary(cfg.n_layers + 1, params)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     if head_fn is not None:
-        out = head_fn(x, params["embed"])
+        out = head_fn(x, table)
     else:
-        out = x.astype(jnp.float32) @ params["embed"].T.astype(jnp.float32)
+        out = x.astype(jnp.float32) @ table.T.astype(jnp.float32)
+    if return_aux and return_stats:
+        return out, aux_total, stats_total
     if return_aux:
         return out, aux_total
     return out

@@ -81,6 +81,12 @@ def _decode_compiler_params(q: Array, cache: Array, block_k: int,
     return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
+def _check_window(window, causal) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window!r} needs causal=True and a "
+                         f"window of at least one key")
+
+
 # ---------------------------------------------------------------------------
 # Reference attention (the correctness oracle)
 # ---------------------------------------------------------------------------
@@ -88,7 +94,7 @@ def _decode_compiler_params(q: Array, cache: Array, block_k: int,
 def attention_reference(
     q: Array, k: Array, v: Array, *, causal: bool = False,
     sm_scale: float | None = None, with_lse: bool = False,
-    bias: Array | None = None,
+    bias: Array | None = None, window: int | None = None,
 ):
     """Plain XLA attention over (B, H, S, D) tensors.
 
@@ -97,7 +103,10 @@ def attention_reference(
     ring attention needs to merge partial results across sequence chunks.
     ``bias`` is an additive score bias broadcastable to (B, H, Sq, Sk)
     (e.g. the NEG_INF cache-validity mask of KV-cache decode, generate.py).
+    ``window`` (causal only): a query sees the ``window`` newest keys up to
+    and including its own position (key > query - window).
     """
+    _check_window(window, causal)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
@@ -109,6 +118,8 @@ def attention_reference(
         qi = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         kj = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
         s = jnp.where(qi + (sk - sq) >= kj, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(kj > qi + (sk - sq) - window, s, NEG_INF)
     lse = jax.nn.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
     o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
@@ -122,9 +133,41 @@ def attention_reference(
 # Flash attention: forward kernel
 # ---------------------------------------------------------------------------
 
+def _causal_mask(s, i, j, block_q: int, block_k: int, window: int | None):
+    """Scores of q block i against k block j with the keys a query may not
+    see at NEG_INF: those after it and, with ``window``, those more than
+    ``window - 1`` positions before it."""
+    qi = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    kj = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    s = jnp.where(qi >= kj, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(kj > qi - window, s, NEG_INF)
+    return s
+
+
+def _in_band(i, j, block_q: int, block_k: int, window: int):
+    """Whether k block j holds a key inside the window of some query of q
+    block i (the causal side is the kernels' own predicate)."""
+    return j * block_k + block_k - 1 > i * block_q - window
+
+
+def _band_k_blocks(i, block_q: int, block_k: int, window: int):
+    """First and last k block that q block i's band touches."""
+    lo = jnp.maximum(i * block_q - window + 1, 0) // block_k
+    return lo, (i * block_q + block_q - 1) // block_k
+
+
+def _band_q_blocks(j, block_q: int, block_k: int, window: int, nq: int):
+    """First and last q block whose band touches k block j."""
+    hi = jnp.minimum((j * block_k + block_k - 2 + window) // block_q, nq - 1)
+    return (j * block_k) // block_q, hi
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, sm_scale: float, causal: bool,
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, window: int | None = None):
     """Grid (BH, num_q, num_k); the k dimension is innermost/sequential, so
     the VMEM scratch (acc/m/l) carries the online-softmax state across k
     blocks of one q block."""
@@ -144,6 +187,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     # check then rejects block loads on shard_map-varying inputs (a traced
     # cond keeps CPU interpret tests working; Mosaic folds it on TPU).
     live = (j * block_k <= i * block_q + block_q - 1) if causal else (j >= 0)
+    if window is not None:   # the band's far side: dead blocks are skipped
+        live &= _in_band(i, j, block_q, block_k, window)
 
     @pl.when(live)
     def _compute():
@@ -152,11 +197,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
             q, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
         if causal:
-            qi = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kj = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qi >= kj, s, NEG_INF)
+            s = _causal_mask(s, i, j, block_q, block_k, window)
         m_prev = m_ref[:, :1]                          # (bq, 1)
         l_prev = l_ref[:, :1]                          # (bq, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)      # (bq, 1)
@@ -193,21 +234,38 @@ def _vma(*arrays):
     return out
 
 
-def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
+def _kv_index(block_q: int, block_k: int, window: int | None):
+    """Index map of a K/V tile on the (BH, num_q, num_k) grid.  With a
+    window the k index is held inside q block i's band, so a dead step
+    names the tile the pipeline already holds and its copy is skipped, as
+    the decode kernel skips dead pages."""
+    if window is None:
+        return lambda b, i, j: (b, j, 0)
+
+    def index(b, i, j):
+        lo, hi = _band_k_blocks(i, block_q, block_k, window)
+        return b, jnp.clip(j, lo, hi), 0
+
+    return index
+
+
+def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret,
+         window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
     nq, nk = sq // block_q, sk // block_k
     vma = _vma(q, k, v)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, window=window)
+    kv_index = _kv_index(block_q, block_k, window)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -233,7 +291,7 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, sm_scale: float, causal: bool,
-                   block_q: int, block_k: int):
+                   block_q: int, block_k: int, window: int | None = None):
     """Grid (BH, num_q, num_k), k innermost: accumulate dQ for one q block.
 
     ``delta`` is precomputed outside the kernel as rowsum(do*o) - dlse, so
@@ -248,6 +306,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     live = (j * block_k <= i * block_q + block_q - 1) if causal else (j >= 0)
+    if window is not None:   # the band's far side: dead blocks are skipped
+        live &= _in_band(i, j, block_q, block_k, window)
 
     @pl.when(live)
     def _compute():
@@ -256,11 +316,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             q, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            qi = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kj = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qi >= kj, s, NEG_INF)
+            s = _causal_mask(s, i, j, block_q, block_k, window)
         p = jnp.exp(s - lse_ref[0, 0][:, None])        # (bq, bk)
         do = do_ref[0].astype(jnp.float32)
         delta = delta_ref[0, 0][:, None]               # (bq, 1)
@@ -280,7 +336,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, sm_scale: float, causal: bool,
-                    block_q: int, block_k: int):
+                    block_q: int, block_k: int, window: int | None = None):
     """Grid (BH, num_k, num_q), q innermost: accumulate dK/dV for one k block."""
     j, i = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
@@ -291,6 +347,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     live = (i * block_q + block_q - 1 >= j * block_k) if causal else (i >= 0)
+    if window is not None:
+        live &= _in_band(i, j, block_q, block_k, window)
 
     @pl.when(live)
     def _compute():
@@ -299,11 +357,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             q, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         if causal:
-            qi = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kj = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qi >= kj, s, NEG_INF)
+            s = _causal_mask(s, i, j, block_q, block_k, window)
         p = jnp.exp(s - lse_ref[0, 0][:, None])        # (bq, bk)
         do = do_ref[0].astype(jnp.float32)
         delta = delta_ref[0, 0][:, None]               # (bq, 1)
@@ -325,12 +379,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
-         dlse=None):
+         dlse=None, window=None):
     q, k, v, o, lse = residuals
     bh, sq, d = q.shape
     sk = k.shape[1]
     nq, nk = sq // block_q, sk // block_k
     vma = _vma(q, k, v, o, do, lse)
+    kv_index = _kv_index(block_q, block_k, window)
+    if window is None:
+        def q_block(j, i):
+            return i
+    else:   # the dK/dV grid's q tiles, held inside k block j's band
+        def q_block(j, i):
+            return jnp.clip(i, *_band_q_blocks(j, block_q, block_k, window,
+                                               nq))
 
     # delta = rowsum(do*o) - dlse, packed (bh, 8, sq) like lse.  Folding the
     # lse cotangent here is exact: d s from lse is dlse*p, so
@@ -343,12 +405,12 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, d), kv_index),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
@@ -361,15 +423,19 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         grid=(bh, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, d),
+                         lambda b, j, i: (b, q_block(j, i), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, block_q, d),
+                         lambda b, j, i: (b, q_block(j, i), 0)),
+            pl.BlockSpec((1, 8, block_q),
+                         lambda b, j, i: (b, 0, q_block(j, i))),
+            pl.BlockSpec((1, 8, block_q),
+                         lambda b, j, i: (b, 0, q_block(j, i))),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -392,39 +458,43 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
 # Public entry point
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    o, _ = _fwd(q, k, v, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret, window):
+    o, _ = _fwd(q, k, v, sm_scale=sm_scale, causal=causal, block_q=block_q,
+                block_k=block_k, interpret=interpret, window=window)
     return o
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, sm_scale=sm_scale, causal=causal,
-                  block_q=block_q, block_k=block_k, interpret=interpret)
+def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+               window):
+    o, lse = _fwd(q, k, v, sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, interpret=interpret, window=window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
-    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, g)
+def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, window, res, g):
+    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
+                window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+               window):
     """(o, lse) variant: lse is a differentiable OUTPUT (its cotangent from
     an online-softmax merge folds into the backward's delta term) — the
     kernel form ring attention needs (parallel/context.py)."""
-    o, lse = _fwd(q, k, v, sm_scale=sm_scale, causal=causal,
-                  block_q=block_q, block_k=block_k, interpret=interpret)
+    o, lse = _fwd(q, k, v, sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, interpret=interpret, window=window)
     return o, lse[:, 0]
 
 
-def _flash_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, sm_scale=sm_scale, causal=causal,
-                  block_q=block_q, block_k=block_k, interpret=interpret)
+def _flash_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+                   window):
+    o, lse = _fwd(q, k, v, sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, interpret=interpret, window=window)
     # Selective-remat seam (models/transformer.py remat="selective"): name
     # the kernel's OWN residuals so a save_only_these_names policy can pin
     # exactly (o, lse) — the remat backward then rebuilds q/k/v from the
@@ -435,10 +505,11 @@ def _flash_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return (o, lse[:, 0]), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(sm_scale, causal, block_q, block_k, interpret, res, g):
+def _flash_lse_bwd(sm_scale, causal, block_q, block_k, interpret, window,
+                   res, g):
     do, dlse = g
     return _bwd(sm_scale, causal, block_q, block_k, interpret, res, do,
-                dlse=dlse)
+                dlse=dlse, window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -683,6 +754,7 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool | None = None,
     with_lse: bool = False,
+    window: int | None = None,
 ) -> Array | tuple[Array, Array]:
     """Tiled attention over (B, H, S, D); differentiable (custom VJP).
 
@@ -694,9 +766,19 @@ def flash_attention(
     With ``with_lse`` also returns the row logsumexp (B, H, S) as a second
     differentiable output — the contract ring attention's online-softmax
     merge needs (the lse cotangent is handled exactly in the backward).
+
+    ``window`` (causal only): a query sees the ``window`` newest keys up to
+    and including its own position (key > query - window).  The three
+    kernels skip the blocks that lie wholly before the band as they skip
+    those after the diagonal, compute and copy both, and mask inside the
+    blocks the band's edge crosses.  A window that covers the sequence is
+    plain causal attention; ``None`` traces the kernels without a window.
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, H, S, D) q, got {q.shape}")
+    _check_window(window, causal)
+    if window is not None and window >= q.shape[2]:
+        window = None
     b, h, sq, d = q.shape
     sk = k.shape[2]
     block_q = _fit_block(DEFAULT_BLOCK_Q, sq) if block_q is None else min(
@@ -717,9 +799,10 @@ def flash_attention(
                   v.reshape(b * h, sk, d))
     if with_lse:
         o, lse = _flash_lse(qf, kf, vf, sm_scale, causal,
-                            block_q, block_k, interpret)
+                            block_q, block_k, interpret, window)
         return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
-    o = _flash(qf, kf, vf, sm_scale, causal, block_q, block_k, interpret)
+    o = _flash(qf, kf, vf, sm_scale, causal, block_q, block_k, interpret,
+               window)
     return o.reshape(b, h, sq, d)
 
 

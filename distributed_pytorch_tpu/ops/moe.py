@@ -1,6 +1,25 @@
-"""Mixture-of-Experts layer with expert parallelism (all_to_all dispatch).
+"""Mixture-of-Experts layers: two routed paths.
 
-The last parallelism axis the framework adds (the reference has none of
+**Which path is used when.**  ``moe_apply`` (first, below) is the capacity
+path: top-1 / top-2 or expert-choice routing through a one-hot ``(T, E, C)``
+dispatch tensor, tokens over an expert's capacity dropped, experts exchanged
+over a mesh axis with all_to_all.  It is what ``TransformerConfig(n_experts=
+E)`` runs and what decode, serving, EP x TP and the pipeline use.  Its
+dispatch tensor grows as T x E x C, so it is for few, wide experts.
+``moe_dropless_apply`` (``TransformerConfig(moe_dropless=True)``) is the
+sorted path for many narrow experts: top-k of any k over all E published
+experts, the ``T x k`` picks sorted by expert and taken through grouped
+matrix products (``lax.ragged_dot``), **no capacity and no dropped token at
+any imbalance**.  It is told which **experts it holds** -- ``(first_expert,
+how many)``: the contiguous slice of the E routed experts whose weights live
+on this chip -- routes over all E, and returns the part of the layer's
+result that its own experts give; picks of experts held elsewhere add
+nothing here, while their weight still takes its part of the softmax over
+the k picks.  The shares of all holders add up to the whole layer.  It runs
+on one chip's share without an exchange (training only; with an ``axis`` it
+refuses: the exchange is not written yet).
+
+The capacity path, as first written -- the last parallelism axis the framework adds (the reference has none of
 this — SURVEY.md section 5): a Switch-style top-1-routed MoE MLP whose
 experts are sharded over a mesh axis.  Design:
 
@@ -39,6 +58,7 @@ so the whole layer compiles into one XLA program.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -51,20 +71,24 @@ Array = jax.Array
 PyTree = Any
 
 
-def moe_init(key: Array, d_model: int, d_ff: int, n_experts: int) -> PyTree:
-    """Router + per-expert SwiGLU stacks.  To expert-shard, split the
+def moe_init(key: Array, d_model: int, d_ff: int, n_experts: int,
+             held: int | None = None) -> PyTree:
+    """Router + per-expert gated-MLP stacks.  To expert-shard, split the
     leading expert dim of w_gate/w_up/w_down over the mesh axis (the router
-    stays replicated)."""
+    stays replicated).  ``held``: the stacks hold only that many experts
+    (one chip's share for ``moe_dropless_apply``); the router keeps all
+    ``n_experts`` outputs."""
     ks = jax.random.split(key, 4)
+    held = n_experts if held is None else held
 
     def dense(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
     return {
         "router": dense(ks[0], (d_model, n_experts), d_model),
-        "w_gate": dense(ks[1], (n_experts, d_model, d_ff), d_model),
-        "w_up": dense(ks[2], (n_experts, d_model, d_ff), d_model),
-        "w_down": dense(ks[3], (n_experts, d_ff, d_model), d_ff),
+        "w_gate": dense(ks[1], (held, d_model, d_ff), d_model),
+        "w_up": dense(ks[2], (held, d_model, d_ff), d_model),
+        "w_down": dense(ks[3], (held, d_ff, d_model), d_ff),
     }
 
 
@@ -240,3 +264,152 @@ def moe_apply(
 
     out = jnp.einsum("tec,ecd->td", combine, yout)       # (T, D)
     return out, aux.astype(jnp.float32)
+
+
+
+# ---------------------------------------------------------------------------
+# The dropless path: sorted picks, grouped products over the experts held
+# ---------------------------------------------------------------------------
+
+# Rows of the buffer are laid out in whole tiles an expert: XLA's grouped
+# product on the TPU walks the rows in tiles of 512 and visits a tile once
+# for every expert with a row in it, so where a group starts inside a tile
+# the layer's time follows the routing (read on the chip: 2.4% of a step
+# between seeds).  Aligned, an expert of n rows costs ceil(n / 512) visits
+# wherever it lies.
+ROW_TILE = 512
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_picks(x, src, dest, k):
+    """One row of ``x`` (T, D) for every buffer row: ``x[src // k]``, where
+    ``src`` names the pick a row holds (nought for an empty row: the
+    caller masks those).  Its backward is a gather too (through ``dest``,
+    the row each pick went to), where autodiff would scatter-add T x k
+    rows."""
+    return x[src // k]
+
+
+def _rows_of_picks_fwd(x, src, dest, k):
+    return x[src // k], dest
+
+
+def _rows_of_picks_bwd(k, dest, g):
+    return g[dest].reshape(-1, k, g.shape[-1]).sum(1), None, None
+
+
+_rows_of_picks.defvjp(_rows_of_picks_fwd, _rows_of_picks_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_picks(ys, src, dest):
+    """Buffer rows back in pick order, ``ys[dest]``; backward ``g[src]``."""
+    return ys[dest]
+
+
+_rows_to_picks.defvjp(lambda ys, src, dest: (ys[dest], src),
+                      lambda src, g: (g[src], None, None))
+
+
+def merge_stats(total: dict | None, layer: dict) -> dict:
+    """The counters of several dropless layers as one set: rows and drops
+    add up, the load figure is the worst layer's."""
+    if total is None:
+        return layer
+    return {"rows_here": total["rows_here"] + layer["rows_here"],
+            "dropped": total["dropped"] + layer["dropped"],
+            "load_max_over_mean": jnp.maximum(total["load_max_over_mean"],
+                                              layer["load_max_over_mean"])}
+
+
+def moe_dropless_apply(
+    params: PyTree,
+    x: Array,                      # (T, D) the experts' input
+    *,
+    top_k: int,
+    first_expert: int = 0,         # index of the first expert held here
+    router_input: Array | None = None,   # (T, D) what the router reads
+    act: str = "silu",             # the gate branch: 'silu' | 'relu'
+    axis: str | None = None,
+) -> tuple[Array, dict]:
+    """The part of a routed layer's result that the experts held here give:
+    ``(out (T, D), stats)``.
+
+    ``params["router"]`` is (D, E) over all E published experts;
+    ``params["w_gate" | "w_up" | "w_down"]`` stack the ``held`` experts
+    ``first_expert .. first_expert + held - 1``.  Per token: the router's
+    logits in float32, the ``top_k`` largest, a softmax over those k; each
+    pick of an expert held here contributes ``weight * down(act(gate(x)) *
+    up(x))``.  The picks of held experts are laid out by expert in a row
+    buffer, each expert's rows from a tile boundary on (``ROW_TILE``), one
+    input row is gathered per pick, and the three products run as
+    ``lax.ragged_dot`` over the held experts' groups.  The buffer holds all
+    ``T x k`` picks and a tile of padding an expert, the worst case, so no
+    pick is cut however uneven the routing: with every token on one expert
+    the result is still exact.  Empty rows and rows past the last group are
+    masked on both sides of the products.
+
+    ``stats``: ``rows_here`` (picks routed to held experts),
+    ``load_max_over_mean`` (largest group over the mean group) and
+    ``dropped`` (picks of held experts that reached no product: 0), float32
+    scalars of this call.
+    """
+    if axis is not None:
+        raise NotImplementedError(
+            f"moe_dropless_apply over the mesh axis {axis!r}: the dropless "
+            f"path has no exchange yet (one chip's share only); use "
+            f"moe_apply for expert parallelism over an axis")
+    if act not in ("silu", "relu"):
+        raise ValueError(f"act must be 'silu' or 'relu', got {act!r}")
+    t, d = x.shape
+    e = params["router"].shape[-1]
+    held = params["w_gate"].shape[0]
+    if not 1 <= top_k <= e:
+        raise ValueError(f"top_k={top_k} of {e} experts")
+    if first_expert < 0 or first_expert + held > e:
+        raise ValueError(f"experts {first_expert}..{first_expert + held - 1} "
+                         f"are not among the router's {e}")
+    xr = x if router_input is None else router_input
+    logits = jnp.dot(xr.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)           # (T, E)
+    top_logits, top_idx = lax.top_k(logits, top_k)              # (T, K)
+    weights = jax.nn.softmax(top_logits, axis=-1)
+
+    # -- a buffer row for every pick of a held expert, sorted by expert ----
+    # each expert's rows start on a tile boundary; picks of experts held
+    # elsewhere all go to the last row, in a tile no product reads
+    local = top_idx - first_expert
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).reshape(t * top_k)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    padded = (sizes + ROW_TILE - 1) // ROW_TILE * ROW_TILE
+    n_rows = t * top_k + (held + 1) * ROW_TILE      # the worst case
+    shift = jnp.cumsum(padded) - padded - (jnp.cumsum(sizes) - sizes)
+    rank = jnp.argsort(jnp.argsort(group, stable=True))  # place when sorted
+    dest = jnp.where(here.reshape(-1),
+                     rank + jnp.append(shift, 0)[group], n_rows - 1)
+    src = jnp.full((n_rows,), -1, jnp.int32).at[dest].set(
+        jnp.arange(t * top_k, dtype=jnp.int32))
+    live = ((src >= 0) & (jnp.arange(n_rows) < jnp.sum(padded)))[:, None]
+    src = jnp.maximum(src, 0)
+    rows_here = jnp.sum(sizes)
+
+    # -- the held experts over their rows ----------------------------------
+    dt = x.dtype
+    xs = jnp.where(live, _rows_of_picks(x, src, dest, top_k), 0)
+    gate = lax.ragged_dot(xs, params["w_gate"].astype(dt), padded)
+    gate = jax.nn.relu(gate) if act == "relu" else jax.nn.silu(gate)
+    up = lax.ragged_dot(xs, params["w_up"].astype(dt), padded)
+    ys = lax.ragged_dot(gate * up, params["w_down"].astype(dt), padded)
+    ys = jnp.where(live, ys, 0)
+
+    # -- weight and add each token's picks ---------------------------------
+    w = jnp.where(here, weights, 0.0).astype(dt)
+    out = jnp.sum(_rows_to_picks(ys, src, dest).reshape(t, top_k, d)
+                  * w[..., None], axis=1)
+    reached = jnp.sum(live[dest, 0] & here.reshape(-1))
+    mean = jnp.maximum(rows_here, 1) / held
+    stats = {"rows_here": rows_here, "dropped": rows_here - reached,
+             "load_max_over_mean": jnp.max(sizes) / mean}
+    return out, {k: v.astype(jnp.float32) for k, v in stats.items()}

@@ -279,6 +279,7 @@ class ContinuousBatcher:
                  overlap: bool = True,
                  kv_dtype=None,
                  mesh=None, tp_axis: str = "model"):
+        tfm.require_servable(cfg, "ContinuousBatcher")
         self.params = params
         self.cfg = cfg
         self.slots = slots

@@ -414,7 +414,8 @@ def disable() -> None:
 
 def emit_train_steps(tel: Telemetry, t0: float, step0: int, k: int,
                      losses, oks, mets, *, span_name: str = "train_steps",
-                     phase: str = "train", defer: bool = False) -> None:
+                     phase: str = "train", defer: bool = False,
+                     extra_gauges: tuple = ()) -> None:
     """The ONE train-dispatch emission both trainers share (train.py /
     lm.py): a span for the dispatch plus per-step gauges for the
     device-side scalars that ride the in-scan health-flag output —
@@ -427,7 +428,9 @@ def emit_train_steps(tel: Telemetry, t0: float, step0: int, k: int,
     last at ``flush``/``close``), so a gauge's ``ts`` is then a dispatch
     late while its ``step`` is exact.  Only ever called with an active
     registry, so telemetry-off pays nothing.  numpy imports lazily: this
-    module must stay cheap and jax-free for the launcher agent."""
+    module must stay cheap and jax-free for the launcher agent.
+    ``extra_gauges``: names of what a step's metric vector carries after
+    the two norms (lm.MOE_METRICS), one gauge each."""
     dur = time.perf_counter() - t0
     step0, k = int(step0), int(k)
     tel.span_at(span_name, t0, dur, phase=phase, step0=step0, k=k)
@@ -437,12 +440,14 @@ def emit_train_steps(tel: Telemetry, t0: float, step0: int, k: int,
 
         loss = np.asarray(losses).reshape(-1)
         ok = np.asarray(oks).reshape(-1)
-        met = np.asarray(mets).reshape(-1, 2)
+        met = np.asarray(mets).reshape(-1, 2 + len(extra_gauges))
         for i in range(k):
             s = step0 + i
             tel.gauge("loss", float(loss[i]), phase=phase, step=s)
             tel.gauge("grad_norm", float(met[i, 0]), phase=phase, step=s)
             tel.gauge("param_norm", float(met[i, 1]), phase=phase, step=s)
+            for name, value in zip(extra_gauges, met[i, 2:]):
+                tel.gauge(name, float(value), phase=phase, step=s)
             if float(ok[i]) < 1.0:
                 tel.event("unhealthy_step", phase=phase, step=s,
                           ok=float(ok[i]))

@@ -79,6 +79,53 @@ def test_flash_forward_backward_compiles(chip):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_flash_at_the_routed_cells_row_compiles(chip, window):
+    """``smallthinker_train_8k``'s attention: one 8,192-token row of 28
+    heads x 128, the NoPE-global layers without a window and the others
+    over 4,096 keys.  The windowed kernels' index maps hold a dead step on
+    the tile the pipeline has (``jnp.clip`` of a block index): VMEM and
+    tiling as the chip's compiler sees them, forward, dQ and dK/dV."""
+    qkv = ((1, 28, 8192, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return att.flash_attention(q, k, v, causal=True, window=window,
+                                   interpret=False).astype(jnp.float32).sum()
+
+    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                        qkv, qkv, qkv)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_grouped_products_compile_to_the_chips_own_kernel(chip):
+    """The dropless routed layer at the routed cell's shapes (49,152 picks
+    of 2,560 over 16 held experts of 768), forward and backward: XLA lowers
+    ``lax.ragged_dot`` on the TPU to Mosaic kernels of its own (so the step
+    holds two families of ``tpu_custom_call`` and the benchmark's readers
+    tell them apart by name, ``benchmarks/routed_ops.py``), with no dense
+    (rows, experts, ...) expansion: the temporaries stay under 2 GiB."""
+    from distributed_pytorch_tpu.ops import moe
+
+    def loss(params, x):
+        out, _ = moe.moe_dropless_apply(params, x, top_k=6, act="relu")
+        return out.astype(jnp.float32).sum()
+
+    shapes = {"router": (2560, 64), "w_gate": (16, 2560, 768),
+              "w_up": (16, 2560, 768), "w_down": (16, 768, 2560)}
+    x = jax.ShapeDtypeStruct((8192, 2560), jnp.bfloat16, sharding=chip)
+    params = {k: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=chip)
+              for k, dims in shapes.items()}
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 9     # 3 products x (fwd, dx, dw)
+    # the backward of a row gather is a gather too: no scatter of rows
+    # (the two that remain are top-k's into (8192, 64) and the bincount's)
+    assert not [line for line in text.split("\n") if " scatter(" in line
+                and "2560" in line.split(" scatter(")[0]]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
 @pytest.mark.parametrize("case", ["dense_bf16", "dense_int8_block512",
                                   "paged_bf16", "paged_int8"])
 def test_decode_attention_compiles(chip, case):

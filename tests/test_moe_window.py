@@ -1,0 +1,356 @@
+"""The mechanisms a routed, two-kind decoder needs on the training path
+(SmallThinker's block): windowed flash attention, the dropless routed layer
+told which experts it holds, an untied head, per-layer attention kinds --
+each against plain code, at small sizes on the CPU with seeded weights.
+
+The plain side is the benchmark's reference for the family
+(``benchmarks/families/moe_window_gqa/reference.py``: float32 ``jax.numpy``,
+experts as a masked loop), reached as the benchmark reaches it, and the
+trainer is built as the benchmark builds it (``program.build_trainer``).
+Tolerances: both sides compute in float32 here, so they differ by the order
+of float32 sums alone; ``TOL`` = 2e-5 of the largest entry admits that
+(readings: 1e-7 to 2e-6) and is a thousandth of what one expert left out,
+one wrong pick or a window one key too wide would move.
+"""
+
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_pytorch_tpu import lm
+from distributed_pytorch_tpu.generate import generate
+from distributed_pytorch_tpu.models import transformer as tfm
+from distributed_pytorch_tpu.ops import attention as att
+from distributed_pytorch_tpu.ops import moe
+from distributed_pytorch_tpu.serve import ContinuousBatcher
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+TOL = 2e-5
+T, D, F, E, K = 96, 32, 16, 16, 6
+EPS = 1e-6
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """The benchmark's modules, as its own entry points import them."""
+    sys.path.insert(0, BENCH)
+    import checks
+    import families
+    import program
+    import reference
+    import weights
+
+    yield {"family": families.load("moe_window_gqa"), "reference": reference,
+           "weights": weights, "program": program, "checks": checks}
+    sys.path.remove(BENCH)
+
+
+def close(a, b, tol=TOL):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    # entries here are of order one; a result that is exactly nought (the
+    # gradient on q of a one-key window) is held to the same absolute size
+    return np.abs(a - b).max() <= tol * max(np.abs(b).max(), 0.1)
+
+
+def normed(x):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS)
+
+
+def reference_routed(bench, params, x, first):
+    """What the held experts add, by the family's reference: its ``layer``
+    on a tree whose attention adds nothing (wo = 0) and whose norm scales
+    are one, so the router and the experts both read ``normed(x)`` and
+    ``out - x`` is the routed part alone."""
+    zero = {"wq": jnp.zeros((D, 2, 16)), "wk": jnp.zeros((D, 1, 16)),
+            "wv": jnp.zeros((D, 1, 16)), "wo": jnp.zeros((2, 16, D))}
+    lp = {"attn_norm": jnp.ones((D,)), "mlp_norm": jnp.ones((D,)),
+          "attn_global_nope": zero, "moe": params}
+    cfg = {"rms_norm_eps": EPS, "num_attention_heads": 2,
+           "num_key_value_heads": 1, "head_dim": 16,
+           "moe_num_active_primary_experts": K, "moe_first_expert": first,
+           "moe_num_primary_experts": params["w_gate"].shape[0]}
+    with jax.default_matmul_precision("highest"):
+        return bench["family"].reference.layer(
+            lp, x, jnp.arange(x.shape[0]), cfg, None) - x
+
+
+def program_routed(params, x, first):
+    with jax.default_matmul_precision("highest"):
+        return moe.moe_dropless_apply(params, normed(x), top_k=K,
+                                      first_expert=first, act="relu")
+
+
+def share_of(full, first, held):
+    return {"router": full["router"],
+            **{k: full[k][first:first + held]
+               for k in ("w_gate", "w_up", "w_down")}}
+
+
+@pytest.fixture(scope="module")
+def routed():
+    return (moe.moe_init(jax.random.key(1), D, F, E),
+            jax.random.normal(jax.random.key(2), (T, D)))
+
+
+@pytest.mark.parametrize("first,held", [(4, 4), (0, E)])
+def test_dropless_layer_agrees_with_the_reference(bench, routed, first, held):
+    """k = 6 of 16, with 4 held and with all held: forward, and the
+    gradients on every leaf and on the input."""
+    full, x = routed
+    params = share_of(full, first, held)
+    out, stats = program_routed(params, x, first)
+    assert close(out, reference_routed(bench, params, x, first))
+    assert float(stats["dropped"]) == 0
+    assert 0 < float(stats["rows_here"]) <= T * K
+
+    def loss(f):
+        return lambda p, x: jnp.sum(f(p, x) * jnp.cos(jnp.arange(D)))
+
+    got = jax.grad(loss(lambda p, x: program_routed(p, x, first)[0]),
+                   (0, 1))(params, x)
+    want = jax.grad(loss(lambda p, x: reference_routed(bench, p, x, first)),
+                    (0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert close(g, w)
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer(bench, routed):
+    full, x = routed
+    parts = [program_routed(share_of(full, first, 4), x, first)
+             for first in range(0, E, 4)]
+    assert close(sum(out for out, _ in parts),
+                 reference_routed(bench, full, x, 0))
+    # every pick lands on exactly one share
+    assert sum(float(st["rows_here"]) for _, st in parts) == T * K
+
+
+def test_every_token_on_one_expert_drops_nothing(bench, routed):
+    full, x = routed
+    # every token scores expert 3 far above the rest
+    x = jnp.abs(x)
+    params = dict(full, router=full["router"].at[:, 3].set(50.0))
+    out, stats = program_routed(params, x, 0)
+    assert float(stats["dropped"]) == 0
+    assert float(stats["rows_here"]) == T * K
+    assert float(stats["load_max_over_mean"]) == pytest.approx(E / K)
+    assert close(out, reference_routed(bench, params, x, 0))
+    # the share that holds expert 3 gets every token, exactly
+    mine = share_of(params, 0, 4)
+    out, stats = program_routed(mine, x, 0)
+    assert float(stats["dropped"]) == 0 and float(stats["rows_here"]) >= T
+    assert close(out, reference_routed(bench, mine, x, 0))
+
+
+def test_dropless_layer_refuses_an_axis(routed):
+    full, x = routed
+    with pytest.raises(NotImplementedError, match="no exchange"):
+        moe.moe_dropless_apply(full, x, top_k=K, axis="expert")
+
+
+# -- windowed flash attention -------------------------------------------------
+
+@pytest.fixture(scope="module")
+def qkv():
+    return [jax.random.normal(k, (1, 2, 512, 32), jnp.float32)
+            for k in jax.random.split(jax.random.key(0), 3)]
+
+
+@pytest.mark.parametrize("window,block_q,block_k",
+                         [(100, 128, 128), (129, 128, 128), (300, 128, 256),
+                          (37, 256, 128), (1, 128, 128)])
+def test_windowed_flash_agrees_with_plain_attention(qkv, window, block_q,
+                                                    block_k):
+    """Forward and all three gradients (interpret mode), with the band's
+    edge inside a block, just past a block boundary, wider than a block
+    and down to one key."""
+    def loss(f):
+        def of(q, k, v):
+            o = f(q, k, v)
+            return jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(o.shape)))
+        return of
+
+    def flash(q, k, v):
+        return att.flash_attention(q, k, v, causal=True, window=window,
+                                   block_q=block_q, block_k=block_k)
+
+    def plain(q, k, v):
+        return att.attention_reference(q, k, v, causal=True, window=window)
+
+    assert close(flash(*qkv), plain(*qkv))
+    for g, w in zip(jax.grad(loss(flash), (0, 1, 2))(*qkv),
+                    jax.grad(loss(plain), (0, 1, 2))(*qkv)):
+        assert close(g, w)
+
+
+def test_a_window_that_covers_the_row_is_plain_causal(qkv):
+    for window in (512, 4096):
+        got = att.flash_attention(*qkv, causal=True, window=window)
+        assert jnp.array_equal(got, att.flash_attention(*qkv, causal=True))
+    with pytest.raises(ValueError, match="causal"):
+        att.flash_attention(*qkv, causal=False, window=64)
+
+
+# -- the whole model ----------------------------------------------------------
+
+TINY = {"hidden_size": 64, "num_hidden_layers": 4, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "moe_ffn_hidden_size": 32,
+        "moe_num_primary_experts": 4, "moe_router_width": 16,
+        "moe_first_expert": 8, "moe_num_active_primary_experts": 6,
+        "sliding_window_size": 48, "vocab_size": 256, "rms_norm_eps": 1e-6,
+        "rope_theta": 1.5e6, "global_attention_every": 4,
+        "sliding_window_layout": [0, 1, 1, 1], "rope_layout": [0, 1, 1, 1],
+        "tie_word_embeddings": False}
+HP = {"lr": 3e-4, "weight_decay": 0.1, "b1": 0.9, "b2": 0.95,
+      "grad_clip": 1.0}
+
+
+def tiny_trainer(bench, remat="none"):
+    cell = {"family": bench["family"], "config_file": TINY,
+            "mix": {"trainer": {**HP, "compute_dtype": "float32",
+                                "loss_impl": "dense", "remat": remat}}}
+    return bench["program"].build_trainer(cell, jax.devices()[:1], seed=3)
+
+
+def batch(seed=0):
+    tok = np.random.default_rng(seed).integers(
+        0, TINY["vocab_size"], (2, 128)).astype(np.int32)
+    return tok, np.roll(tok, -1, 1)
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_whole_model_step_agrees_with_the_reference(bench, remat):
+    """A period of four (one NoPE-global layer, three windowed), untied
+    head, window shorter than the row, experts 8-11 of 16 held, through
+    ``LMTrainer``'s step: the loss, the first gradient as AdamW gets it and
+    the parameters' change, leaf by leaf, as the benchmark compares them.
+    Float32 on both sides: 1e-4 admits reassociation (readings under 1e-5)
+    and is far under one wrong pick or one layer of the wrong kind."""
+    fam, ref, prog = bench["family"], bench["reference"], bench["program"]
+    trainer = tiny_trainer(bench, remat)
+    start = bench["weights"].make_params(fam, 7, TINY)
+    assert jax.tree.structure(start) == jax.tree.structure(trainer.params)
+    prog.reset_trainer(trainer, jax.tree.map(jnp.copy, start))
+    tok, tgt = batch()
+    loss = float(trainer.train_step(tok, tgt))
+    rows, load, dropped = np.asarray(trainer.last_metrics)[2:]
+    assert dropped == 0 and 0 < rows < 4 * tok.size * K and load >= 1
+    mine = {"losses": [loss],
+            "grad_norms": np.asarray(ref.leaf_norms(prog.adam_first_moment(
+                trainer.opt_state))) / (1 - HP["b1"]),
+            "delta_norms": np.asarray(ref.diff_norms(trainer.params, start))}
+    theirs = ref.with_delta_norms(
+        ref.train_steps(fam.reference, jax.tree.map(jnp.copy, start),
+                        [(tok, tgt)], TINY, HP), start)
+    numbers = bench["checks"].train_numbers(mine, theirs)
+    assert all(v < 1e-4 for v in numbers.values()), numbers
+
+
+def test_a_layer_of_the_wrong_kind_is_caught(bench):
+    """The plain attention path agrees with the reference's logits too, and
+    the comparison is not blind to a layer's kind: the same weights run
+    with the NoPE-global layer windowed and rotary read logits a thousand
+    tolerances off."""
+    import dataclasses
+
+    fam, ref = bench["family"], bench["reference"]
+    params = bench["weights"].make_params(fam, 7, TINY)
+    tok = jnp.asarray(batch()[0][0])
+    with jax.default_matmul_precision("highest"):
+        want = fam.reference.project(
+            fam.reference.head_params(params),
+            ref.hidden(fam.reference, params, tok, TINY), TINY, None)
+    good = fam.program.model_config(TINY)
+    wrong = dataclasses.replace(good, attn_kinds=("window",) * 4)
+    renamed = dict(params, layer0={
+        ("attn_window" if k == "attn_global_nope" else k): v
+        for k, v in params["layer0"].items()})
+    for cfg, tree, off in ((good, params, False), (wrong, renamed, True)):
+        with jax.default_matmul_precision("highest"):
+            got = tfm.apply(tree, tok[None], cfg=cfg,
+                            attn_impl="reference")[0]
+        assert close(got, want, 1e-4) != off
+        assert close(got, want, 1e-1) != off
+
+
+# sha256 of the lowered text of the dense model's train step below, taken at
+# the parent of the PR that brought windows, kinds, the dropless layer and
+# the untied head (commit 2b1df82) and equal on this tree: with the new
+# configuration fields at their defaults the program is today's
+DENSE_STEP = "d3f6e824bfe5e2d56d0a54313b05d83088220290b3da116f08871e08ab870645"
+
+
+def test_the_dense_models_step_program_is_unchanged():
+    cfg = lm.LMTrainConfig(model=tfm.TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128), dp=1)
+    mesh = lm.make_lm_mesh(cfg, devices=jax.devices()[:1])
+    trainer = lm.LMTrainer(cfg, mesh)
+    tok = jnp.zeros((2, 128), jnp.int32)
+    text = lm.make_lm_train_step(cfg, mesh).lower(
+        trainer.params, trainer.opt_state, tok, tok).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == DENSE_STEP
+    # and the dense tree is flat, tied and without a head of its own
+    assert {"wq", "w_gate"} <= set(trainer.params["layer0"])
+    assert "lm_head" not in trainer.params
+    assert np.asarray(trainer.last_metrics if trainer.last_metrics is not None
+                      else np.zeros(2)).shape == (2,)
+
+
+# -- what refuses it ----------------------------------------------------------
+
+@pytest.mark.parametrize("change,named", [
+    ({"attn_kinds": ("global", "window"), "attn_window": 8}, "windowed"),
+    ({"attn_kinds": ("global_nope", "global")}, "without rotary"),
+    ({"tie_embeddings": False}, "untied output head"),
+    ({"n_experts": 8, "moe_top_k": 2, "moe_dropless": True},
+     "dropless routed layer"),
+])
+def test_decode_and_serving_refuse_what_they_do_not_implement(change, named):
+    cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_layers=2,
+                                n_heads=2, head_dim=16, d_ff=32, **change)
+    params = tfm.init(jax.random.key(0), cfg)
+    with pytest.raises(NotImplementedError, match=named):
+        generate(params, jnp.zeros((1, 4), jnp.int32), jax.random.key(0),
+                 cfg=cfg, max_new=2)
+    with pytest.raises(NotImplementedError, match=named):
+        ContinuousBatcher(params, cfg, slots=2, max_len=32)
+
+
+@pytest.mark.parametrize("keywords,named", [
+    ({"tp": 2}, "tp=2"), ({"ep": 2, "dp": 1}, "ep=2"),
+    ({"grad_accum": 2}, "grad_accum=2"), ({"pp": 2}, "pipeline"),
+])
+def test_the_trainer_refuses_layouts_the_dropless_layer_lacks(bench, keywords,
+                                                              named):
+    cfg = lm.LMTrainConfig(model=bench["family"].program.model_config(TINY),
+                           **keywords)
+    with pytest.raises(ValueError, match=named):
+        lm.validate_lm_cfg(cfg)
+
+
+def test_windowed_layers_refuse_ring_attention(bench):
+    model = tfm.TransformerConfig(n_layers=2, attn_kinds=("global", "window"),
+                                  attn_window=8)
+    with pytest.raises(ValueError, match="ring attention has no window"):
+        lm.validate_lm_cfg(lm.LMTrainConfig(model=model, sp=2))
+
+
+def test_counters_reach_telemetry_by_name(bench, tmp_path):
+    from distributed_pytorch_tpu.utils import telemetry
+
+    trainer = tiny_trainer(bench)
+    telemetry.enable(str(tmp_path), rank=0)
+    try:
+        trainer.train_step(*batch())
+    finally:
+        telemetry.disable()     # flushes the step's deferred gauges
+    (_, records), = telemetry.read_run(str(tmp_path))
+    gauges = {r["name"]: r["value"] for r in records if r["type"] == "gauge"}
+    assert gauges["moe.dropped"] == 0 and gauges["moe.rows_here"] > 0
+    assert gauges["moe.load_max_over_mean"] >= 1
