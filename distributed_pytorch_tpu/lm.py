@@ -58,8 +58,12 @@ IGNORE = IGNORE_INDEX  # target id excluded from the loss (padding)
 # to ``LMTrainer.last_metrics`` after [grad-norm, param-norm], summed over
 # the step's layers and chips (the load figure: the worst layer's): picks
 # routed to experts held here, the largest expert's rows over the mean
-# expert's, picks that reached no product (0 by construction).
-MOE_METRICS = ("moe.rows_here", "moe.load_max_over_mean", "moe.dropped")
+# expert's, picks that reached no product (0 by construction), and the tiles
+# of the worst-case row buffer that the layers' loops worked over (the mean
+# over layers and chips: ~0.29 with a quarter of the experts held and even
+# routing, 1 with every pick held here).
+MOE_METRICS = ("moe.rows_here", "moe.load_max_over_mean", "moe.dropped",
+               "moe.live_tile_share")
 
 
 @dataclass
@@ -1345,7 +1349,8 @@ def _build_local_loss(cfg: LMTrainConfig, specs, *, dcn_sync: bool,
             return loss, jnp.stack([
                 jax.lax.psum(st["rows_here"], reduce_axes),
                 jax.lax.pmax(st["load_max_over_mean"], reduce_axes),
-                jax.lax.psum(st["dropped"], reduce_axes)])
+                jax.lax.psum(st["dropped"], reduce_axes),
+                jax.lax.pmean(st["live_tile_share"], reduce_axes)])
         return loss
 
     if stateful:

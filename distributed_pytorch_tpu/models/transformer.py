@@ -602,7 +602,7 @@ def apply(
     if pos is None:
         pos = pos0 + jnp.arange(x.shape[1])
     aux_total = jnp.zeros((), jnp.float32)
-    stats_total = None
+    layer_stats = []
 
     use_remat = remat in ("full", "selective")
     remat_policy = (jax.checkpoint_policies.save_only_these_names(
@@ -629,7 +629,7 @@ def apply(
                                  prevent_cse=False)
         if cfg.moe_dropless:
             x, aux, stats = run(params[f"layer{i}"], x, pos)
-            stats_total = moe_ops.merge_stats(stats_total, stats)
+            layer_stats.append(stats)
         else:
             x, aux = run(params[f"layer{i}"], x, pos)
         aux_total = aux_total + aux
@@ -643,7 +643,8 @@ def apply(
     else:
         out = x.astype(jnp.float32) @ table.T.astype(jnp.float32)
     if return_aux and return_stats:
-        return out, aux_total, stats_total
+        return out, aux_total, (moe_ops.merge_stats(layer_stats)
+                                if layer_stats else None)
     if return_aux:
         return out, aux_total
     return out

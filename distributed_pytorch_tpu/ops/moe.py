@@ -17,7 +17,15 @@ result that its own experts give; picks of experts held elsewhere add
 nothing here, while their weight still takes its part of the softmax over
 the k picks.  The shares of all holders add up to the whole layer.  It runs
 on one chip's share without an exchange (training only; with an ``axis`` it
-refuses: the exchange is not written yet).
+refuses: the exchange is not written yet).  **What its row buffer costs:**
+memory for the worst case (every pick held here, a tile of padding an
+expert), time for what arrived: each expert's rows fill whole tiles from the
+buffer's start, and everything beside the three products -- the rows
+gathered in, the gate, the rows summed back per token, and the transposes of
+all three in the backward -- runs in loops over the live tiles whose trip
+counts are read on the device, as XLA's grouped products skip the tiles past
+the last group (``moe.live_tile_share`` says what part of the buffer a step
+worked over).
 
 The capacity path, as first written -- the last parallelism axis the framework adds (the reference has none of
 this — SURVEY.md section 5): a Switch-style top-1-routed MoE MLP whose
@@ -66,6 +74,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from ..parallel import routing as _routing
+from ..utils import compat
 
 Array = jax.Array
 PyTree = Any
@@ -278,48 +287,240 @@ def moe_apply(
 # between seeds).  Aligned, an expert of n rows costs ceil(n / 512) visits
 # wherever it lies.
 ROW_TILE = 512
+# The buffer is allocated for the worst case and worked over its live part:
+# every loop over its rows takes CHUNK of them a trip (whole tiles) and makes
+# only the trips that hold a row of this step's routing.
+CHUNK = ROW_TILE
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_of_picks(x, src, dest, k):
-    """One row of ``x`` (T, D) for every buffer row: ``x[src // k]``, where
-    ``src`` names the pick a row holds (nought for an empty row: the
-    caller masks those).  Its backward is a gather too (through ``dest``,
-    the row each pick went to), where autodiff would scatter-add T x k
-    rows."""
-    return x[src // k]
+def _varying(x, axes):
+    """``x`` varying over the mesh axes ``axes`` as well (a loop's carry has
+    to start typed as its body leaves it)."""
+    missing = frozenset(axes) - compat.vma_of(x)
+    return compat.pcast(x, tuple(missing), to="varying") if missing else x
 
 
-def _rows_of_picks_fwd(x, src, dest, k):
-    return x[src // k], dest
+def _as_cotangent(g, primal):
+    """``g`` typed as the cotangent of ``primal``: summed over the mesh
+    axes it varies over and ``primal`` does not (what autodiff does for an
+    input that is the same on every shard), varying over the rest."""
+    extra = compat.vma_of(g) - compat.vma_of(primal)
+    return _varying(lax.psum(g, tuple(extra)) if extra else g,
+                    compat.vma_of(primal))
 
 
-def _rows_of_picks_bwd(k, dest, g):
-    return g[dest].reshape(-1, k, g.shape[-1]).sum(1), None, None
+def _over_live(f, n_live, *rows):
+    """``f(*chunks) -> tuple of (CHUNK, ...)`` over the chunks of ``rows``
+    (arrays whose leading dimension is the buffer's) that hold a live row,
+    written into buffers of the buffer's length.  The trip count is read on
+    the device; what lies past the last trip is never written
+    (``lax.empty``: on the TPU no fill), so a consumer reads live chunks
+    only, as XLA's grouped products do."""
+    n_rows = rows[0].shape[0]
+    outs = jax.eval_shape(lambda: f(*(r[:CHUNK] for r in rows)))
+    init = tuple(_varying(lax.empty((n_rows,) + o.shape[1:], o.dtype),
+                          (o.vma or frozenset()) | compat.vma_of(n_live))
+                 for o in outs)
+
+    def body(i, bufs):
+        lo = i * CHUNK
+        new = f(*(lax.dynamic_slice_in_dim(r, lo, CHUNK) for r in rows))
+        return tuple(lax.dynamic_update_slice_in_dim(b, o, lo, 0)
+                     for b, o in zip(bufs, new))
+
+    return lax.fori_loop(0, (n_live + CHUNK - 1) // CHUNK, body, init)
 
 
-_rows_of_picks.defvjp(_rows_of_picks_fwd, _rows_of_picks_bwd)
+def _rows(a, tok):
+    """Row ``tok[r]`` of ``a`` for each r, nought where ``tok[r] < 0``."""
+    return jnp.where((tok >= 0)[:, None], a[jnp.maximum(tok, 0)], 0)
+
+
+def _token(pick, lay):
+    """The token whose pick a buffer row holds, -1 for an empty row."""
+    return jnp.where(pick >= 0, pick // lay["dest"].shape[1], -1)
+
+
+def _collect(bufs, lay):
+    """``(T, D)``: for each token the sum, over its picks of held experts,
+    of the picks' rows of ``bufs`` (buffers, added), in float32.  The rows
+    read follow the picks that arrived: the tokens are ordered by how many
+    of their picks are held (``_layout``), so that "the r-th held pick"
+    exists for a prefix of them, and for r = 0 .. k-1 that prefix alone is
+    gathered, a chunk of tokens a trip of one loop; then the T sums go back
+    to token order.  (A scatter-add of each live tile into its tokens'
+    rows, sorted and unique as they are, reads fewer rows and was fifty
+    times slower on the chip: PERF.md, PR 29.)"""
+    front, trips = lay["front"], lay["trips"]
+    n_tok = front.shape[0] // trips.shape[0]
+    ends = jnp.cumsum(trips)
+
+    def add(i, acc):
+        r = jnp.sum(i >= ends)
+        # unsigned, so that the slices' starts are seen to be whole chunks
+        # (a signed start is wrapped first, and the update is then no
+        # longer done in place)
+        lo = (i - (ends[r] - trips[r])).astype(jnp.uint32) * CHUNK
+        ids = lax.dynamic_slice_in_dim(
+            front, r.astype(jnp.uint32) * n_tok + lo, CHUNK)
+        rows = sum(_rows(b, ids).astype(jnp.float32) for b in bufs)
+        return lax.dynamic_update_slice_in_dim(
+            acc, lax.dynamic_slice_in_dim(acc, lo, CHUNK) + rows, lo, 0)
+
+    acc = _varying(jnp.zeros((n_tok, bufs[0].shape[1]), jnp.float32),
+                   frozenset().union(*map(compat.vma_of, (front, *bufs))))
+    acc = lax.fori_loop(0, ends[-1], add, acc)
+    return acc[lay["back"]].astype(bufs[0].dtype)
 
 
 @jax.custom_vjp
-def _rows_to_picks(ys, src, dest):
-    """Buffer rows back in pick order, ``ys[dest]``; backward ``g[src]``."""
-    return ys[dest]
+def _expand(x, w_gate, w_up, lay):
+    """The gate and up products of every live buffer row: the row's token
+    gathered from ``x`` (T, D), then ``lax.ragged_dot`` over the held
+    experts' groups.  The backward is written by hand: the two products'
+    transposes, and the rows' cotangents summed back per token by
+    ``_collect`` (no (rows, D) sum of the two, no scatter of T x k rows)."""
+    return _expand_fwd(x, w_gate, w_up, lay)[0]
 
 
-_rows_to_picks.defvjp(lambda ys, src, dest: (ys[dest], src),
-                      lambda src, g: (g[src], None, None))
+@jax.jit
+def _expand_fwd(x, w_gate, w_up, lay):
+    xs, = _over_live(lambda pick: (_rows(x, _token(pick, lay)),),
+                     lay["n_live"], lay["pick"])
+    gate = lax.ragged_dot(xs, w_gate, lay["padded"])
+    up = lax.ragged_dot(xs, w_up, lay["padded"])
+    return (gate, up), (x[:0], xs, w_gate, w_up, lay)
 
 
-def merge_stats(total: dict | None, layer: dict) -> dict:
+@jax.jit
+def _expand_bwd(res, cts):
+    x0, xs, w_gate, w_up, lay = res
+    dxs, dws = [], []
+    for w, g in zip((w_gate, w_up), cts):
+        dxs.append(jax.linear_transpose(
+            lambda a, w=w: lax.ragged_dot(a, w, lay["padded"]), xs)(g)[0])
+        dws.append(jax.linear_transpose(
+            lambda b: lax.ragged_dot(xs, b, lay["padded"]), w)(g)[0])
+    return (*map(_as_cotangent, (_collect(dxs, lay), *dws),
+                 (x0, w_gate, w_up)), None)
+
+
+_expand.defvjp(_expand_fwd, _expand_bwd)
+
+
+def _weighted(act, dt):
+    """``act(gate) * up`` of a chunk's rows, each times its pick's weight
+    (float32), in the compute type."""
+    def f(gate, up, w_row):
+        gate = jax.nn.relu(gate) if act == "relu" else jax.nn.silu(gate)
+        return ((gate * up).astype(jnp.float32) * w_row[:, None]).astype(dt)
+    return f
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _contract(gate, up, w, w_down, lay, act):
+    """``(T, D)``: every live row's ``act(gate) * up`` times the weight
+    ``w`` (T, k) of the pick it holds, through the down product
+    (``lax.ragged_dot``), summed per token (``_collect``).  The weight goes
+    in before the product, which is linear in its rows, so the sum after it
+    is plain and neither the product's result nor its input is kept: the
+    backward gathers the tokens' cotangents into the live rows, makes the
+    product's input again, and gives a pick's weight its row's dot product
+    over the experts' width."""
+    return _contract_fwd(gate, up, w, w_down, lay, act)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(5,))
+def _contract_fwd(gate, up, w, w_down, lay, act):
+    def hidden(gate, up, pick):
+        w_row = jnp.where(pick >= 0, w.reshape(-1)[jnp.maximum(pick, 0)], 0)
+        return _weighted(act, gate.dtype)(gate, up, w_row), w_row
+
+    h, w_row = _over_live(hidden, lay["n_live"], gate, up, lay["pick"])
+    ys = lax.ragged_dot(h, w_down, lay["padded"])
+    return _collect([ys], lay), (gate, up, w_row, w[:0], w_down, lay)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _contract_bwd(act, res, g):
+    gate, up, w_row, w0, w_down, lay = res
+    weighted = _weighted(act, gate.dtype)
+    dys, h = _over_live(
+        lambda pick, *rows: (_rows(g, _token(pick, lay)), weighted(*rows)),
+        lay["n_live"], lay["pick"], gate, up, w_row)
+    dh, = jax.linear_transpose(
+        lambda a: lax.ragged_dot(a, w_down, lay["padded"]), h)(dys)
+    dw_down, = jax.linear_transpose(
+        lambda b: lax.ragged_dot(h, b, lay["padded"]), w_down)(dys)
+    dgate, dup, dw_row = _over_live(
+        lambda g, u, w_row, dh: jax.vjp(weighted, g, u, w_row)[1](dh),
+        lay["n_live"], gate, up, w_row, dh)
+    dw = jnp.where(lay["dest"] >= 0, dw_row[jnp.maximum(lay["dest"], 0)], 0)
+    return *map(_as_cotangent, (dgate, dup, dw, dw_down),
+                (gate, up, w0, w_down)), None
+
+
+_contract.defvjp(_contract_fwd, _contract_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _layout(top_idx, first_expert, held):
+    """A buffer row for every pick of a held expert, sorted by expert, each
+    expert's rows from a tile boundary on, so that the live rows are the
+    buffer's first tiles; the buffer could hold every pick.  Returns the
+    layout (``pick``: the pick a row holds or -1; ``dest``: the row of a
+    pick (T, k) or -1; ``padded``: the experts' rows in whole tiles;
+    ``n_live``: their sum; ``front``, ``trips``, ``back``: the order in
+    which ``_collect`` reads), which picks are held here, and the experts'
+    rows."""
+    t, top_k = top_idx.shape
+    local = top_idx - first_expert
+    here = (local >= 0) & (local < held)
+    group = jnp.where(here, local, held).reshape(t * top_k)
+    one_hot = group[:, None] == jnp.arange(held)     # no scatter, no gather
+    sizes = jnp.sum(one_hot, axis=0, dtype=jnp.int32)
+    padded = (sizes + ROW_TILE - 1) // ROW_TILE * ROW_TILE
+    n_rows = -(-(t * top_k + held * ROW_TILE) // CHUNK) * CHUNK  # worst case
+    shift = jnp.cumsum(padded) - padded - (jnp.cumsum(sizes) - sizes)
+    rank = jnp.argsort(jnp.argsort(group, stable=True))  # place when sorted
+    dest = jnp.where(here.reshape(-1),
+                     rank + jnp.sum(jnp.where(one_hot, shift, 0), axis=1),
+                     n_rows)
+    pick = jnp.full((n_rows,), -1, jnp.int32).at[dest].set(
+        jnp.arange(t * top_k, dtype=jnp.int32), mode="drop")
+    dest = jnp.where(here, dest.reshape(t, top_k), -1)
+    # for ``_collect``: the tokens ordered by how many of their picks are
+    # held, each token's held picks moved to the front of its k places
+    count = jnp.sum(here, axis=1)
+    place = here[:, :, None] & (
+        (jnp.cumsum(here, axis=1) - 1)[:, :, None] == jnp.arange(top_k))
+    front = jnp.where(jnp.arange(top_k) < count[:, None],
+                      jnp.sum(jnp.where(place, dest[:, :, None], 0), axis=1),
+                      -1)
+    order = jnp.argsort(-count, stable=True)        # most held picks first
+    n_tok = -(-t // CHUNK) * CHUNK
+    lay = {"padded": padded, "n_live": jnp.sum(padded), "pick": pick,
+           "dest": dest,
+           # (k x tokens,): the row of each token's r-th held pick, or -1
+           "front": jnp.pad(front[order], ((0, n_tok - t), (0, 0)),
+                            constant_values=-1).T.reshape(-1),
+           # chunks of tokens that have an r-th held pick, r = 0 .. k-1
+           "trips": (jnp.sum(count[:, None] > jnp.arange(top_k), axis=0)
+                     + CHUNK - 1) // CHUNK,
+           "back": jnp.zeros((t,), jnp.int32).at[order].set(
+               jnp.arange(t, dtype=jnp.int32))}
+    return lay, here, sizes
+
+
+def merge_stats(layers: list) -> dict:
     """The counters of several dropless layers as one set: rows and drops
-    add up, the load figure is the worst layer's."""
-    if total is None:
-        return layer
-    return {"rows_here": total["rows_here"] + layer["rows_here"],
-            "dropped": total["dropped"] + layer["dropped"],
-            "load_max_over_mean": jnp.maximum(total["load_max_over_mean"],
-                                              layer["load_max_over_mean"])}
+    add up, the load figure is the worst layer's, the share of the buffer
+    that was worked over is the layers' mean."""
+    each = {k: jnp.stack([s[k] for s in layers]) for k in layers[0]}
+    return {"rows_here": each["rows_here"].sum(),
+            "dropped": each["dropped"].sum(),
+            "load_max_over_mean": each["load_max_over_mean"].max(),
+            "live_tile_share": each["live_tile_share"].mean()}
 
 
 def moe_dropless_apply(
@@ -341,18 +542,25 @@ def moe_dropless_apply(
     logits in float32, the ``top_k`` largest, a softmax over those k; each
     pick of an expert held here contributes ``weight * down(act(gate(x)) *
     up(x))``.  The picks of held experts are laid out by expert in a row
-    buffer, each expert's rows from a tile boundary on (``ROW_TILE``), one
-    input row is gathered per pick, and the three products run as
-    ``lax.ragged_dot`` over the held experts' groups.  The buffer holds all
-    ``T x k`` picks and a tile of padding an expert, the worst case, so no
-    pick is cut however uneven the routing: with every token on one expert
-    the result is still exact.  Empty rows and rows past the last group are
-    masked on both sides of the products.
+    buffer, each expert's rows from a tile boundary on (``ROW_TILE``), so
+    the live rows are the buffer's first ``sum(padded) / ROW_TILE`` tiles;
+    one input row is gathered per pick, and the three products run as
+    ``lax.ragged_dot`` over the held experts' groups.  The buffer is
+    *allocated* for the worst case (all ``T x k`` picks and a tile of
+    padding an expert), so no pick is cut however uneven the routing: with
+    every token on one expert the result is still exact.  It is *worked
+    over* its live tiles alone, forward and backward (``_over_live``,
+    ``_collect``): what lies past them is never written nor read, and a
+    step under even routing over a quarter of the experts costs a quarter
+    of the row traffic.  A pick's weight multiplies its row before the down
+    product (linear in its rows), the sum over a token's picks is in
+    float32.
 
     ``stats``: ``rows_here`` (picks routed to held experts),
-    ``load_max_over_mean`` (largest group over the mean group) and
-    ``dropped`` (picks of held experts that reached no product: 0), float32
-    scalars of this call.
+    ``load_max_over_mean`` (largest group over the mean group), ``dropped``
+    (picks of held experts that no live row holds: 0) and
+    ``live_tile_share`` (the tiles the loops worked over / the buffer's),
+    float32 scalars of this call.
     """
     if axis is not None:
         raise NotImplementedError(
@@ -361,7 +569,6 @@ def moe_dropless_apply(
             f"moe_apply for expert parallelism over an axis")
     if act not in ("silu", "relu"):
         raise ValueError(f"act must be 'silu' or 'relu', got {act!r}")
-    t, d = x.shape
     e = params["router"].shape[-1]
     held = params["w_gate"].shape[0]
     if not 1 <= top_k <= e:
@@ -373,43 +580,27 @@ def moe_dropless_apply(
     logits = jnp.dot(xr.astype(jnp.float32),
                      params["router"].astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)           # (T, E)
-    top_logits, top_idx = lax.top_k(logits, top_k)              # (T, K)
+    # the picks' logits read back through a one-hot select, whose backward
+    # is a select too (top_k's own is a scatter into (T, E))
+    _, top_idx = lax.top_k(lax.stop_gradient(logits), top_k)    # (T, K)
+    top_logits = jnp.sum(jnp.where(top_idx[..., None] == jnp.arange(e),
+                                   logits[:, None, :], 0), axis=-1)
     weights = jax.nn.softmax(top_logits, axis=-1)
 
-    # -- a buffer row for every pick of a held expert, sorted by expert ----
-    # each expert's rows start on a tile boundary; picks of experts held
-    # elsewhere all go to the last row, in a tile no product reads
-    local = top_idx - first_expert
-    here = (local >= 0) & (local < held)
-    group = jnp.where(here, local, held).reshape(t * top_k)
-    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
-    padded = (sizes + ROW_TILE - 1) // ROW_TILE * ROW_TILE
-    n_rows = t * top_k + (held + 1) * ROW_TILE      # the worst case
-    shift = jnp.cumsum(padded) - padded - (jnp.cumsum(sizes) - sizes)
-    rank = jnp.argsort(jnp.argsort(group, stable=True))  # place when sorted
-    dest = jnp.where(here.reshape(-1),
-                     rank + jnp.append(shift, 0)[group], n_rows - 1)
-    src = jnp.full((n_rows,), -1, jnp.int32).at[dest].set(
-        jnp.arange(t * top_k, dtype=jnp.int32))
-    live = ((src >= 0) & (jnp.arange(n_rows) < jnp.sum(padded)))[:, None]
-    src = jnp.maximum(src, 0)
+    lay, here, sizes = _layout(top_idx, first_expert, held)
+    n_rows, n_live = lay["pick"].shape[0], lay["n_live"]
     rows_here = jnp.sum(sizes)
 
     # -- the held experts over their rows ----------------------------------
     dt = x.dtype
-    xs = jnp.where(live, _rows_of_picks(x, src, dest, top_k), 0)
-    gate = lax.ragged_dot(xs, params["w_gate"].astype(dt), padded)
-    gate = jax.nn.relu(gate) if act == "relu" else jax.nn.silu(gate)
-    up = lax.ragged_dot(xs, params["w_up"].astype(dt), padded)
-    ys = lax.ragged_dot(gate * up, params["w_down"].astype(dt), padded)
-    ys = jnp.where(live, ys, 0)
-
-    # -- weight and add each token's picks ---------------------------------
-    w = jnp.where(here, weights, 0.0).astype(dt)
-    out = jnp.sum(_rows_to_picks(ys, src, dest).reshape(t, top_k, d)
-                  * w[..., None], axis=1)
-    reached = jnp.sum(live[dest, 0] & here.reshape(-1))
+    gate, up = _expand(x, params["w_gate"].astype(dt),
+                       params["w_up"].astype(dt), lay)
+    out = _contract(gate, up, jnp.where(here, weights, 0.0),
+                    params["w_down"].astype(dt), lay, act)
+    # a pick reaches the products if a row of the live part holds it
+    reached = jnp.sum((lay["pick"] >= 0) & (jnp.arange(n_rows) < n_live))
     mean = jnp.maximum(rows_here, 1) / held
     stats = {"rows_here": rows_here, "dropped": rows_here - reached,
-             "load_max_over_mean": jnp.max(sizes) / mean}
+             "load_max_over_mean": jnp.max(sizes) / mean,
+             "live_tile_share": (n_live + CHUNK - 1) // CHUNK * CHUNK / n_rows}
     return out, {k: v.astype(jnp.float32) for k, v in stats.items()}

@@ -97,13 +97,31 @@ def test_flash_at_the_routed_cells_row_compiles(chip, window):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+def _entry_results(text, shape):
+    """Opcodes of the entry computation's operations whose result (or an
+    element of it) has ``shape``: what runs once a call, outside every loop
+    body, conditional and fusion."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    found = []
+    for line in entry.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if m and shape in re.sub(r"\{[^{}]*\}", "", m.group(1)):
+            found.append(m.group(2))
+    return found
+
+
 def test_grouped_products_compile_to_the_chips_own_kernel(chip):
     """The dropless routed layer at the routed cell's shapes (49,152 picks
     of 2,560 over 16 held experts of 768), forward and backward: XLA lowers
     ``lax.ragged_dot`` on the TPU to Mosaic kernels of its own (so the step
     holds two families of ``tpu_custom_call`` and the benchmark's readers
     tell them apart by name, ``benchmarks/routed_ops.py``), with no dense
-    (rows, experts, ...) expansion: the temporaries stay under 2 GiB."""
+    (rows, experts, ...) expansion.  And the buffer is allocated for the
+    worst case but worked over its live part: beside the products, nothing
+    with a result of the buffer's length, or of one row a pick, runs once a
+    call; the row work is in loops whose trip counts are read on the
+    device."""
     from distributed_pytorch_tpu.ops import moe
 
     def loss(params, x):
@@ -119,11 +137,23 @@ def test_grouped_products_compile_to_the_chips_own_kernel(chip):
         params, x).compile()
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 9     # 3 products x (fwd, dx, dw)
-    # the backward of a row gather is a gather too: no scatter of rows
-    # (the two that remain are top-k's into (8192, 64) and the bincount's)
+    # both sides of the products move rows by gathers, forward and backward
+    # (a scatter-add of rows is serialised on this chip: fifty times slower,
+    # PERF.md PR 29): the scatters that remain are of indices
     assert not [line for line in text.split("\n") if " scatter(" in line
                 and "2560" in line.split(" scatter(")[0]]
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    # 1.27 GiB here (1.13 GiB before the loops; the whole step's
+    # temporaries fell, PERF.md section 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+    rows = -(-(8192 * 6 + 16 * moe.ROW_TILE) // moe.CHUNK) * moe.CHUNK
+    passing = {"custom-call", "while", "get-tuple-element", "tuple",
+               "parameter", "bitcast"}
+    for shape in (f"[{rows},2560]", f"[{rows},768]", "[49152,2560]",
+                  "[8192,6,2560]", "[57856,2560]"):
+        assert set(_entry_results(text, shape)) <= passing, shape
+    # rows in and the gate, forward; cotangents in, the gate's transpose
+    # and the rows summed back per token, backward
+    assert len(re.findall(r" while\(", text)) >= 5
 
 
 @pytest.mark.parametrize("case", ["dense_bf16", "dense_int8_block512",

@@ -92,6 +92,12 @@ def share_of(full, first, held):
                for k in ("w_gate", "w_up", "w_down")}}
 
 
+def weighted_sum(f):
+    """A scalar of ``f(params, x)`` (T, D) with a cotangent that differs
+    from column to column."""
+    return lambda p, x: jnp.sum(f(p, x) * jnp.cos(jnp.arange(D)))
+
+
 @pytest.fixture(scope="module")
 def routed():
     return (moe.moe_init(jax.random.key(1), D, F, E),
@@ -109,13 +115,10 @@ def test_dropless_layer_agrees_with_the_reference(bench, routed, first, held):
     assert float(stats["dropped"]) == 0
     assert 0 < float(stats["rows_here"]) <= T * K
 
-    def loss(f):
-        return lambda p, x: jnp.sum(f(p, x) * jnp.cos(jnp.arange(D)))
-
-    got = jax.grad(loss(lambda p, x: program_routed(p, x, first)[0]),
+    got = jax.grad(weighted_sum(lambda p, x: program_routed(p, x, first)[0]),
                    (0, 1))(params, x)
-    want = jax.grad(loss(lambda p, x: reference_routed(bench, p, x, first)),
-                    (0, 1))(params, x)
+    want = jax.grad(weighted_sum(
+        lambda p, x: reference_routed(bench, p, x, first)), (0, 1))(params, x)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert close(g, w)
 
@@ -145,6 +148,101 @@ def test_every_token_on_one_expert_drops_nothing(bench, routed):
     out, stats = program_routed(mine, x, 0)
     assert float(stats["dropped"]) == 0 and float(stats["rows_here"]) >= T
     assert close(out, reference_routed(bench, mine, x, 0))
+
+
+# -- routings that stress the work over the live tiles -----------------------
+# 1,100 tokens, experts 0-3 of 16 held; a case says how many tokens pick each
+# held expert (tokens start, start + 1, ... of the row; the other picks go to
+# experts held elsewhere), so the rows, the live tiles and the counters can
+# be worked out by hand.  2,048 is a multiple of every chunk length in use.
+T_LIVE, HELD_LIVE = 1100, 4
+ROUTINGS = {
+    "even": (300, 300, 300, 300),
+    "all_on_one_expert": (0, 0, T_LIVE, 0),
+    "no_pick_held_here": (0, 0, 0, 0),
+    "an_expert_of_one_tile": (moe.ROW_TILE, 100, 0, 7),
+    "an_expert_of_one_tile_and_a_row": (moe.ROW_TILE + 1, 100, 0, 7),
+    "live_rows_end_on_a_chunk": (512, 512, 512, 512),
+    "live_rows_end_a_row_past_a_chunk": (513, 512, 512, 512),
+}
+
+
+def routed_as(counts):
+    """Weights and an input under which exactly ``counts[e]`` tokens pick
+    held expert e: the router reads the input's first E features one for
+    one, and those features rank each token's K picks above the rest (all
+    scores distinct, so both sides pick alike)."""
+    full = moe.moe_init(jax.random.key(5), D, F, E)
+    router = jnp.zeros((D, E)).at[:E].set(jnp.eye(E))
+    t = np.arange(T_LIVE)
+    want = np.zeros((T_LIVE, E), bool)
+    for e, n in enumerate(counts):
+        want[(t - 37 * e) % T_LIVE < n, e] = True
+    for tok in t:       # fill up to K with experts held elsewhere
+        spare = HELD_LIVE + (tok + np.arange(E - HELD_LIVE)) % (E - HELD_LIVE)
+        want[tok, spare[:K - want[tok].sum()]] = True
+    assert (want.sum(1) == K).all()
+    score = np.where(want, 2.0, -2.0) + 0.3 * np.random.default_rng(0).random(
+        (T_LIVE, E))
+    x = jax.random.normal(jax.random.key(6), (T_LIVE, D))
+    return (share_of(dict(full, router=router), 0, HELD_LIVE),
+            x.at[:, :E].set(jnp.asarray(score, jnp.float32)))
+
+
+@pytest.mark.parametrize("case", list(ROUTINGS))
+def test_any_routing_is_exact_over_the_live_tiles(bench, case):
+    """Value and every gradient (input, router, the three stacks) against
+    the reference, nothing dropped, and the counters as counted by hand:
+    an expert's rows take whole tiles, the layer's loops whole chunks."""
+    counts = ROUTINGS[case]
+    assert 2048 % moe.CHUNK == 0 and moe.CHUNK % moe.ROW_TILE == 0
+    params, x = routed_as(counts)
+    out, stats = program_routed(params, x, 0)
+    assert close(out, reference_routed(bench, params, x, 0))
+    live = sum(-(-n // moe.ROW_TILE) * moe.ROW_TILE for n in counts)
+    worst = T_LIVE * K + HELD_LIVE * moe.ROW_TILE
+    assert float(stats["dropped"]) == 0
+    assert float(stats["rows_here"]) == sum(counts)
+    assert float(stats["live_tile_share"]) == pytest.approx(
+        -(-live // moe.CHUNK) * moe.CHUNK
+        / (-(-worst // moe.CHUNK) * moe.CHUNK))
+    if sum(counts):
+        assert float(stats["load_max_over_mean"]) == pytest.approx(
+            max(counts) * HELD_LIVE / sum(counts))
+    else:
+        assert not np.asarray(out).any()
+
+    got = jax.grad(weighted_sum(lambda p, x: program_routed(p, x, 0)[0]),
+                   (0, 1))(params, x)
+    want = jax.grad(weighted_sum(
+        lambda p, x: reference_routed(bench, p, x, 0)), (0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert close(g, w)
+
+
+@pytest.mark.parametrize("case", ["even", "no_pick_held_here"])
+def test_nothing_is_read_past_the_live_tiles(bench, monkeypatch, case):
+    """On the TPU the dead tiles of the buffers the loops write are
+    uninitialised (``lax.empty``; zeros on the CPU).  With NaN there
+    instead, value and gradients are finite and the reference's: no loop
+    and no product reads them.  (What a product itself leaves past its
+    groups is zero on the CPU: a read of that shows on the chip only, where
+    the benchmark's ``correct`` sees it.)  The layer's rules are jitted, so
+    this runs at a token count no other test has: nothing traced with the
+    real ``lax.empty`` is found again, nor anything traced here later."""
+    monkeypatch.setattr(moe.lax, "empty", lambda shape, dtype: jnp.full(
+        shape, jnp.nan, dtype))
+    untouched, = moe._over_live(lambda rows: (rows,), jnp.int32(0),
+                                jnp.ones((2 * moe.CHUNK, 2)))
+    assert np.isnan(np.asarray(untouched)).all()
+    params, x = routed_as(ROUTINGS[case])
+    x = x[:-4]
+    got = jax.value_and_grad(weighted_sum(
+        lambda p, x: program_routed(p, x, 0)[0]), (0, 1))(params, x)
+    want = jax.value_and_grad(weighted_sum(
+        lambda p, x: reference_routed(bench, p, x, 0)), (0, 1))(params, x)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g)).all() and close(g, w)
 
 
 def test_dropless_layer_refuses_an_axis(routed):
@@ -238,8 +336,9 @@ def test_whole_model_step_agrees_with_the_reference(bench, remat):
     prog.reset_trainer(trainer, jax.tree.map(jnp.copy, start))
     tok, tgt = batch()
     loss = float(trainer.train_step(tok, tgt))
-    rows, load, dropped = np.asarray(trainer.last_metrics)[2:]
+    rows, load, dropped, share = np.asarray(trainer.last_metrics)[2:]
     assert dropped == 0 and 0 < rows < 4 * tok.size * K and load >= 1
+    assert 0 < share <= 1
     mine = {"losses": [loss],
             "grad_norms": np.asarray(ref.leaf_norms(prog.adam_first_moment(
                 trainer.opt_state))) / (1 - HP["b1"]),
@@ -354,3 +453,5 @@ def test_counters_reach_telemetry_by_name(bench, tmp_path):
     gauges = {r["name"]: r["value"] for r in records if r["type"] == "gauge"}
     assert gauges["moe.dropped"] == 0 and gauges["moe.rows_here"] > 0
     assert gauges["moe.load_max_over_mean"] >= 1
+    assert 0 < gauges["moe.live_tile_share"] <= 1
+    assert lm.MOE_METRICS[3] == "moe.live_tile_share"
