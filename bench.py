@@ -80,7 +80,6 @@ def vgg11_train_flops_per_sample() -> float:
 
 # bf16 peak TFLOP/s per chip by device kind (Google Cloud documentation,
 # "TPU v5e" / "TPU v4" / "TPU v5p" / "TPU v6e" system architecture pages).
-# The one table: scripts/lm_roofline.py reads it too.
 _PEAK_BF16_TFLOPS = {
     "TPU v5 lite": 197.0,   # v5e
     "TPU v5e": 197.0,
@@ -1229,124 +1228,6 @@ def bench_elastic(steps: int = 2, seq: int = 128, batch: int = 8) -> dict:
     return {"recovery_ms": recovery_ms, "resize_events": events}
 
 
-def canon_pp_size_env(value: str | None) -> int:
-    """Validate the BENCH_PP_SIZE knob: unset/''/'0' skips the
-    interleaved-1F1B pipeline A/B (the default — it needs >= 2 devices
-    to mean anything); an integer >= 2 is the stage count for the
-    virtual 'pp' mesh.  A typo must fail HERE, before any measurement
-    (the BENCH_DCN_SIZE contract): inside the bench it would be
-    swallowed by the catch-all while the JSON silently omitted the
-    pipeline keys."""
-    if value is None or value in ("", "0"):
-        return 0
-    try:
-        n = int(value)
-    except ValueError:
-        raise ValueError(
-            f"BENCH_PP_SIZE must be an integer >= 2 (or ''/0 to skip), "
-            f"got {value!r}") from None
-    if n < 2:
-        raise ValueError(
-            f"BENCH_PP_SIZE must be >= 2 (a {n}-stage 'pipeline' has no "
-            f"stage boundary to schedule); unset it or use 0 to skip")
-    return n
-
-
-def canon_microbatches_env(value: str | None, pp_size: int) -> int:
-    """Validate BENCH_MICROBATCHES against BENCH_PP_SIZE pre-bench:
-    default 2*pp_size (the <=1/3-bubble regime), and the combination
-    must satisfy the ONE schedulability check the trainer itself uses
-    (strategies.require_pp_schedulable on the bench LM config) — an
-    incoherent knob pair fails loudly here, not mid-measurement."""
-    if value is None or value == "":
-        m = 2 * pp_size
-    else:
-        try:
-            m = int(value)
-        except ValueError:
-            raise ValueError(
-                f"BENCH_MICROBATCHES must be an integer >= BENCH_PP_SIZE, "
-                f"got {value!r}") from None
-    if pp_size:
-        from distributed_pytorch_tpu.parallel.strategies import (
-            require_pp_schedulable)
-        require_pp_schedulable(n_stages=pp_size, n_micro=m,
-                               n_layers=_lm_cfg().n_layers)
-    return m
-
-
-def bench_train_pp(pp_size: int, microbatches: int, iters: int = 20,
-                   batch: int | None = None, seq: int = 256,
-                   reps: int = 5) -> dict | None:
-    """Interleaved-1F1B pipeline A/B (round 10, BENCH_PP_SIZE): the LM
-    trainer on a virtual ('pp', data, ...) mesh at ``pp_size`` stages vs
-    the same model/microbatching single-stage, hardened-window
-    discipline (>= ``reps`` alternating reps, median, value fetch at
-    window end).  Reports the measured steady-state bubble fraction of
-    the EMITTED timetable via the schedule inspector
-    (utils/debug.assert_pipeline_schedule — which also re-checks 1F1B
-    well-formedness and the analytic (pp-1)/(pp-1+M) bound on every
-    bench run) alongside tokens/sec.  On CPU meshes expect ~<=1.0x
-    speedup (stages serialize on one core — the schedule/bubble numbers
-    are the CPU content); on real hardware pp pays off when the model
-    does not fit one stage's HBM.  Needs >= pp_size devices; returns
-    None (JSON nulls) otherwise."""
-    import jax
-
-    from distributed_pytorch_tpu.lm import LMTrainConfig, LMTrainer
-    from distributed_pytorch_tpu.utils import debug as dbg
-
-    n_dev = len(jax.devices())
-    if n_dev < pp_size or n_dev % pp_size:
-        _log(f"[bench] train-pp A/B needs >= {pp_size} devices divisible "
-             f"by pp_size (have {n_dev}); omitting")
-        return None
-
-    # batch scales with M (2 rows per microbatch) so EVERY schedulable
-    # BENCH_MICROBATCHES value divides cleanly — a knob pair that passed
-    # canon_* validation must never die mid-bench on divisibility
-    if batch is None:
-        batch = 2 * microbatches
-    model = _lm_cfg()
-    trainers = {
-        n: LMTrainer(LMTrainConfig(model=model, pp_size=n,
-                                   microbatches=microbatches))
-        for n in (1, pp_size)}
-    rng = np.random.default_rng(0)
-    toks = rng.integers(0, 256, (batch, seq)).astype(np.int32)
-    tgts = np.roll(toks, -1, axis=1).astype(np.int32)
-
-    for tr in trainers.values():  # compile + warm outside the timed reps
-        float(tr.train_step(toks, tgts))
-
-    times: dict[int, list[float]] = {n: [] for n in trainers}
-    for _ in range(reps):
-        for n, tr in trainers.items():  # alternate: drift hits both
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                loss = tr.train_step(toks, tgts)
-            float(loss)
-            times[n].append((time.perf_counter() - t0) / iters)
-    med = {n: sorted(ts)[len(ts) // 2] for n, ts in times.items()}
-    tps = batch * seq / med[pp_size]
-    speedup = med[1] / max(med[pp_size], 1e-12)
-
-    step = trainers[pp_size].step_fn
-    stats = dbg.assert_pipeline_schedule(
-        step, n_stages=step.pp_meta["n_stages"],
-        n_micro=step.pp_meta["n_micro"],
-        interleave=step.pp_meta["interleave"])
-    _log(f"[bench] train-pp A/B (1F1B, pp_size={pp_size}, "
-         f"M={microbatches}, {n_dev} dev): {med[pp_size] * 1e3:.2f} "
-         f"ms/step vs {med[1] * 1e3:.2f} single-stage -> "
-         f"{speedup:.3f}x, {tps:,.0f} tok/s; measured bubble "
-         f"{stats['bubble_fraction']:.4f} (bound "
-         f"{stats['analytic_bound']:.4f}; {reps} reps median)")
-    return {"tokens_per_sec": tps, "speedup": speedup,
-            "bubble_fraction": stats["bubble_fraction"],
-            "bubble_bound": stats["analytic_bound"]}
-
-
 def _lm_cfg():
     """The BASELINE.md LM measurement config: byte-vocab d512/4L
     transformer, flash attention, bf16."""
@@ -1955,13 +1836,6 @@ def main() -> None:
     # memory-thrifty LM step against the stock dense/no-remat one.
     mem_loss_impl = canon_loss_impl_env(os.environ.get("BENCH_LOSS_IMPL"))
     mem_remat = canon_remat_env(os.environ.get("BENCH_REMAT"))
-    # Interleaved-1F1B pipeline A/B knobs (round 10), validated loudly
-    # pre-bench: BENCH_PP_SIZE >= 2 runs the LM pipeline A/B on a
-    # pp_size-staged virtual mesh; BENCH_MICROBATCHES sets M (default
-    # 2*pp_size) and the pair must be schedulable for the bench model.
-    pp_size = canon_pp_size_env(os.environ.get("BENCH_PP_SIZE"))
-    pp_micro = canon_microbatches_env(
-        os.environ.get("BENCH_MICROBATCHES"), pp_size)
     # Autotuner A/B knob (round 11), validated loudly pre-bench:
     # BENCH_AUTOTUNE=1 runs calibrate->choose->A/B vs the hand-picked
     # default and stamps the chosen plan into the JSON.
@@ -2063,15 +1937,6 @@ def main() -> None:
             mem_ab = bench_lm_memory(mem_loss_impl, mem_remat)
         except Exception as e:
             _log(f"[bench] lm-memory gate failed ({e}); omitting")
-
-    # Interleaved-1F1B pipeline A/B (round 10): LM pp_size stages vs
-    # single-stage on the virtual mesh; optional like the other gates.
-    pp_ab = None
-    if pp_size:
-        try:
-            pp_ab = bench_train_pp(pp_size, pp_micro)
-        except Exception as e:
-            _log(f"[bench] train-pp A/B failed ({e}); omitting")
 
     # Topology-aware autotuner A/B (round 11): calibrate the real
     # links, choose a plan, measure it against the hand-picked default;
@@ -2264,19 +2129,6 @@ def main() -> None:
         "lm_remat_step_overhead_pct": (
             round(mem_ab["step_overhead_pct"], 3)
             if mem_ab is not None else None),
-        # interleaved-1F1B pipeline A/B (round 10, BENCH_PP_SIZE):
-        # tokens/sec of the pp_size-stage LM step, its measured
-        # steady-state bubble fraction (from the emitted 1F1B timetable
-        # via the schedule inspector, which re-asserts the analytic
-        # (pp-1)/(pp-1+M) bound on every bench run), and the ms/step
-        # ratio vs the single-stage baseline at the same microbatching.
-        # All null when the A/B is skipped.
-        "lm_pp_tokens_per_sec": (round(pp_ab["tokens_per_sec"], 1)
-                                 if pp_ab is not None else None),
-        "lm_pp_bubble_fraction": (round(pp_ab["bubble_fraction"], 4)
-                                  if pp_ab is not None else None),
-        "lm_pp_speedup": (round(pp_ab["speedup"], 3)
-                          if pp_ab is not None else None),
         # topology-aware autotuner A/B (round 11, BENCH_AUTOTUNE=1):
         # calibrated-link plan (strategy/bucket/compression + predicted
         # ms — the explainable decision) and its measured ms/step ratio

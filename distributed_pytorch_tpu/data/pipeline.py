@@ -95,7 +95,7 @@ def prefetch(iterable, depth: int = 2):
     import threading
 
     q: "queue.Queue" = queue.Queue(maxsize=depth)
-    done = object()
+    done, failed = object(), object()
     stop = threading.Event()
 
     def _put(item) -> bool:
@@ -117,7 +117,7 @@ def prefetch(iterable, depth: int = 2):
                     return
             _put(done)
         except BaseException as e:  # surfaced at the consuming side
-            _put(("__prefetch_error__", e))
+            _put((failed, e))
 
     t = threading.Thread(target=producer, daemon=True)
     t.start()
@@ -126,8 +126,7 @@ def prefetch(iterable, depth: int = 2):
             item = q.get()
             if item is done:
                 return
-            if (isinstance(item, tuple) and len(item) == 2
-                    and item[0] == "__prefetch_error__"):
+            if isinstance(item, tuple) and item and item[0] is failed:
                 raise item[1]
             yield item
     finally:

@@ -51,8 +51,6 @@ PyTree = Any
 
 DATA, SEQ, MODEL, PIPE, EXPERT = "data", "seq", "model", "pipe", "expert"
 DCN = "dcn"  # outer factor of the data axis on multislice meshes
-PP = "pp"    # interleaved-1F1B stage axis (round 10; distinct from the
-             # wave scheduler's 'pipe' — see make_lm_1f1b_train_step)
 IGNORE = IGNORE_INDEX  # target id excluded from the loss (padding)
 # What the step of a model with the dropless routed layer (ops/moe.py) adds
 # to ``LMTrainer.last_metrics`` after [grad-norm, param-norm], summed over
@@ -122,16 +120,16 @@ class LMTrainConfig:
     # same machinery one rung lower: [-7, 7] levels, two nibbles packed
     # per int8 lane around every DCN ppermute (~0.51x the int8 wire
     # bytes), identical residual layout and EF invariant.  Requires
-    # dcn_size > 1; does not compose with pp/pp_size (their gradient
-    # paths are hand-emitted; open item).  Dropping the carry on
+    # dcn_size > 1 (so not with pp: the pipeline mesh has no factored
+    # data axis).  Dropping the carry on
     # restart is safe (residuals re-accumulate within a step;
     # checkpoints skip it).
     dcn_compress: str | None = None
     # Streaming bucket size (MB) for the factored-mesh exchange
     # (default: strategies.BUCKET_CAP_MB's ~25 MB): feeds the
-    # grad-accumulation path's post-scan sync, the 1F1B path's
-    # _pp_grad_sync, and the int8 ring's bucket layout.  None keeps the
-    # historical default — the plain paths are bitwise-unchanged.
+    # grad-accumulation path's post-scan sync and the int8 ring's
+    # bucket layout.  None keeps the historical default — the plain
+    # paths are bitwise-unchanged.
     bucket_mb: float | None = None
     # "auto" (round 11): resolve dcn_compress/bucket_mb from a
     # calibrated (or injected — ``autotune_profile``) link profile by
@@ -154,31 +152,16 @@ class LMTrainConfig:
     # knobs above (the exact routes `_two_level_sync` already
     # executes), so a routed config trains BITWISE-identically to the
     # explicit config it names; anything the LM machinery cannot run —
-    # other shapes, pp/pp_size, combining with sync_plan="auto" or
+    # other shapes, pp, combining with sync_plan="auto" or
     # dcn_compress — refuses loudly (strategies.require_lm_route).
     sync_route: str | None = None
-    # Interleaved-1F1B pipeline parallelism (round 10): pp_size > 0 routes
-    # training through make_lm_1f1b_train_step — layer chunks partitioned
-    # over a dedicated 'pp' mesh axis, one explicit forward/backward unit
-    # emitted per (chunk, microbatch) in one-forward-one-backward timetable
-    # order (parallel/pipeline.py one_f_one_b_schedule), stage-boundary
-    # activations/cotangents moving as ppermute transfers over 'pp'.
-    # Unlike the wave scheduler (``pp``), the backward is hand-emitted
-    # (one jax.vjp per unit) with every gradient reduction explicit, so it
-    # composes with fsdp-within-stage, dcn_size, grad_accum and overlap —
-    # and the 1F1B reordering is a pure reassociation of the same
-    # microbatch grads: pp_size=N trains BITWISE-identically to pp_size=1
-    # (test-pinned, params+Adam over multi-step runs).  pp_size=1 is the
-    # legal degenerate schedule (single-stage microbatched accumulation,
-    # the baseline of those pins); 0 = off.
-    pp_size: int = 0
     microbatches: int = 0  # per-step microbatches for pp (default 2*pp)
     # Virtual pipeline stages per device (Megatron interleaved placement):
     # the fill/drain bubble shrinks by this factor (parallel/pipeline.py
     # wave schedule).  Requires n_layers % (pp * interleave) == 0.
     interleave: int = 1
     # Tick-scan remat block for pp (parallel/pipeline.py): 0 = auto (one
-    # wave per block — 1F1B-grade O(pp*mb) activation memory), None = flat
+    # wave per block — O(pp*mb) activation memory), None = flat
     # scan (O(num_ticks) memory; kept for A/B measurement), or an explicit
     # tick count.
     pp_remat_block: int | None = 0
@@ -191,9 +174,7 @@ class LMTrainConfig:
     # 'data', dequantize at the consumer.  Weights-not-grads, so there
     # is no EF carry — the pin is a convergence-curve follow of the
     # full-precision run plus a jaxpr pin that i8 is on the wire.
-    # Requires fsdp=True (there is no gather to quantize otherwise);
-    # does not compose with pp_size (the 1F1B stacked gather is a
-    # different code path, kept full-precision).  "int4" (round 18,
+    # Requires fsdp=True (no gather to quantize otherwise).  "int4" (round 18,
     # lifting the round-16 refusal) packs two nibbles per wire byte on
     # the same exchange (+/-7 levels against the identical per-row
     # scales) — 8x fewer payload bytes; same full-precision gradient
@@ -262,7 +243,7 @@ class LMTrainConfig:
     # boundaries (overlap streaming, ZeRO-3 gathers, two-level DCN
     # points) sit OUTSIDE the checkpointed block, so no sync collective
     # is re-emitted — schedule-inspector-pinned.  Does not compose with
-    # pp/pp_size: parallel/pipeline.py owns its own per-tick remat
+    # pp: parallel/pipeline.py owns its own per-tick remat
     # (pp_remat_block).  "none" = historical graph.
     remat: str = "none"
     # Communication-sparse windows (round 18, the BAGUA-style system
@@ -343,10 +324,10 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
     would drop the setting)."""
     if cfg.interleave < 1:
         raise ValueError(f"interleave must be >= 1, got {cfg.interleave}")
-    if cfg.interleave > 1 and cfg.pp == 1 and cfg.pp_size <= 1:
+    if cfg.interleave > 1 and cfg.pp == 1:
         raise ValueError(
-            "interleave (virtual pipeline stages) requires pp > 1 or "
-            "pp_size > 1; without a pipeline it would be silently ignored")
+            "interleave (virtual pipeline stages) requires pp > 1; "
+            "without a pipeline it would be silently ignored")
     if cfg.grad_accum < 1:
         raise ValueError(f"grad_accum must be >= 1, got {cfg.grad_accum}")
     if cfg.grad_accum > 1 and cfg.pp > 1:
@@ -354,35 +335,6 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
             "grad_accum does not compose with pp (the pipeline's "
             "microbatch schedule already bounds activation memory; use "
             "--microbatches)")
-    if cfg.pp_size < 0:
-        raise ValueError(f"pp_size must be >= 0, got {cfg.pp_size}")
-    if cfg.pp_size > 0:
-        # the 1F1B path: composition checks live in ONE place
-        # (parallel/strategies.py require_pp_schedulable, the round-9
-        # require_*-style consolidation) so lm_cli/bench/LMTrainer cannot
-        # drift from the step builder's actual capabilities
-        from .parallel.pipeline import _uniform_moe
-        from .parallel.strategies import require_pp_schedulable
-        if cfg.pp > 1:
-            raise ValueError(
-                "pp (wave scheduler) and pp_size (interleaved-1F1B) are "
-                "two schedulers for the same axis — set one, not both")
-        if cfg.ep > 1:
-            raise ValueError("the dedicated 'expert' axis does not "
-                             "compose with pp_size (experts shard over "
-                             "'model' inside pipeline stages); use ep=1")
-        if cfg.model.n_experts and not _uniform_moe(cfg.model):
-            raise ValueError(
-                "pp_size supports MoE only for uniform stacks "
-                "(moe_every=1); a dense/MoE-alternating stack cannot "
-                "stack into homogeneous pipeline chunks")
-        # (tp head-divisibility is checked once, below: pp_size keeps
-        # cfg.pp == 1, so the detailed non-pp tp branch applies)
-        require_pp_schedulable(
-            n_stages=cfg.pp_size,
-            n_micro=cfg.microbatches or 2 * cfg.pp_size,
-            n_layers=cfg.model.n_layers,
-            interleave=cfg.interleave)
     if cfg.dcn_size < 1:
         raise ValueError(f"dcn_size must be >= 1, got {cfg.dcn_size}")
     if cfg.dcn_size > 1:
@@ -407,12 +359,6 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
                 f"dcn_compress={cfg.dcn_compress!r} quantizes the "
                 "cross-slice (dcn) hop of the factored-mesh sync; with "
                 f"dcn_size={cfg.dcn_size} there is no DCN hop to compress")
-        if cfg.pp > 1 or cfg.pp_size > 0:
-            raise ValueError(
-                "dcn_compress does not compose with pipeline parallelism "
-                "(pp/pp_size): the pipeline gradient paths are "
-                "hand-emitted without the stateful sync-state channel "
-                "(open item); drop the pipeline or the compression")
     if (cfg.sync_every != 1 or cfg.staleness != 0
             or cfg.max_sync_every != 1 or cfg.outer_opt is not None
             or cfg.sync_every_per_slice is not None):
@@ -425,7 +371,7 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
         require_sync_window(
             sync_every=cfg.sync_every, staleness=cfg.staleness,
             max_sync_every=cfg.max_sync_every, mesh=True,
-            overlap=cfg.overlap, pp=cfg.pp > 1 or cfg.pp_size > 0,
+            overlap=cfg.overlap, pp=cfg.pp > 1,
             grad_accum=cfg.grad_accum, dcn_size=cfg.dcn_size,
             trainer="lm", outer_opt=cfg.outer_opt,
             outer_momentum=cfg.outer_momentum, outer_lr=cfg.outer_lr,
@@ -440,20 +386,15 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
                 "fsdp_gather_dtype quantizes the ZeRO-3 weight "
                 "all-gather; with fsdp=False there is no gather to "
                 "quantize")
-        if cfg.pp_size > 0:
-            raise ValueError(
-                "fsdp_gather_dtype does not compose with pp_size: the "
-                "1F1B stacked per-chunk gather is a separate path kept "
-                "full-precision (open item); drop one")
     if cfg.matmul_dtype is not None:
         if cfg.matmul_dtype != "int8":
             raise ValueError(
                 f"matmul_dtype must be None or 'int8', got "
                 f"{cfg.matmul_dtype!r}")
-        if cfg.pp > 1 or cfg.pp_size > 0:
+        if cfg.pp > 1:
             raise ValueError(
                 "matmul_dtype does not compose with pipeline parallelism "
-                "(pp/pp_size): the stage runners call the block body "
+                "(pp): the stage runners call the block body "
                 "directly without the matmul_dtype plumbing (open item); "
                 "drop one")
     if cfg.loss_impl not in ("dense", "chunked"):
@@ -461,7 +402,7 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
             f"loss_impl must be 'dense' or 'chunked', got "
             f"{cfg.loss_impl!r}")
     if (cfg.loss_impl == "chunked" and cfg.tp > 1 and cfg.pp == 1
-            and cfg.pp_size == 0 and cfg.model.vocab_size % cfg.tp):
+            and cfg.model.vocab_size % cfg.tp):
         raise ValueError(
             f"vocab_size {cfg.model.vocab_size} must divide over "
             f"tp={cfg.tp} for the chunked (vocab-sharded) head")
@@ -473,9 +414,8 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
                 "(set loss_impl='chunked' or drop loss_chunk)")
         v = cfg.model.vocab_size
         # the streamed head shards the vocab over 'model' only on the
-        # non-pp SPMD path; the pipeline heads chunk the full vocab
-        v_local = v // cfg.tp if (cfg.tp > 1 and cfg.pp == 1
-                                  and cfg.pp_size == 0) else v
+        # non-pp SPMD path; the pipeline head chunks the full vocab
+        v_local = v // cfg.tp if cfg.tp > 1 and cfg.pp == 1 else v
         if cfg.loss_chunk <= 0 or v_local % cfg.loss_chunk:
             raise ValueError(
                 f"loss_chunk={cfg.loss_chunk} must be a positive divisor "
@@ -486,10 +426,10 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
         raise ValueError(
             f"remat must be 'none', 'full' or 'selective', got "
             f"{cfg.remat!r}")
-    if cfg.remat != "none" and (cfg.pp > 1 or cfg.pp_size > 0):
+    if cfg.remat != "none" and cfg.pp > 1:
         raise ValueError(
             "remat does not compose with pipeline parallelism "
-            "(pp/pp_size): the pipeline schedulers own their own "
+            "(pp): the pipeline scheduler owns its own "
             "rematerialization (pp_remat_block wraps each tick block in "
             "jax.checkpoint already); drop one")
     if cfg.fsdp and cfg.dp // max(cfg.dcn_size, 1) == 1:
@@ -513,13 +453,11 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
         from .parallel.strategies import require_lm_overlap_streamable
         require_lm_overlap_streamable(
             fsdp=cfg.fsdp,
-            dcn=cfg.dcn_size > 1 and (cfg.grad_accum == 1
-                                      or cfg.pp_size > 0),
-            pp=cfg.pp_size > 0)
+            dcn=cfg.dcn_size > 1 and cfg.grad_accum == 1)
     unpiped = cfg.model.training_only()
-    if unpiped and (cfg.pp > 1 or cfg.pp_size > 0):
-        # the pipeline runners build the dense block on a tied table
-        raise ValueError("pipeline parallelism (pp / pp_size) does not "
+    if unpiped and cfg.pp > 1:
+        # the pipeline runner builds the dense block on a tied table
+        raise ValueError("pipeline parallelism (pp) does not "
                          "implement " + "; ".join(unpiped))
     if "window" in cfg.model.attn_kinds and cfg.sp > 1:
         raise ValueError("windowed attention layers do not compose with "
@@ -588,25 +526,6 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
 
 def make_lm_mesh(cfg: LMTrainConfig, devices=None) -> Mesh:
     validate_lm_cfg(cfg)
-    if cfg.pp_size > 0:
-        # 1F1B: a dedicated OUTERMOST 'pp' axis — stages map onto DCN
-        # slices on multislice topologies (the stage-boundary ppermutes
-        # are the only cross-stage traffic), and the remaining axes keep
-        # the exact non-pp layout so param_specs/_two_level_sync apply
-        # unchanged within each stage.
-        inner = (cfg.dp * cfg.ep * cfg.sp * cfg.tp)
-        if cfg.dcn_size > 1:
-            return make_mesh(cfg.pp_size * inner,
-                             axis_names=(PP, DCN, DATA, EXPERT, SEQ, MODEL),
-                             axis_shape=(cfg.pp_size, cfg.dcn_size,
-                                         cfg.dp // cfg.dcn_size, cfg.ep,
-                                         cfg.sp, cfg.tp),
-                             devices=devices)
-        return make_mesh(cfg.pp_size * inner,
-                         axis_names=(PP, DATA, EXPERT, SEQ, MODEL),
-                         axis_shape=(cfg.pp_size, cfg.dp, cfg.ep,
-                                     cfg.sp, cfg.tp),
-                         devices=devices)
     if cfg.pp > 1:
         # pp composes with dp, sp (ring attention inside each stage's
         # layer chunks) and tp — a 4-axis mesh; unused axes have size 1.
@@ -1002,7 +921,7 @@ def _two_level_sync(g: PyTree, specs: PyTree,
                     dcn_compress: str | None = None,
                     residual: jax.Array | None = None):
     """The factored-mesh gradient sync itself (shared by the custom-VJP
-    points, the grad-accumulation path, and the 1F1B path): per-leaf
+    points and the grad-accumulation path): per-leaf
     flat psums over each leaf's remaining invariant axes, then the
     grouped two-level (data, dcn) reduction over the ``_sync_partition``
     buckets.  Leaves are grouped by their sharded axes:
@@ -1979,433 +1898,6 @@ def make_lm_pp_train_step(cfg: LMTrainConfig, mesh: Mesh):
     return step
 
 
-def _stack_layers(params: PyTree, n_layers: int) -> PyTree:
-    """Per-layer param subtrees -> one (L, ...)-stacked tree (pure data
-    movement, inside the step; the trainer keeps the DENSE layout)."""
-    layers = [params[f"layer{i}"] for i in range(n_layers)]
-    return jax.tree.map(lambda *ls: jnp.stack(ls), *layers)
-
-
-def _gather_stacked(stacked: PyTree, layer_spec: PyTree) -> PyTree:
-    """all_gather the fsdp ('data') dim of stacked layer leaves — the
-    per-layer spec's sharded dim shifted right past the layer-stack axis."""
-    def gather(p, spec):
-        for dim, ax in enumerate(spec):
-            if ax == DATA:
-                return jax.lax.all_gather(p, DATA, axis=dim + 1, tiled=True)
-        return p
-
-    return jax.tree.map(gather, stacked, layer_spec)
-
-
-def _pp_grad_sync(g: PyTree, specs: PyTree, cfg: LMTrainConfig) -> PyTree:
-    """The EXPLICIT gradient sync of the 1F1B path (its backward is
-    hand-emitted, so nothing is synthesized by shard_map's transpose):
-    fsdp leaves reduce-scatter over 'data' (the transpose of their
-    forward all_gather, written out), then either the factored-mesh
-    two-level (data, dcn) reduction (``_two_level_sync``, streamed per
-    ~25 MB bucket) or — on flat meshes — one flat psum per leaf over
-    every axis the leaf is invariant to.  Identical per-element sums to
-    the autodiff-era sync; emission point is the caller's (whole-tree
-    post-backward, or per-chunk under overlap)."""
-    def scatter(leaf, spec):
-        for dim, ax in enumerate(spec):
-            if ax == DATA:
-                return jax.lax.psum_scatter(leaf, DATA,
-                                            scatter_dimension=dim,
-                                            tiled=True)
-        return leaf
-
-    g = jax.tree.map(scatter, g, specs)
-    if cfg.dcn_size > 1:
-        return _two_level_sync(g, specs,
-                               bucket_bytes=_sync_bucket_bytes(cfg))
-
-    def flat(leaf, spec):
-        axes = _spec_axes(spec)
-        rest = tuple(a for a in (DATA, EXPERT, SEQ, MODEL)
-                     if a not in axes)
-        return jax.lax.psum(leaf, rest) if rest else leaf
-
-    return jax.tree.map(flat, g, specs)
-
-
-def make_lm_1f1b_train_step(cfg: LMTrainConfig, mesh: Mesh):
-    """Interleaved-1F1B pipeline train step (round 10): same signature as
-    ``make_lm_train_step``, params in the DENSE per-layer layout.
-
-    The transformer's layer groups are partitioned into ``pp_size *
-    interleave`` contiguous chunks — cut on the same layer-group
-    boundaries the streaming ZeRO-3 gathers and DCN sync points use —
-    chunk j on stage j % pp_size of a dedicated 'pp' mesh axis.  The step EMITS one forward or backward unit per
-    (chunk, microbatch) in the order of the one-forward-one-backward
-    timetable (``one_f_one_b_schedule``; M = microbatches * grad_accum),
-    with stage-boundary activations and cotangents moving as ``ppermute``
-    transfers over 'pp' and bounded rolling stashes carrying in-flight
-    state (``stash_plan`` — O(pp) deep, the 1F1B activation bound).
-
-    The backward is explicit — one ``jax.vjp`` per unit, seeded with the
-    stashed output cotangent (+ the CE seed on the last chunk) — and so
-    is every gradient reduction (``_pp_grad_sync``).  Consequences, both
-    test-pinned:
-
-    - the 1F1B reordering is a pure reassociation of the same microbatch
-      grads (per chunk, backwards run in ascending microbatch order;
-      the tied embedding's lookup- and head-path cotangents accumulate
-      in SEPARATE accumulators summed once at the end, so the
-      association is pp_size-independent): pp_size=N trains
-      bitwise-identically to pp_size=1;
-    - no collective is synthesized by autodiff — unlike the wave
-      scheduler.
-
-    ``overlap=True`` unrolls the clock loop and streams: each chunk's
-    ZeRO-3 gathers are emitted at its F/B clocks and its gradient sync
-    (psum('pp') + ``_pp_grad_sync``) right after its LAST backward unit —
-    interleaved with the other chunks' remaining backward matmuls.
-    ``overlap=False`` scans one uniform clock body (compile-cheap) with
-    the whole-tree gather up-front and the whole-tree sync post-backward.
-    Bitwise-identical either way at pp_size >= 2 (same elementwise sums,
-    moved — test-pinned); at pp_size=1 the unrolled clocks constant-fold
-    their schedule-table masks where the scanned body keeps them dynamic,
-    and the refused fusions reassociate f32 reductions sub-ulp (~1e-13
-    grads — the pp1+overlap corner pins allclose, not bitwise).
-
-    Cost model (SPMD, be honest about it): every rank traces ONE uniform
-    clock body that executes one forward unit AND one backward unit per
-    clock, masking the unscheduled one — the timetable gives each stage
-    at most one unit per clock, so the emitted program runs ~2x the
-    scheduled FLOPs in steady state and fill/drain clocks burn full
-    masked units.  This is the price of a single-program formulation:
-    the per-(stage, clock) kind is ``axis_index('pp')``-dependent, and
-    SPMD control flow cannot skip per-rank (a varying-predicate cond
-    executes both sides), while masking is exactly what makes the step
-    one program, bitwise-provable on a CPU mesh.  The bubble fraction
-    the inspector reports therefore measures the TIMETABLE (the thing a per-stage-program MPMD runtime would
-    execute), not this step's executed idle time; the bench A/B
-    (bench.py bench_train_pp) compares pp_size=N against pp_size=1
-    through this same builder, so both legs pay the same masking tax
-    and the ratio isolates the schedule.  Real-hardware deployment at
-    HBM-limit scale wants per-stage programs — BASELINE.md round-10
-    records this as the standing limitation.
-
-    Bitwise caveat (both schedulers' pins respect it): chunks must hold
-    >= 2 layers.  XLA unrolls a trip-count-1 layer scan and re-fuses it
-    with its neighbours sub-ulp differently (see the opt_barrier note in
-    parallel/pipeline.py _chunk); 1-layer chunks train correctly but
-    match pp_size=1 only to reassociation noise.  sp > 1 (ring
-    attention) composes the same way: losses bitwise-equal, grads to
-    reassociation noise only — the ring's own in-scan ppermute/matmul
-    residuals re-fuse with the chunk body beyond what the barrier pins.
-    """
-    from .parallel import pipeline as pp_mod
-
-    model = cfg.model
-    n = cfg.pp_size
-    v = cfg.interleave
-    n_chunks = n * v
-    m_micro = (cfg.microbatches or 2 * n) * cfg.grad_accum
-    per = model.n_layers // n_chunks
-    clocks = pp_mod.one_f_one_b_schedule(m_micro, n, v)
-    tabs = pp_mod.schedule_tables(clocks, n, m_micro, v)
-    x_depth, c_depth = pp_mod.stash_plan(clocks, n, m_micro, v)
-    t_total = len(clocks)
-    # last backward clock per chunk: where overlap streams its sync
-    last_b = {}
-    for t, clock in enumerate(clocks):
-        for s, (kind, c, m) in clock.items():
-            if kind == "B":
-                last_b[c] = t
-    finishing_at: dict[int, list[int]] = {}
-    for c, t in last_b.items():
-        finishing_at.setdefault(t, []).append(c)
-
-    specs = param_specs(cfg)
-    lspec = specs["layer0"]
-    shared_specs = {"embed": specs["embed"],
-                    "final_norm": specs["final_norm"]}
-    fsdp = cfg.fsdp
-    dtype = cfg.dtype
-    tp_axis = MODEL
-    seq_axis = SEQ if cfg.sp > 1 else None
-    is_moe = bool(model.n_experts)
-    batch_axes = _batch_axes(cfg)
-    # aux cotangent seed: d(aux_coef * pmean(aux_sum/M, batch+seq))/d unit
-    r_mean = int(np.prod([mesh.shape[a] for a in batch_axes + (SEQ,)]))
-    fwd_perm = [(i, (i + 1) % n) for i in range(n)]
-    rev_perm = [(i, (i - 1) % n) for i in range(n)]
-
-    tx = make_optimizer(cfg)
-
-    def local_grad(params, micro_t, micro_y, n_total, aux_w):
-        me = jax.lax.axis_index(PP)
-        mb_loc, s_loc = micro_t.shape[1], micro_t.shape[2]
-        d = model.d_model
-        cdtype = dtype or jnp.float32
-        pos = _shard_positions(cfg, s_loc)
-
-        shared = {"embed": params["embed"],
-                  "final_norm": params["final_norm"]}
-        if fsdp:
-            # the two shared leaves gather once (they are consumed at
-            # both ends of every schedule, not per chunk)
-            shared = _fsdp_gather(shared, shared_specs)
-        emb, fnorm = shared["embed"], shared["final_norm"]
-        stacked = _stack_layers(params, model.n_layers)
-        if fsdp and not cfg.overlap:
-            stacked = _gather_stacked(stacked, lspec)
-
-        def slice_chunk(chunk):
-            sl = jax.tree.map(
-                lambda x: jax.lax.dynamic_slice_in_dim(x, chunk * per,
-                                                       per, axis=0),
-                stacked)
-            if fsdp and cfg.overlap:
-                # streamed ZeRO-3: gather THIS unit's chunk at its clock
-                sl = _gather_stacked(sl, lspec)
-            return sl
-
-        ce_seed = 1.0 / jnp.maximum(n_total, 1)
-        aux_seed = aux_w / jnp.float32(r_mean)
-
-        def unit(chunk_layers, emb_in, emb_out, fn_, x_in, toks, tgts,
-                 is_first, is_last):
-            """The uniform (chunk, microbatch) body every rank traces:
-            embed-or-receive, the chunk's layer scan, and the (masked)
-            unembed head — first/last-chunk special-casing as masks, so
-            F and B units stay one traced program under SPMD.  The tied
-            embedding enters as TWO arguments so its lookup-path and
-            head-path cotangents come back separately (the
-            pp_size-independent accumulation the bitwise pin needs)."""
-            xe = emb_in[toks]
-            if dtype is not None:
-                xe = xe.astype(dtype)
-            x0 = jnp.where(is_first, xe, x_in)
-            y, aux = pp_mod._chunk(
-                chunk_layers, x0, cfg=model, attn_impl="flash",
-                tp_axis=tp_axis, seq_axis=seq_axis,
-                seq_layout=cfg.seq_layout, pos=pos, is_moe=is_moe)
-            h = tfm.rms_norm(y, fn_, model.norm_eps)
-            # the unified head-loss seam (ops/losses.py): dense traces the
-            # historical logits matmul + masked_ce bit-for-bit; the 1F1B
-            # head keeps the full vocab per rank (no tp vocab sharding)
-            ce, _ = losses.head_loss(h, emb_out, tgts,
-                                     loss_impl=cfg.loss_impl,
-                                     loss_chunk=cfg.loss_chunk)
-            return y, jnp.where(is_last, ce, 0.0), aux
-
-        def at2(buf, i, j):
-            row = jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
-            return jax.lax.dynamic_index_in_dim(row, j, 0, keepdims=False)
-
-        def put2(buf, i, j, val, valid):
-            cur = at2(buf, i, j)
-            val = jnp.where(valid, val, cur)
-            row = jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
-            row = jax.lax.dynamic_update_index_in_dim(row, val, j, 0)
-            return jax.lax.dynamic_update_index_in_dim(buf, row, i, 0)
-
-        def clock_body(carry, row):
-            x_st, c_st, acc_l, acc_ei, acc_eo, acc_fn, ce_acc, aux_acc = \
-                carry
-            r = {k: row[k][me] for k in row}
-            # -- forward unit (masked when this stage has none) ----------
-            f_chunk = r["f_k"] * n + me
-            toks_f = jax.lax.dynamic_index_in_dim(micro_t, r["f_m"], 0,
-                                                  keepdims=False)
-            tgts_f = jax.lax.dynamic_index_in_dim(micro_y, r["f_m"], 0,
-                                                  keepdims=False)
-            x_in_f = at2(x_st, r["f_k"], r["f_m"] % x_depth)
-            y_f, ce_f, aux_f = unit(
-                slice_chunk(f_chunk), emb, emb, fnorm, x_in_f,
-                toks_f, tgts_f, f_chunk == 0, f_chunk == n_chunks - 1)
-            fv = r["f_valid"].astype(jnp.float32)
-            ce_acc = ce_acc + ce_f * fv
-            aux_acc = aux_acc + aux_f * fv
-            # -- backward unit: explicit vjp, timetable-seeded -----------
-            b_chunk = r["b_k"] * n + me
-            toks_b = jax.lax.dynamic_index_in_dim(micro_t, r["b_m"], 0,
-                                                  keepdims=False)
-            tgts_b = jax.lax.dynamic_index_in_dim(micro_y, r["b_m"], 0,
-                                                  keepdims=False)
-            x_in_b = at2(x_st, r["b_k"], r["b_m"] % x_depth)
-            b_first = b_chunk == 0
-            b_last = b_chunk == n_chunks - 1
-            cot_y = at2(c_st, r["b_k"], r["b_m"] % c_depth)
-            cot_y = jnp.where(b_last, jnp.zeros_like(cot_y), cot_y)
-            _, vjp_fn = jax.vjp(
-                lambda cl, ei, eo, fn_, xi: unit(
-                    cl, ei, eo, fn_, xi, toks_b, tgts_b, b_first, b_last),
-                slice_chunk(b_chunk), emb, emb, fnorm, x_in_b)
-            g_cl, g_ei, g_eo, g_fn, g_xi = vjp_fn(
-                (cot_y, ce_seed, aux_seed))
-            bv = r["b_valid"] != 0
-            off = b_chunk * per
-            acc_l = jax.tree.map(
-                lambda a, g: jax.lax.dynamic_update_slice_in_dim(
-                    a, jax.lax.dynamic_slice_in_dim(a, off, per, axis=0)
-                    + jnp.where(bv, g, jnp.zeros_like(g)), off, axis=0),
-                acc_l, g_cl)
-            acc_ei = acc_ei + jnp.where(bv, g_ei, jnp.zeros_like(g_ei))
-            acc_eo = acc_eo + jnp.where(bv, g_eo, jnp.zeros_like(g_eo))
-            acc_fn = acc_fn + jnp.where(bv, g_fn, jnp.zeros_like(g_fn))
-            # -- stage-boundary ring hops (the 'pp'-axis transfers) ------
-            recv_f = jax.lax.ppermute(y_f, PP, fwd_perm)
-            recv_b = jax.lax.ppermute(g_xi, PP, rev_perm)
-            x_st = put2(x_st, r["fr_k"], r["fr_m"] % x_depth, recv_f,
-                        r["fr_valid"] != 0)
-            c_st = put2(c_st, r["br_k"], r["br_m"] % c_depth, recv_b,
-                        r["br_valid"] != 0)
-            return (x_st, c_st, acc_l, acc_ei, acc_eo, acc_fn, ce_acc,
-                    aux_acc)
-
-        # full-size layer-grad accumulator: each rank fills only its own
-        # chunks' slots; psum('pp') assembles the rest (zeros elsewhere,
-        # so the merge adds exact zeros — bitwise-neutral).  Under
-        # fsdp+overlap the stacked closure holds SHARDS (chunks gather at
-        # their clocks), so the full accumulator shape is computed, not
-        # gathered.
-        if fsdp and cfg.overlap:
-            n_data = mesh.shape[DATA]
-
-            def full_zeros(x, spec):
-                shape = list(x.shape)
-                for dim, ax in enumerate(spec):
-                    if ax == DATA:
-                        shape[dim + 1] *= n_data
-                        break
-                return jnp.zeros(shape, x.dtype)
-
-            acc_l0 = jax.tree.map(full_zeros, stacked, lspec)
-        else:
-            acc_l0 = jax.tree.map(jnp.zeros_like, stacked)
-        # carries/accumulators mix with pp-varying (and batch-varying)
-        # values inside the clock loop: pre-cast them varying so the
-        # scan carry vma is stable
-        want_vma = compat.vma_of(
-            jnp.zeros((), jnp.float32)) | {PP} | compat.vma_of(micro_t)
-
-        def _varying(x):
-            missing = tuple(a for a in want_vma
-                            if a not in compat.vma_of(x))
-            return compat.pcast(x, missing, to="varying") if missing else x
-
-        ce_seed = _varying(ce_seed)
-        aux_seed = _varying(aux_seed)
-        carry = jax.tree.map(_varying, (
-            jnp.zeros((v, x_depth, mb_loc, s_loc, d), cdtype),
-            jnp.zeros((v, c_depth, mb_loc, s_loc, d), cdtype),
-            acc_l0,
-            jnp.zeros_like(emb), jnp.zeros_like(emb),
-            jnp.zeros_like(fnorm),
-            jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32),
-        ))
-
-        if not cfg.overlap:
-            xs = {k: jnp.asarray(a) for k, a in tabs.items()}
-            carry, _ = jax.lax.scan(
-                lambda c, row: (clock_body(c, row), None), carry, xs)
-            x_st, c_st, acc_l, acc_ei, acc_eo, acc_fn, ce_acc, aux_acc = \
-                carry
-            acc_l = jax.tree.map(lambda g: jax.lax.psum(g, PP), acc_l)
-            g_layers = {
-                f"layer{i}": jax.tree.map(lambda x, i=i: x[i], acc_l)
-                for i in range(model.n_layers)}
-        else:
-            synced: dict[int, PyTree] = {}
-            for t in range(t_total):
-                row = {k: jnp.asarray(a[t]) for k, a in tabs.items()}
-                # same clock-boundary fusion barrier the scanned path gets
-                # from its while-loop body (parallel/pipeline.py _chunk
-                # documents the failure mode): without it XLA fuses
-                # ACROSS unrolled clocks and overlap drifts sub-ulp off
-                # the scanned schedule when the 'pp' collectives compile
-                # away (pp_size=1 — the degenerate-schedule pin)
-                carry = jax.lax.optimization_barrier(carry)
-                carry = clock_body(carry, row)
-                (x_st, c_st, acc_l, acc_ei, acc_eo, acc_fn, ce_acc,
-                 aux_acc) = carry
-                for c in finishing_at.get(t, ()):
-                    # stream chunk c's sync right after its last backward
-                    sl = jax.tree.map(lambda x: x[c * per:(c + 1) * per],
-                                      acc_l)
-                    sl = jax.tree.map(lambda g: jax.lax.psum(g, PP), sl)
-                    sub = {f"layer{c * per + i}":
-                           jax.tree.map(lambda x, i=i: x[i], sl)
-                           for i in range(per)}
-                    sub_specs = {k: lspec for k in sub}
-                    synced.update(_pp_grad_sync(sub, sub_specs, cfg))
-            g_layers = synced
-        # tied embedding: lookup- and head-path accumulators merge ONCE,
-        # after their 'pp' psums — a pp_size-independent association
-        g_emb = jax.lax.psum(acc_ei, PP) + jax.lax.psum(acc_eo, PP)
-        g_fn = jax.lax.psum(acc_fn, PP)
-        g_shared = _pp_grad_sync({"embed": g_emb, "final_norm": g_fn},
-                                 shared_specs, cfg)
-        if not cfg.overlap:
-            g_layers = _pp_grad_sync(
-                g_layers, {k: lspec for k in g_layers}, cfg)
-        grads = dict(g_layers)
-        grads["embed"] = g_shared["embed"]
-        grads["final_norm"] = g_shared["final_norm"]
-        ce_tot = jax.lax.psum(ce_acc, batch_axes + (SEQ, PP))
-        # aux_w arrives as coef/M (the per-unit weight, same convention
-        # as the grad_accum path), so the reported aux term is
-        # coef * mean-over-units — matching make_lm_train_step's loss
-        # and the aux_seed the backward units were seeded with
-        aux_tot = jax.lax.psum(aux_acc, (PP,))
-        aux_tot = jax.lax.pmean(aux_tot, batch_axes + (SEQ,))
-        loss = ce_tot / jnp.maximum(n_total, 1) + aux_w * aux_tot
-        return loss, grads
-
-    bspec = _lm_batch_spec(cfg)
-    mspec = P(None, *bspec)
-    grad_step = shard_map(
-        local_grad, mesh=mesh,
-        in_specs=(specs, mspec, mspec, P(), P()),
-        out_specs=(P(), specs))
-
-    coef = jnp.float32(cfg.aux_coef)
-
-    @partial(jax.jit, donate_argnums=compat.donate(0, 1))
-    def step(params, opt_state, tokens, targets, step_no=0,
-             fault_arm=0.0):
-        tokens = _zigzag_global(cfg, tokens)
-        targets = _zigzag_global(cfg, targets)
-        n_total = jnp.sum(targets != IGNORE).astype(jnp.float32)
-        b = tokens.shape[0]
-        if b % (m_micro * cfg.dp * cfg.ep):
-            raise ValueError(
-                f"global batch {b} not divisible into {m_micro} "
-                f"microbatches (pp_size={n} x microbatches="
-                f"{cfg.microbatches or 2 * n} x grad_accum="
-                f"{cfg.grad_accum}) of dp*ep={cfg.dp * cfg.ep}-divisible "
-                f"size")
-        mb = b // m_micro
-        # INTERLEAVED split, exactly the grad_accum path's (microbatch j
-        # = rows j, j+M, j+2M, ...): resharding-free, and the microbatch
-        # contents match the pp_size=1 baseline row for row
-        micro_t = tokens.reshape(mb, m_micro, -1).swapaxes(0, 1)
-        micro_y = targets.reshape(mb, m_micro, -1).swapaxes(0, 1)
-        loss, grads = grad_step(params, micro_t, micro_y, n_total,
-                                coef / m_micro)
-        grads = faults.tap_grads(grads, step_no, fault_arm)
-        loss = faults.tap_loss(loss, step_no, fault_arm)
-        gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                  for g in jax.tree.leaves(grads))
-        ok = (jnp.isfinite(loss) & jnp.isfinite(gsq)).astype(jnp.float32)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        met = _step_metrics(gsq, params)
-        return params, opt_state, loss, ok, met
-
-    # surface the timetable for the schedule inspector / bench: the
-    # emitted order IS this data (utils/debug.assert_pipeline_schedule)
-    step.pp_clocks = clocks
-    step.pp_meta = {"n_stages": n, "n_micro": m_micro, "interleave": v,
-                    "x_depth": x_depth, "cot_depth": c_depth}
-    return step
-
-
 def make_lm_eval_step(cfg: LMTrainConfig, mesh: Mesh):
     """Forward-only masked-CE: (params, tokens, targets) -> (ce_sum, count),
     globally reduced.  Works for the (data, seq, model) mesh; the pp layout
@@ -2553,10 +2045,7 @@ def make_lm_pp_eval_step(cfg: LMTrainConfig, mesh: Mesh):
 class LMTrainer:
     """Owns (params, opt_state) laid out over the (data, seq, model) mesh —
     the (data, pipe, seq, model) mesh when cfg.pp > 1 (the wave
-    scheduler's stage-stacked layout) — or the ('pp', data, ...) mesh
-    when cfg.pp_size > 0 (interleaved-1F1B; params keep the DENSE
-    per-layer layout, pp-replicated, so checkpoints/eval/param_specs are
-    layout-identical to the non-pp trainer)."""
+    scheduler's stage-stacked layout)."""
 
     def __init__(self, cfg: LMTrainConfig, mesh: Mesh | None = None):
         # sync_plan="auto" (round 11): resolve FIRST into explicit
@@ -2584,15 +2073,11 @@ class LMTrainer:
         # ignored by whichever step builder does not read the setting
         validate_lm_cfg(cfg)
         self.mesh = mesh if mesh is not None else make_lm_mesh(cfg)
-        want = (cfg.dp * cfg.ep * cfg.sp * cfg.tp * cfg.pp
-                * max(cfg.pp_size, 1))
+        want = cfg.dp * cfg.ep * cfg.sp * cfg.tp * cfg.pp
         assert self.mesh.devices.size == want, (
             f"mesh has {self.mesh.devices.size} devices, config wants {want}")
         # batch sharding: (data, expert) jointly split the batch on the
         # non-pp mesh; the pp mesh has no expert axis (ep=1 enforced).
-        # The 1F1B mesh keeps the non-pp batch spec — every stage holds
-        # the full (data, expert)-sharded batch, pp-replicated (stages
-        # consume different microbatch slices of it per clock).
         self._batch_spec = (P(DATA, SEQ) if cfg.pp > 1
                             else _lm_batch_spec(cfg))
 
@@ -2601,18 +2086,7 @@ class LMTrainer:
                              "mesh, not with pp")
         params = tfm.init(jax.random.key(cfg.seed), cfg.model)
         tx = make_optimizer(cfg)
-        if cfg.pp_size > 0:
-            # interleaved-1F1B: dense layout over the 'pp' mesh —
-            # param_specs carry no 'pp' entry, so every leaf replicates
-            # across stages (each stage reads only its own chunks'
-            # slices inside the step; ZeRO-3 shards still apply within
-            # the stage via the 'data' axis)
-            specs = param_specs(cfg)
-            params = jax.tree.map(
-                lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-                params, specs)
-            self._install_step_fns(self._build_step_fn(cfg, self.mesh))
-        elif cfg.pp > 1:
+        if cfg.pp > 1:
             from .parallel import pipeline as pp
             stages, shared = pp.split_layer_params(
                 params, cfg.model, cfg.pp, interleave=cfg.interleave)
@@ -2703,9 +2177,7 @@ class LMTrainer:
         keyed by layout + clip so a sentry tighten or elastic rebuild
         shows up as a NEW program in the trace.  Telemetry off: the
         span is a no-op and the build is byte-identical."""
-        if cfg.pp_size > 0:
-            kind, builder = "1f1b", make_lm_1f1b_train_step
-        elif cfg.pp > 1:
+        if cfg.pp > 1:
             kind, builder = "pp", make_lm_pp_train_step
         elif cfg.sync_every > 1:
             # round 18: the communication-sparse program family (local
@@ -2804,8 +2276,8 @@ class LMTrainer:
         re-initializes (safe to drop); compiled step/eval functions are
         discarded; the step counter survives.
 
-        Pipeline meshes refuse: pp/pp_size stage placement is baked into
-        the hand-emitted step, so a pipelined gang resizes by relaunch,
+        Pipeline meshes refuse: pp stage placement is baked into
+        the stage-stacked step, so a pipelined gang resizes by relaunch,
         not rebuild (the lm_cli --elastic refusal mirrors this).
         Single-controller only — a multi-process gang resizes via the
         elastic agent's drain + re-rendezvous."""
@@ -2817,10 +2289,10 @@ class LMTrainer:
         import dataclasses
         cfg = (dataclasses.replace(self.cfg, **overrides) if overrides
                else self.cfg)
-        if cfg.pp > 1 or cfg.pp_size > 0:
+        if cfg.pp > 1:
             raise ValueError(
-                "cannot resize a pipeline (pp/pp_size) config for now: "
-                "stage placement is baked into the hand-emitted step — "
+                "cannot resize a pipeline (pp) config for now: "
+                "stage placement is baked into the stage-stacked step — "
                 "relaunch at the new size instead")
         validate_lm_cfg(cfg)
         new_mesh = mesh if mesh is not None else make_lm_mesh(cfg)
@@ -3140,10 +2612,10 @@ class LMTrainer:
         assembly per step; a host that also runs data loading).  Not
         available with pp > 1 (its step carries pipeline-stacked
         params)."""
-        if self.cfg.pp > 1 or self.cfg.pp_size > 0:
+        if self.cfg.pp > 1:
             raise ValueError("train_steps (K-step scan) supports the "
                              "(data, expert, seq, model) layout; with pp "
-                             "or pp_size use train_step")
+                             "use train_step")
         if self.cfg.grad_accum > 1:
             raise ValueError("train_steps does not implement gradient "
                              "accumulation; use train_step with "

@@ -71,20 +71,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="gradient-accumulation microbatches per optimizer "
                         "step (peak activation memory drops ~A-fold; CE "
                         "gradient exact)")
-    p.add_argument("--pp-size", type=int, default=0,
-                   help="interleaved-1F1B pipeline stages over a dedicated "
-                        "'pp' mesh axis (round 10): layer chunks cut on "
-                        "layer-group boundaries, one-forward-one-backward "
-                        "microbatch schedule with explicit per-unit "
-                        "backward, bitwise-identical trajectory to "
-                        "pp_size=1 (composes with --fsdp/--tp/--dcn-size/"
-                        "--grad-accum/--overlap; distinct from --pp, the "
-                        "forward-wave scheduler)")
     p.add_argument("--microbatches", type=int, default=0,
-                   help="in-flight microbatches per optimizer step for "
-                        "--pp-size (M >= pp_size required; steady-state "
-                        "bubble fraction is (pp-1)/(pp-1+M); default "
-                        "2*pp_size)")
+                   help="microbatches per optimizer step for --pp (the "
+                        "wave schedule admits them pp at a time; the "
+                        "local batch must divide into them; default "
+                        "2*pp)")
     p.add_argument("--interleave", type=int, default=1,
                    help="virtual pipeline stages per device (shrinks the "
                         "pipeline bubble by this factor)")
@@ -138,8 +129,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "'selective' additionally saves the flash "
                         "kernel's (o, lse) so only the projections/MLP "
                         "recompute.  Losses bitwise-equal to 'none' "
-                        "(test-pinned); does not compose with --pp/"
-                        "--pp-size (the pipeline owns its own remat)")
+                        "(test-pinned); does not compose with --pp "
+                        "(the pipeline owns its own remat)")
     p.add_argument("--bucket-mb", type=float, default=None,
                    help="streaming bucket size for the factored-mesh "
                         "exchange (default: the 25 MB torch-DDP cap)")
@@ -173,7 +164,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "— the ICI hop still syncs every step, the DCN "
                         "hop only at window boundaries (~1/H dcn "
                         "bytes/step; requires --dcn-size >= 2, no "
-                        "--pp/--pp-size, --grad-accum 1)")
+                        "--pp, --grad-accum 1)")
     p.add_argument("--staleness", type=int, default=0,
                    help="bounded staleness for --sync-every: launch the "
                         "window exchange at step kH and apply it at "
@@ -325,10 +316,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.loss_chunk is not None and args.loss_impl != "chunked":
         parser.error("--loss-chunk tunes the chunked head; pass "
                      "--loss-impl chunked (or drop the chunk size)")
-    if args.remat in ("full", "selective") and (args.pp > 1
-                                                or args.pp_size > 0):
-        parser.error("--remat does not compose with --pp/--pp-size: the "
-                     "pipeline schedulers own their own rematerialization "
+    if args.remat in ("full", "selective") and args.pp > 1:
+        parser.error("--remat does not compose with --pp: the "
+                     "pipeline scheduler owns its own rematerialization "
                      "(each tick block is already checkpointed); drop one")
     max_sync_every = (args.max_sync_every if args.max_sync_every is not None
                       else max(args.sync_every, 1))
@@ -352,8 +342,7 @@ def main(argv: list[str] | None = None) -> int:
             require_sync_window(
                 sync_every=args.sync_every, staleness=args.staleness,
                 max_sync_every=max_sync_every, mesh=True,
-                overlap=args.overlap,
-                pp=args.pp > 1 or args.pp_size > 0,
+                overlap=args.overlap, pp=args.pp > 1,
                 grad_accum=args.grad_accum, dcn_size=args.dcn_size,
                 trainer="lm", outer_opt=args.outer_opt,
                 outer_momentum=args.outer_momentum,
@@ -363,13 +352,13 @@ def main(argv: list[str] | None = None) -> int:
             parser.error(str(e))
     if args.elastic:
         # refuse loudly anything that CANNOT resize: a pipeline's stage
-        # placement is baked into the hand-emitted step, so a resized
+        # placement is baked into the stage-stacked step, so a resized
         # world has no program to resume into (LMTrainer.rebuild refuses
         # for the same reason)
-        if args.pp_size > 1 or args.pp > 1:
+        if args.pp > 1:
             parser.error(
-                "--elastic cannot resize pipeline configs (--pp/--pp-size "
-                "> 1): stage placement is baked into the compiled step; "
+                "--elastic cannot resize pipeline configs (--pp > 1): "
+                "stage placement is baked into the compiled step; "
                 "drop the pipeline axis or --elastic")
         if not args.checkpoint_dir:
             parser.error(
@@ -402,7 +391,7 @@ def main(argv: list[str] | None = None) -> int:
                        else args.compute_dtype),
         warmup_steps=args.warmup_steps, decay_steps=args.decay_steps,
         dp=args.dp, sp=args.sp, tp=args.tp, pp=args.pp, ep=args.ep,
-        pp_size=args.pp_size, microbatches=args.microbatches,
+        microbatches=args.microbatches,
         dcn_size=args.dcn_size, grad_accum=args.grad_accum,
         interleave=args.interleave, fsdp=args.fsdp, overlap=args.overlap,
         dcn_compress=args.dcn_compress, bucket_mb=args.bucket_mb,
@@ -435,9 +424,9 @@ def main(argv: list[str] | None = None) -> int:
                      ectx.rank, ectx.world_size, ectx.generation,
                      ectx.min_nodes, ectx.max_nodes)
     log.info("model: %s | mesh: dp=%d (dcn=%d) ep=%d sp=%d tp=%d pp=%d "
-             "pp_size=%d over %d devices",
+             "over %d devices",
              cfg.model, args.dp, args.dcn_size, args.ep, args.sp, args.tp,
-             args.pp, args.pp_size, trainer.mesh.devices.size)
+             args.pp, trainer.mesh.devices.size)
 
     start = 0
     if args.checkpoint_dir:

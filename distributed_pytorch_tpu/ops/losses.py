@@ -9,8 +9,8 @@ strategy amortizes against.
 
 Two exports:
 
-- :func:`head_loss` — the ONE seam all four head-loss sites route through
-  (lm.py's train/1F1B/eval builders and parallel/pipeline.py's wave tick;
+- :func:`head_loss` — the ONE seam all three head-loss sites route through
+  (lm.py's train and eval builders and parallel/pipeline.py's wave tick;
   the round-13 ``step_metrics`` consolidation pattern).  ``loss_impl="dense"``
   traces the historical op sequence bit-for-bit; ``"chunked"`` streams.
 - :func:`masked_ce_chunked` — a custom-vjp loss that scans the head
@@ -42,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..utils import compat
 from .nn import IGNORE_INDEX, masked_ce
 
 Array = jax.Array
@@ -101,9 +102,11 @@ def _fwd_core(h, emb, targets, chunk, tp_axis, tp_size):
         tl = tl + jnp.where(own, got, 0.0)
         return (m_new, s, tl), None
 
-    init = (jnp.full((n,), -jnp.inf, jnp.float32),
-            jnp.zeros((n,), jnp.float32),
-            jnp.zeros((n,), jnp.float32))
+    axes = compat.vma_of(h2) | compat.vma_of(emb_l)
+    init = tuple(compat.varying(c, axes) for c in (
+        jnp.full((n,), -jnp.inf, jnp.float32),
+        jnp.zeros((n,), jnp.float32),
+        jnp.zeros((n,), jnp.float32)))
     (m, s, tl), _ = lax.scan(body, init, jnp.arange(n_chunks))
 
     if tp_axis is not None and tp_size > 1:
@@ -155,13 +158,17 @@ def _ce_chunked_bwd(chunk, tp_axis, tp_size, res, g):
         dw = coeff.T @ h2                           # (chunk, d)
         return dh, dw
 
-    dh, dws = lax.scan(body, jnp.zeros_like(h2), jnp.arange(n_chunks))
+    dh0 = compat.varying(jnp.zeros_like(h2), compat.vma_of(emb_l))
+    dh, dws = lax.scan(body, dh0, jnp.arange(n_chunks))
     demb = dws.reshape(v_local, h.shape[-1])
+    # summed over the mesh axes the head broadcast the embedding over, as
+    # autodiff sums the dense head's
+    demb = compat.as_cotangent(demb, emb_l)
     if tp_axis is not None and tp_size > 1:
         # each rank holds the partial dh for ITS vocab slice and the full
         # demb for its rows: reduce / reassemble, replicated like dense
         dh = lax.psum(dh, tp_axis)
-        demb = lax.all_gather(demb, tp_axis, axis=0, tiled=True)
+        demb = compat.all_gather_invariant(demb, tp_axis, axis=0, tiled=True)
     dh = dh.reshape(h.shape).astype(h.dtype)
     demb = demb.astype(emb.dtype)
     dtargets = np.zeros(targets.shape, dtype=jax.dtypes.float0)

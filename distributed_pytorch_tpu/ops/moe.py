@@ -293,22 +293,6 @@ ROW_TILE = 512
 CHUNK = ROW_TILE
 
 
-def _varying(x, axes):
-    """``x`` varying over the mesh axes ``axes`` as well (a loop's carry has
-    to start typed as its body leaves it)."""
-    missing = frozenset(axes) - compat.vma_of(x)
-    return compat.pcast(x, tuple(missing), to="varying") if missing else x
-
-
-def _as_cotangent(g, primal):
-    """``g`` typed as the cotangent of ``primal``: summed over the mesh
-    axes it varies over and ``primal`` does not (what autodiff does for an
-    input that is the same on every shard), varying over the rest."""
-    extra = compat.vma_of(g) - compat.vma_of(primal)
-    return _varying(lax.psum(g, tuple(extra)) if extra else g,
-                    compat.vma_of(primal))
-
-
 def _over_live(f, n_live, *rows):
     """``f(*chunks) -> tuple of (CHUNK, ...)`` over the chunks of ``rows``
     (arrays whose leading dimension is the buffer's) that hold a live row,
@@ -318,8 +302,8 @@ def _over_live(f, n_live, *rows):
     only, as XLA's grouped products do."""
     n_rows = rows[0].shape[0]
     outs = jax.eval_shape(lambda: f(*(r[:CHUNK] for r in rows)))
-    init = tuple(_varying(lax.empty((n_rows,) + o.shape[1:], o.dtype),
-                          (o.vma or frozenset()) | compat.vma_of(n_live))
+    init = tuple(compat.varying(lax.empty((n_rows,) + o.shape[1:], o.dtype),
+                                (o.vma or frozenset()) | compat.vma_of(n_live))
                  for o in outs)
 
     def body(i, bufs):
@@ -367,8 +351,9 @@ def _collect(bufs, lay):
         return lax.dynamic_update_slice_in_dim(
             acc, lax.dynamic_slice_in_dim(acc, lo, CHUNK) + rows, lo, 0)
 
-    acc = _varying(jnp.zeros((n_tok, bufs[0].shape[1]), jnp.float32),
-                   frozenset().union(*map(compat.vma_of, (front, *bufs))))
+    acc = compat.varying(
+        jnp.zeros((n_tok, bufs[0].shape[1]), jnp.float32),
+        frozenset().union(*map(compat.vma_of, (front, *bufs))))
     acc = lax.fori_loop(0, ends[-1], add, acc)
     return acc[lay["back"]].astype(bufs[0].dtype)
 
@@ -401,7 +386,7 @@ def _expand_bwd(res, cts):
             lambda a, w=w: lax.ragged_dot(a, w, lay["padded"]), xs)(g)[0])
         dws.append(jax.linear_transpose(
             lambda b: lax.ragged_dot(xs, b, lay["padded"]), w)(g)[0])
-    return (*map(_as_cotangent, (_collect(dxs, lay), *dws),
+    return (*map(compat.as_cotangent, (_collect(dxs, lay), *dws),
                  (x0, w_gate, w_up)), None)
 
 
@@ -456,7 +441,7 @@ def _contract_bwd(act, res, g):
         lambda g, u, w_row, dh: jax.vjp(weighted, g, u, w_row)[1](dh),
         lay["n_live"], gate, up, w_row, dh)
     dw = jnp.where(lay["dest"] >= 0, dw_row[jnp.maximum(lay["dest"], 0)], 0)
-    return *map(_as_cotangent, (dgate, dup, dw, dw_down),
+    return *map(compat.as_cotangent, (dgate, dup, dw, dw_down),
                 (gate, up, w0, w_down)), None
 
 

@@ -1878,15 +1878,15 @@ def resolve_lm_auto(cfg):
     # windows require the windowed step family: no pipeline, no grad
     # accumulation (require_sync_window) — gate the interval dimension
     # rather than choose a plan the trainer would then refuse
-    windowable = (cfg.pp_size == 0 and cfg.pp == 1
-                  and cfg.grad_accum == 1 and cfg.dcn_size > 1)
+    windowable = (cfg.pp == 1 and cfg.grad_accum == 1
+                  and cfg.dcn_size > 1)
     plan = choose_lm_plan(
         census, profile, dcn_size=cfg.dcn_size, overlap=cfg.overlap,
         grad_accum=cfg.grad_accum,
-        # the pipeline steps have no sync-state channel (validate_lm_cfg
+        # the pipeline step has no sync-state channel (validate_lm_cfg
         # rejects dcn_compress there): keep int8 out of the candidates
         # instead of choosing a plan the trainer would then refuse
-        allow_compress=cfg.pp_size == 0 and cfg.pp == 1,
+        allow_compress=cfg.pp == 1,
         max_sync_every=cfg.max_sync_every if windowable else 1,
         ladder=(BUCKET_LADDER_MB if cfg.bucket_mb is None
                 else (float(cfg.bucket_mb),)))
@@ -1920,8 +1920,7 @@ def resolve_lm_route(cfg):
     from .strategies import require_lm_route
 
     plan = routing.parse_route(cfg.sync_route)
-    require_lm_route(plan, dcn=cfg.dcn_size > 1,
-                     pp=cfg.pp > 1 or cfg.pp_size > 0,
+    require_lm_route(plan, dcn=cfg.dcn_size > 1, pp=cfg.pp > 1,
                      dcn_compress=cfg.dcn_compress,
                      sync_plan=cfg.sync_plan)
     ring_bits = [h.bits for h in plan.hops

@@ -198,233 +198,6 @@ def num_ticks(m_micro: int, n: int, interleave: int) -> int:
             + ((m_micro - 1) % n) + n)
 
 
-# ---------------------------------------------------------------------------
-# Interleaved-1F1B over the 'pp' mesh axis (round 10).
-#
-# The wave schedule above is the forward-only SPMD formulation (one scanned
-# tick body, backward synthesized by autodiff).  The 1F1B machinery below is
-# its training-schedule sibling for lm.py's ``pp_size``: the transformer's
-# layer GROUPS (models/transformer.sync_group_index — the same boundary
-# schedule that places the streaming ZeRO-3 gathers and DCN sync points)
-# are partitioned into ``pp_size * interleave`` contiguous chunks, chunk j
-# living on physical stage j % pp_size (Megatron's round-robin interleaved
-# placement), and the train step EMITS each (chunk, microbatch) forward/
-# backward unit in the order of an explicit one-forward-one-backward
-# timetable, with the stage-boundary activation handoffs expressed as
-# ppermute transfers over the 'pp' axis.  The timetable is data (a list of
-# clocks), so the schedule the program was emitted in is directly
-# measurable — utils/debug.py ``assert_pipeline_schedule`` checks 1F1B
-# well-formedness and the fill/drain bubble against the analytic
-# (pp-1)/(pp-1+M) bound, the same way the round-8/9 inspector pins
-# collective interleaving.
-#
-# Unlike the wave schedule, the 1F1B step's backward is NOT synthesized by
-# autodiff-through-the-scan: lm.py emits one explicit ``jax.vjp`` per
-# (chunk, microbatch) backward unit in timetable order, with every
-# cross-device reduction written out by hand.  That makes the schedule a
-# first-class program property (the thing the inspector measures).
-# ---------------------------------------------------------------------------
-
-
-def one_f_one_b_schedule(n_micro: int, n_stages: int,
-                         interleave: int = 1) -> list[dict]:
-    """The interleaved-1F1B timetable: a list of clocks, each a dict
-    ``{stage: (kind, chunk, microbatch)}`` with kind "F" or "B".
-
-    Generated by a work-conserving greedy simulation of the classic
-    policy — every stage runs, each clock, its earliest-microbatch READY
-    backward if one exists (a backward is ready once its own forward and
-    the downstream chunk's backward finished in an EARLIER clock), else
-    its earliest ready forward.  For interleave=1 this reproduces the
-    textbook 1F1B schedule exactly (warmup forwards, steady-state strict
-    F/B alternation, backward drain) and meets the analytic bubble bound
-    (pp-1)/(pp-1+M); with interleave > 1 the virtual chunks round-robin
-    through the same policy.  Per chunk, backwards execute in ascending
-    microbatch order — the property that makes the 1F1B reordering a
-    pure reassociation of the grad-accumulation sum (lm.py's bitwise
-    claim)."""
-    if n_micro < 1:
-        raise ValueError(f"need >= 1 microbatch, got {n_micro}")
-    n_chunks = n_stages * interleave
-    done_f: dict[tuple[int, int], int] = {}   # (chunk, micro) -> clock
-    done_b: dict[tuple[int, int], int] = {}
-    next_f = [0] * n_chunks
-    next_b = [0] * n_chunks
-    clocks: list[dict] = []
-    total = 2 * n_micro * n_chunks
-    while len(done_f) + len(done_b) < total:
-        clock: dict[int, tuple] = {}
-        for s in range(n_stages):
-            op = None
-            cand_b = []
-            for k in range(interleave):
-                c = k * n_stages + s
-                m = next_b[c]
-                if (m < n_micro and (c, m) in done_f
-                        and (c == n_chunks - 1 or (c + 1, m) in done_b)):
-                    cand_b.append((m, -c))
-            if cand_b:
-                m, neg_c = min(cand_b)
-                op = ("B", -neg_c, m)
-            else:
-                cand_f = []
-                for k in range(interleave):
-                    c = k * n_stages + s
-                    m = next_f[c]
-                    if m < n_micro and (c == 0 or (c - 1, m) in done_f):
-                        cand_f.append((m, c))
-                if cand_f:
-                    m, c = min(cand_f)
-                    op = ("F", c, m)
-            if op is not None:
-                clock[s] = op
-        if not clock:  # pragma: no cover - a policy bug, not a data case
-            raise AssertionError(
-                f"1F1B schedule deadlocked at clock {len(clocks)} "
-                f"(M={n_micro}, stages={n_stages}, v={interleave})")
-        t = len(clocks)
-        for s, (kind, c, m) in clock.items():
-            if kind == "F":
-                done_f[(c, m)] = t
-                next_f[c] = m + 1
-            else:
-                done_b[(c, m)] = t
-                next_b[c] = m + 1
-        clocks.append(clock)
-    return clocks
-
-
-def bubble_fraction(clocks: list[dict], n_stages: int) -> float:
-    """Measured bubble of a timetable: the fraction of (stage, clock)
-    slots with no scheduled unit.  For the textbook 1F1B timetable this
-    equals the analytic fill/drain bound exactly — see
-    ``analytic_bubble_bound`` (the ONE definition of that bound — the
-    schedule inspector imports it)."""
-    busy = sum(len(c) for c in clocks)
-    slots = n_stages * len(clocks)
-    return 1.0 - busy / slots if slots else 0.0
-
-
-def analytic_bubble_bound(n_stages: int, n_micro: int,
-                          interleave: int = 1) -> float:
-    """The interleaved-1F1B fill/drain bubble bound in chunk-clock units:
-    ``(pp-1) / (pp-1 + M*v)`` — the classic (pp-1)/(pp-1+M) at
-    interleave 1, shrinking v-fold with virtual stages (each of the M*v
-    chunk-passes per stage is 1/v the work, but the fill/drain ramp stays
-    pp-1 chunk-clocks)."""
-    denom = n_stages - 1 + n_micro * interleave
-    return (n_stages - 1) / denom if denom else 0.0
-
-
-def schedule_tables(clocks: list[dict], n_stages: int, n_micro: int,
-                    interleave: int = 1) -> dict:
-    """Compile a 1F1B timetable into the dense per-(clock, stage) arrays
-    the SPMD train step indexes with ``axis_index('pp')`` — the bridge
-    from the timetable-as-data to the uniform per-clock program every
-    rank traces.
-
-    Returns int32/bool numpy arrays of shape (T, n_stages):
-
-    - ``f_valid/f_k/f_m``: this stage runs a forward unit this clock, on
-      its local virtual-stage slot ``f_k`` (chunk ``f_k*n + s``) and
-      microbatch ``f_m``;
-    - ``b_valid/b_k/b_m``: same for backward units;
-    - ``fr_valid/fr_k/fr_m``: the stage RECEIVES a forward activation
-      this clock (the upstream neighbour ran F on the preceding chunk),
-      to stash for local slot ``fr_k``'s microbatch ``fr_m``;
-    - ``br_valid/br_k/br_m``: same for backward cotangents arriving from
-      the downstream neighbour.
-
-    Invalid slots carry index 0 (the step masks them out).
-    """
-    import numpy as np
-
-    n_chunks = n_stages * interleave
-    t_total = len(clocks)
-    z = lambda: np.zeros((t_total, n_stages), np.int32)  # noqa: E731
-    f = {k: z() for k in ("f_valid", "f_k", "f_m", "b_valid", "b_k", "b_m",
-                          "fr_valid", "fr_k", "fr_m",
-                          "br_valid", "br_k", "br_m")}
-    for t, clock in enumerate(clocks):
-        for s, (kind, c, m) in clock.items():
-            k = c // n_stages
-            if kind == "F":
-                f["f_valid"][t, s] = 1
-                f["f_k"][t, s], f["f_m"][t, s] = k, m
-                if c < n_chunks - 1:
-                    # chunk c+1 lives on stage (s+1) % n: it receives this
-                    # unit's output over the forward ring hop this clock
-                    rs = (s + 1) % n_stages
-                    f["fr_valid"][t, rs] = 1
-                    f["fr_k"][t, rs] = (c + 1) // n_stages
-                    f["fr_m"][t, rs] = m
-            else:
-                f["b_valid"][t, s] = 1
-                f["b_k"][t, s], f["b_m"][t, s] = k, m
-                if c > 0:
-                    # chunk c-1's stage receives this unit's input
-                    # cotangent over the reverse ring hop this clock
-                    rs = (s - 1) % n_stages
-                    f["br_valid"][t, rs] = 1
-                    f["br_k"][t, rs] = (c - 1) // n_stages
-                    f["br_m"][t, rs] = m
-    return f
-
-
-def stash_plan(clocks: list[dict], n_stages: int, n_micro: int,
-               interleave: int = 1) -> tuple[int, int]:
-    """Activation/cotangent stash depths for the 1F1B step, computed FROM
-    the timetable and statically verified collision-free.
-
-    The step keeps two rolling buffers per local chunk slot, indexed by
-    ``microbatch % depth``: ``x_stash`` (chunk inputs received over the
-    forward hop, read at the chunk's F clock and again at its B clock for
-    the recompute-vjp) and ``cot_stash`` (output cotangents received over
-    the reverse hop, read at the B clock).  A slot written at the end of
-    clock ``t_w`` is live through its final read at clock ``t_r``; the
-    plan asserts no later write lands on the slot before ``t_r`` — the
-    bounded-stash property that gives 1F1B its O(pp * microbatch)
-    activation memory (vs the flat wave scan's O(num_ticks)).
-
-    Returns ``(x_depth, cot_depth)``.
-    """
-    n_chunks = n_stages * interleave
-    done_f: dict = {}
-    done_b: dict = {}
-    for t, clock in enumerate(clocks):
-        for s, (kind, c, m) in clock.items():
-            (done_f if kind == "F" else done_b)[(c, m)] = t
-
-    def min_depth(spans_by_chunk: dict) -> int:
-        depth = 1
-        for spans in spans_by_chunk.values():
-            while True:
-                by_slot: dict = {}
-                for m, (t_w, t_r) in spans.items():
-                    by_slot.setdefault(m % depth, []).append((t_w, t_r))
-                ok = True
-                for entries in by_slot.values():
-                    entries.sort()
-                    for (w1, r1), (w2, _) in zip(entries, entries[1:]):
-                        if w2 < r1:  # overwritten while still live
-                            ok = False
-                if ok:
-                    break
-                depth += 1
-        return depth
-
-    x_spans: dict = {c: {} for c in range(1, n_chunks)}
-    cot_spans: dict = {c: {} for c in range(n_chunks - 1)}
-    for m in range(n_micro):
-        for c in range(1, n_chunks):
-            # written when the upstream F runs, last read at this B
-            x_spans[c][m] = (done_f[(c - 1, m)], done_b[(c, m)])
-        for c in range(n_chunks - 1):
-            # written when the downstream B runs, read at this B
-            cot_spans[c][m] = (done_b[(c + 1, m)], done_b[(c, m)])
-    return (max(1, min_depth(x_spans)), max(1, min_depth(cot_spans)))
-
-
 def pipeline_loss(
     stage_params: PyTree,
     shared: PyTree,
@@ -533,14 +306,14 @@ def pipeline_loss(
     n0 = _varying(jnp.zeros((), jnp.int32))
     aux0 = _varying(jnp.zeros(()))
 
-    # -- 1F1B-grade activation memory: block-remat over the tick scan ------
+    # -- O(pp * mb) activation memory: block-remat over the tick scan ------
     # A flat scan of T ticks saves one (mb, S, D) carry per tick for the
     # backward: O(T) = O(M*v) live activations — the O(num_ticks) wall.
     # Nesting the scan (outer over blocks of ``remat_block_ticks`` ticks,
     # inner scan checkpointed) makes the backward keep only the T/block
     # block-boundary carries and rematerialize one block at a time, so peak
     # live activations are O(M*v/n + n) microbatch-sized buffers — for the
-    # standard M = O(n) microbatch regime, O(pp * mb), 1F1B's bound.  The
+    # standard M = O(n) microbatch regime, O(pp * mb) in all.  The
     # price is one extra tick-forward per backward (the usual remat trade;
     # the per-chunk jax.checkpoint above keeps the within-block recompute
     # itself lean).  remat_block_ticks: 0 = auto (one wave, n ticks);

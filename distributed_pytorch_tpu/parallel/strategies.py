@@ -39,8 +39,8 @@ from typing import Any, Callable, Protocol
 import jax
 import jax.numpy as jnp
 from jax import lax
-# provable varying->invariant gather (jax 0.9.0 does not re-export it)
-from jax._src.lax.parallel import all_gather_invariant as _all_gather_inv
+
+from ..utils.compat import all_gather_invariant as _all_gather_inv
 
 PyTree = Any
 
@@ -1290,8 +1290,7 @@ def require_overlap_capable(strategy) -> None:
             f"purpose)")
 
 
-def require_lm_overlap_streamable(*, fsdp: bool, dcn: bool,
-                                  pp: bool = False) -> None:
+def require_lm_overlap_streamable(*, fsdp: bool, dcn: bool) -> None:
     """The LM trainer's overlap capability check
     (``LMTrainConfig(overlap=True)``): raise unless the config has a
     post-backward cluster the layer-group boundary hook can stream —
@@ -1299,22 +1298,17 @@ def require_lm_overlap_streamable(*, fsdp: bool, dcn: bool,
     DCN sync points (``dcn`` — dcn_size > 1 AND the sync actually runs
     in-backward: under grad_accum > 1 the one post-accumulation exchange
     sits outside the backward, so the caller passes dcn=False there;
-    streamed per layer group since round 9) and/or the interleaved-1F1B
-    pipeline (``pp`` — pp_size > 0, round 10: the 1F1B step's per-chunk
-    gradient syncs stream right after each chunk's LAST backward unit,
-    between the other chunks' remaining backward matmuls, and its ZeRO-3
-    gathers move to each chunk's own F/B clocks).  With none of the
-    three, the data-axis cotangent psums are already emitted at each
-    param's use site by shard_map's transpose — there is nothing to
-    stream."""
-    if fsdp or dcn or pp:
+    streamed per layer group since round 9).  With neither, the
+    data-axis cotangent psums are already emitted at each param's use
+    site by shard_map's transpose — there is nothing to stream."""
+    if fsdp or dcn:
         return
     raise ValueError(
         "lm overlap=True streams the ZeRO-3 (fsdp) weight gathers and/or "
         "the factored-mesh (dcn_size > 1) two-level sync points through "
         "the layer boundaries; without either there is no post-backward "
         "cluster to dissolve (BASELINE.md rounds 8-9).  Enable fsdp, set "
-        "dcn_size > 1, set pp_size > 0, or drop overlap (the VGG "
+        "dcn_size > 1, or drop overlap (the VGG "
         "trainer's overlap=True covers the explicit-strategy case)")
 
 
@@ -1333,8 +1327,8 @@ def require_lm_route(plan, *, dcn: bool, pp: bool,
     an unfactored mesh, and ``data:rs → dcn:psum → data:ag`` /
     ``data:rs → dcn:ring[int8|int4+ef] → data:ag`` on a factored one —
     anything else must refuse loudly rather than silently run a
-    different program than the route names.  pp/pp_size gradient paths
-    are hand-emitted (the long-standing dcn_compress refusal), and the
+    different program than the route names.  The pipeline's gradient
+    path is its own (the long-standing dcn_compress refusal), and the
     route carries its own wire format, so combining with an explicit
     ``dcn_compress`` or with ``sync_plan='auto'`` (search vs pin) is
     ambiguous — set one, not both."""
@@ -1351,9 +1345,9 @@ def require_lm_route(plan, *, dcn: bool, pp: bool,
     if pp:
         raise ValueError(
             "sync_route does not compose with pipeline parallelism "
-            "(pp/pp_size): the pipeline's gradient reductions are "
-            "hand-emitted per stage, not routed through "
-            "_two_level_sync — drop the pipeline or the route")
+            "(pp): the pipeline's gradient reductions are per stage, "
+            "not routed through _two_level_sync — drop the pipeline "
+            "or the route")
     hops = list(plan.hops)
     if not dcn:
         if (len(hops) == 1 and hops[0].kind == "exchange"
@@ -1385,41 +1379,6 @@ def require_lm_route(plan, *, dcn: bool, pp: bool,
             f"{x.describe()!r}")
 
 
-def require_pp_schedulable(*, n_stages: int, n_micro: int, n_layers: int,
-                           interleave: int = 1) -> None:
-    """The interleaved-1F1B composition check (``LMTrainConfig(pp_size >
-    0)``): ONE definition site — the round-9 ``require_*`` consolidation
-    — shared by ``lm.validate_lm_cfg``, ``lm_cli``, and ``bench.py``'s
-    pre-bench knob validation, so the refusal conditions cannot drift
-    from what ``make_lm_1f1b_train_step`` actually schedules.
-
-    Rejects the incoherent combos loudly: a stage count that does not
-    divide the layer stack into ``n_stages * interleave`` homogeneous
-    contiguous chunks (the step builder's layer cut needs equal-length
-    layer scans), and fewer microbatches than stages (the 1F1B steady state
-    needs >= n_stages in-flight microbatches; below that the schedule
-    degenerates to fill/drain only and the bubble bound
-    (pp-1)/(pp-1+M) is a third or worse)."""
-    if n_stages < 1:
-        raise ValueError(f"pp_size must be >= 1 here, got {n_stages}")
-    n_chunks = n_stages * interleave
-    if n_layers % n_chunks:
-        raise ValueError(
-            f"pp_size={n_stages} x interleave={interleave} does not "
-            f"divide the {n_layers}-layer stack into contiguous layer-"
-            f"group chunks ({n_layers} % {n_chunks} != 0); pick a stage "
-            f"count that cuts on layer-group boundaries")
-    if n_micro < n_stages:
-        raise ValueError(
-            f"microbatches={n_micro} < pp_size={n_stages}: the 1F1B "
-            f"steady state keeps pp_size microbatches in flight — with "
-            f"fewer the pipeline never leaves fill/drain and the bubble "
-            f"fraction (pp-1)/(pp-1+M) >= "
-            f"{(n_stages - 1) / (n_stages - 1 + max(n_micro, 1)):.2f}; "
-            f"use microbatches >= pp_size (>= 2*pp_size to reach the "
-            f"<=1/3 bubble regime)")
-
-
 def require_sync_window(*, sync_every: int, staleness: int = 0,
                         max_sync_every: int = 1, mesh: bool = True,
                         overlap: bool = False, pp: bool = False,
@@ -1441,7 +1400,7 @@ def require_sync_window(*, sync_every: int, staleness: int = 0,
     Rejects the incoherent combos loudly: windows need a mesh (the
     meshless single-jit path has no collective to amortize and no
     per-device local state); pipeline stages own their own schedule
-    (the 1F1B step has no per-step data exchange a window could skip);
+    (the pipeline step has no per-step data exchange a window could skip);
     grad_accum already IS a window over the exchange (composing the two
     double-counts the amortization); the VGG in-backward overlap
     machinery streams the very per-step collective a window removes;
@@ -1545,7 +1504,7 @@ def require_sync_window(*, sync_every: int, staleness: int = 0,
     if pp:
         raise ValueError(
             f"sync_every={sync_every} is incompatible with pipeline "
-            f"parallelism (pp_size > 0): the 1F1B schedule has no "
+            f"parallelism (pp > 1): the pipeline schedule has no "
             f"per-step data exchange a window could skip")
     if grad_accum > 1:
         raise ValueError(

@@ -6,6 +6,8 @@ buffer-donation helper, and the differentiable fusion barrier.
 from __future__ import annotations
 
 import jax
+# provable varying->invariant gather (jax 0.9.0 does not re-export it)
+from jax._src.lax.parallel import all_gather_invariant  # noqa: F401
 
 shard_map = jax.shard_map
 pcast = jax.lax.pcast
@@ -30,6 +32,22 @@ def donate(*argnums: int) -> tuple:
 def vma_of(x) -> frozenset:
     """The array's varying mesh axes."""
     return jax.typeof(x).vma
+
+
+def varying(x, axes):
+    """``x`` varying over the mesh axes ``axes`` as well (a loop's carry has
+    to start typed as its body leaves it)."""
+    missing = frozenset(axes) - vma_of(x)
+    return pcast(x, tuple(missing), to="varying") if missing else x
+
+
+def as_cotangent(g, primal):
+    """``g`` typed as the cotangent of ``primal``: summed over the mesh
+    axes it varies over and ``primal`` does not (what autodiff does for an
+    input that is the same on every shard), varying over the rest."""
+    extra = vma_of(g) - vma_of(primal)
+    return varying(jax.lax.psum(g, tuple(extra)) if extra else g,
+                   vma_of(primal))
 
 
 def shape_struct(shape, dtype, vma=None):

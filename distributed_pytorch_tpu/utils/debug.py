@@ -425,133 +425,6 @@ def assert_plan_bytes_match(plan, sched: list[dict], *, rtol: float = 0.5,
     return rows
 
 
-def pipeline_schedule_stats(clocks: list[dict], *, n_stages: int) -> dict:
-    """Summary statistics of a 1F1B timetable (the ``pp_clocks`` data a
-    ``make_lm_1f1b_train_step`` step carries): measured ``bubble_fraction``
-    (idle (stage, clock) slots / all slots — the thing the analytic
-    (pp-1)/(pp-1+M) bound bounds), total ``clocks``, per-kind unit counts,
-    and ``steady_alternations`` — the number of F->B / B->F kind switches
-    summed over stages, the 1F1B steady state's signature (a GPipe-shaped
-    all-F-then-all-B schedule has n_stages-ish switches; 1F1B has ~2 per
-    in-flight microbatch per stage)."""
-    busy = sum(len(c) for c in clocks)
-    slots = n_stages * len(clocks)
-    f_units = sum(1 for c in clocks for op in c.values() if op[0] == "F")
-    b_units = sum(1 for c in clocks for op in c.values() if op[0] == "B")
-    alternations = 0
-    for s in range(n_stages):
-        kinds = [c[s][0] for c in clocks if s in c]
-        alternations += sum(1 for a, b in zip(kinds, kinds[1:]) if a != b)
-    return {"bubble_fraction": 1.0 - busy / slots if slots else 0.0,
-            "clocks": len(clocks), "f_units": f_units, "b_units": b_units,
-            "steady_alternations": alternations}
-
-
-def assert_pipeline_schedule(clocks_or_step, *, n_stages: int,
-                             n_micro: int, interleave: int = 1,
-                             max_bubble: float | None = None) -> dict:
-    """Assert a 1F1B timetable is well-formed and meets its bubble bound —
-    the pipeline-parallel sibling of ``assert_overlap_schedule`` (round
-    10): the 1F1B step EMITS its program in timetable order, so checking
-    the timetable checks the emitted schedule the same way the jaxpr
-    inspector checks emitted collective placement.
-
-    Accepts the timetable (list of ``{stage: (kind, chunk, micro)}``
-    clocks) or a step function carrying it (``step.pp_clocks``).  Checks:
-
-    - completeness: every (chunk, microbatch) runs F and B exactly once;
-    - dependencies: chunk c's F after chunk c-1's F (same microbatch),
-      chunk c's B after its own F and after chunk c+1's B — the dataflow
-      the stage-boundary transfers implement;
-    - grad-accumulation order: per chunk, backwards run in ascending
-      microbatch order — the property that makes 1F1B's reordering a
-      pure reassociation of the accumulated sum (lm.py's bitwise pin);
-    - steady-state interleaving: with n_micro > n_stages there is at
-      least one clock where EVERY stage is busy and both F and B units
-      run somewhere (stage-f/stage-b work genuinely interleaved, not a
-      GPipe all-F-then-all-B shape);
-    - bubble: measured bubble fraction <= ``max_bubble`` (default: the
-      analytic 1F1B fill/drain bound (pp-1)/(pp-1+M) with M =
-      ``n_micro`` — which the generated timetable meets EXACTLY at
-      interleave=1 and beats at interleave>1; the idealized v-fold
-      bound (pp-1)/(pp-1+M*v) rides along as ``ideal_bound`` but is not
-      enforced — the greedy schedule lands between the two).
-
-    Returns ``pipeline_schedule_stats`` + ``analytic_bound`` /
-    ``ideal_bound`` for the bench tables (bench.py
-    ``lm_pp_bubble_fraction``)."""
-    clocks = getattr(clocks_or_step, "pp_clocks", clocks_or_step)
-    n_chunks = n_stages * interleave
-    done_f: dict = {}
-    done_b: dict = {}
-    for t, clock in enumerate(clocks):
-        for s, (kind, c, m) in clock.items():
-            if c % n_stages != s:
-                raise ConsistencyError(
-                    f"clock {t}: chunk {c} ran on stage {s}, but the "
-                    f"round-robin placement puts it on {c % n_stages}")
-            key = (c, m)
-            book = done_f if kind == "F" else done_b
-            if key in book:
-                raise ConsistencyError(
-                    f"clock {t}: duplicate {kind} unit for chunk {c} "
-                    f"microbatch {m} (first at clock {book[key]})")
-            if kind == "F":
-                if c > 0 and done_f.get((c - 1, m), t) >= t:
-                    raise ConsistencyError(
-                        f"clock {t}: F({c},{m}) before upstream "
-                        f"F({c - 1},{m}) finished")
-            else:
-                if done_f.get((c, m), t) >= t:
-                    raise ConsistencyError(
-                        f"clock {t}: B({c},{m}) before its own F")
-                if c < n_chunks - 1 and done_b.get((c + 1, m), t) >= t:
-                    raise ConsistencyError(
-                        f"clock {t}: B({c},{m}) before downstream "
-                        f"B({c + 1},{m}) — its output cotangent does "
-                        f"not exist yet")
-            book[key] = t
-    want = {(c, m) for c in range(n_chunks) for m in range(n_micro)}
-    for name, book in (("forward", done_f), ("backward", done_b)):
-        if set(book) != want:
-            missing = sorted(want - set(book))[:4]
-            raise ConsistencyError(
-                f"incomplete schedule: {len(want) - len(book)} {name} "
-                f"units missing (first: {missing})")
-    for c in range(n_chunks):
-        ms = sorted(range(n_micro), key=lambda m: done_b[(c, m)])
-        if ms != sorted(ms):
-            raise ConsistencyError(
-                f"chunk {c}: backwards out of microbatch order {ms} — "
-                f"the grad accumulation would reassociate vs pp_size=1")
-    stats = pipeline_schedule_stats(clocks, n_stages=n_stages)
-    if n_micro > n_stages and n_stages > 1:
-        full = [t for t, c in enumerate(clocks)
-                if len(c) == n_stages
-                and {op[0] for op in c.values()} == {"F", "B"}]
-        if not full:
-            raise ConsistencyError(
-                "no steady-state clock runs F and B units on a fully "
-                "busy stage set — the schedule is not interleaved 1F1B "
-                f"(stats: {stats})")
-        stats["steady_clocks"] = len(full)
-    # the ONE definition of the analytic bound (parallel/pipeline.py) —
-    # enforced at interleave=1 terms, reported also in idealized v-fold
-    # terms (lazy import: debug must stay importable standalone)
-    from ..parallel.pipeline import analytic_bubble_bound
-    bound = analytic_bubble_bound(n_stages, n_micro)
-    stats["analytic_bound"] = bound
-    stats["ideal_bound"] = analytic_bubble_bound(n_stages, n_micro,
-                                                 interleave)
-    limit = bound if max_bubble is None else max_bubble
-    if n_stages > 1 and stats["bubble_fraction"] > limit + 1e-9:
-        raise ConsistencyError(
-            f"measured bubble fraction {stats['bubble_fraction']:.4f} "
-            f"exceeds the bound {limit:.4f} "
-            f"((pp-1)/(pp-1+M) = {bound:.4f}; stats: {stats})")
-    return stats
-
-
 def _leaf_paths(tree: PyTree):
     leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
     for path, leaf in leaves:

@@ -16,7 +16,7 @@ utils/metrics.py; this module adds what a real framework provides on top:
 - ``PhaseTimer``: named-phase wall-clock accumulation (serve.py's
   plan / dispatch / fetch / parse attribution) — so a serving ms/token
   number decomposes into where the time actually went instead of being
-  one opaque wall-clock scalar (``scripts/profile_decode.py`` prints it).
+  one opaque wall-clock scalar.
 """
 
 from __future__ import annotations

@@ -42,8 +42,6 @@ RULES: dict[str, tuple[str, float]] = {
     "calib_tflops": ("higher", 0.10),
     "train_overlap_speedup": ("higher", 0.10),
     "train_dcn_overlap_speedup": ("higher", 0.10),
-    "lm_pp_tokens_per_sec": ("higher", 0.15),
-    "lm_pp_speedup": ("higher", 0.10),
     "train_autotune_speedup": ("higher", 0.10),
     "elastic_recovery_ms": ("lower", 0.25),
     "lm_tokens_per_sec_per_chip": ("higher", 0.10),

@@ -534,61 +534,6 @@ def bench_wan_diloco(sync_every: int = 4) -> tuple[float, dict, bool]:
     return sum(times) / len(times), comm, False
 
 
-def bench_lm_pp(pp_size: int = 2,
-                microbatches: int = 4) -> tuple[float, dict, bool]:
-    """The interleaved-1F1B pipeline row (round 10): a small LM on the
-    ('pp', ...) virtual mesh, same window discipline as the strategy
-    rows.  Its wire profile comes from the same schedule inspector (the
-    'pp'-axis bytes are the stage-boundary activation/cotangent
-    traffic), plus the pipeline-only column: the measured steady-state
-    bubble fraction of the EMITTED 1F1B timetable, re-asserted against
-    the analytic (pp-1)/(pp-1+M) bound on every run
-    (utils/debug.assert_pipeline_schedule).  s/step is not comparable to
-    the VGG rows (different model/loss); the bubble and per-axis bytes
-    are the content."""
-    from distributed_pytorch_tpu.lm import LMTrainConfig, LMTrainer
-    from distributed_pytorch_tpu.models import transformer as tfm
-
-    model = tfm.TransformerConfig(vocab_size=256, d_model=128, n_layers=4,
-                                  n_heads=2, head_dim=64, d_ff=256)
-    cfg = LMTrainConfig(model=model, pp_size=pp_size,
-                        microbatches=microbatches, compute_dtype=None)
-    tr = LMTrainer(cfg)
-    rng = np.random.default_rng(0)
-    batch, seq = 2 * microbatches, 128
-    toks = rng.integers(0, 256, (batch, seq)).astype(np.int32)
-    tgts = np.roll(toks, -1, axis=1).astype(np.int32)
-
-    tr.train_step(toks, tgts)  # compile + warm-up (excluded)
-    sched = dbg.op_schedule(tr.step_fn, tr.params, tr.opt_state, toks, tgts)
-    stats = dbg.collective_stats(sched)
-    per_axis = dbg.per_axis_collective_stats(sched)
-    pp_stats = dbg.assert_pipeline_schedule(
-        tr.step_fn, n_stages=tr.step_fn.pp_meta["n_stages"],
-        n_micro=tr.step_fn.pp_meta["n_micro"],
-        interleave=tr.step_fn.pp_meta["interleave"])
-    comm = {"comm_bytes_per_step": stats["bytes_executed"],
-            "collective_count": stats["executions"],
-            "comm_bytes_static": stats["bytes"],
-            "collective_count_static": stats["total"],
-            "collectives_interleaved": stats["interleaved"],
-            "comm_bytes_by_axis": {a: s["bytes_executed"]
-                                   for a, s in per_axis.items()},
-            "collective_count_by_axis": {a: s["executions"]
-                                         for a, s in per_axis.items()},
-            "hlo_collective_count": None, "hlo_collectives": None,
-            "predicted_ms": None,  # no cost-model formula for the pp row
-            "pp_bubble_fraction": pp_stats["bubble_fraction"],
-            "pp_bubble_bound": pp_stats["analytic_bound"]}
-    times = []
-    for _ in range(WINDOW):
-        t0 = time.perf_counter()
-        loss = tr.train_step(toks, tgts)
-        float(loss)  # value fetch: the honest end-of-step barrier
-        times.append(time.perf_counter() - t0)
-    return sum(times) / len(times), comm, False
-
-
 def main() -> None:
     names = ["none", "ddp", "bucketed", "hierarchical", "hierarchical_int8",
              "hierarchical_int4", "routed_int4", "all_reduce",
@@ -627,17 +572,9 @@ def main() -> None:
                       "sec_per_step": round(t, 4), "window": WINDOW,
                       "per_dev_batch": PER_DEV_BATCH, "overlap": False,
                       **comm}), flush=True)
-    # the 1F1B pipeline row (round 10): LM model, so it joins the table
-    # for its bubble/per-axis columns, not the vs-ddp ratio
-    t, comm, _ = bench_lm_pp()
-    names.append("lm_pp2_1f1b")
-    results["lm_pp2_1f1b"], comms["lm_pp2_1f1b"] = t, comm
-    print(json.dumps({"strategy": "lm_pp2_1f1b",
-                      "sec_per_step": round(t, 4), "window": WINDOW,
-                      "per_dev_batch": PER_DEV_BATCH, "overlap": False,
-                      **comm}), flush=True)
     # the quantized ZeRO-3 gather row (round 16): int8 weight
-    # all-gathers on the wire, same LM caveat as the pipeline row
+    # all-gathers on the wire: an LM model, so it joins the table for
+    # its per-axis columns, not the vs-ddp ratio
     t, comm, _ = bench_lm_fsdp_q8gather()
     names.append("lm_fsdp_q8gather")
     results["lm_fsdp_q8gather"], comms["lm_fsdp_q8gather"] = t, comm
@@ -673,18 +610,9 @@ def main() -> None:
         if "dcn" in by_axis:
             return (f"{by_axis['dcn'] / 1e6:.2f}/"
                     f"{by_axis.get('ici', 0) / 1e6:.2f}")
-        if "pp" in by_axis:  # the pipeline row: stage-boundary bytes
-            return f"pp {by_axis['pp'] / 1e6:.2f}"
         if "expert" in by_axis:  # the MoE row: expert all_to_all bytes
             return f"ep {by_axis['expert'] / 1e6:.2f}"
         return "-"
-
-    def bubble(c: dict) -> str:
-        """Measured 1F1B bubble fraction — pipeline rows only."""
-        if "pp_bubble_fraction" not in c:
-            return "-"
-        return (f"{c['pp_bubble_fraction']:.3f}"
-                f" (<= {c['pp_bubble_bound']:.3f})")
 
     def act_mb(c: dict) -> str:
         """Predicted/census activation MB — the memory row only."""
@@ -695,10 +623,10 @@ def main() -> None:
 
     ddp = results["ddp"]
     print("\n| Strategy | s/step | vs ddp | predicted sync ms | "
-          "comm MB/step | dcn/ici MB | bubble | act MB pred/census | "
+          "comm MB/step | dcn/ici MB | act MB pred/census | "
           "collectives (interleaved) | HLO collectives |",
           file=sys.stderr)
-    print("|---|---|---|---|---|---|---|---|---|---|", file=sys.stderr)
+    print("|---|---|---|---|---|---|---|---|---|", file=sys.stderr)
     for name in names:
         c = comms[name]
         hlo = c["hlo_collective_count"]
@@ -707,7 +635,7 @@ def main() -> None:
               f"{results[name] / ddp:.2f}x | "
               f"{f'{pred:.3f}' if pred is not None else '-'} | "
               f"{c['comm_bytes_per_step'] / 1e6:.2f} | "
-              f"{axis_mb(c)} | {bubble(c)} | {act_mb(c)} | "
+              f"{axis_mb(c)} | {act_mb(c)} | "
               f"{c['collective_count']} ({c['collectives_interleaved']}) | "
               f"{hlo if hlo is not None else '-'} |", file=sys.stderr)
     if "auto" in comms and "resolved" in comms["auto"]:

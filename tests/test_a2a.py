@@ -481,7 +481,7 @@ def test_resolve_lm_route_refusals():
                            sync_plan="auto"), "both"),
             (LMTrainConfig(model=m, dcn_size=2, sync_route=factored,
                            dcn_compress="int4"), "dcn_compress"),
-            (LMTrainConfig(model=m, pp_size=2, sync_route="data:psum"),
+            (LMTrainConfig(model=m, pp=2, sync_route="data:psum"),
              "pp"),
             (LMTrainConfig(model=m, sync_route=factored), "flat"),
             (LMTrainConfig(model=m, dcn_size=2, sync_route=(

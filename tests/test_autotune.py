@@ -438,7 +438,7 @@ class TestLMInt8Dcn:
                 model=tfm.TransformerConfig(vocab_size=128, d_model=128,
                                             n_layers=4, n_heads=2,
                                             head_dim=64, d_ff=256),
-                dp=2, dcn_size=2, pp_size=2, dcn_compress="int8"))
+                dp=2, dcn_size=2, pp=2, dcn_compress="int8"))
         with pytest.raises(ValueError, match="sync_plan"):
             validate_lm_cfg(LMTrainConfig(model=_lm_model(),
                                           sync_plan="bogus"))
@@ -518,7 +518,7 @@ def test_lm_auto_respects_pipeline_and_pinned_bucket():
         model=tfm.TransformerConfig(vocab_size=128, d_model=128,
                                     n_layers=4, n_heads=2, head_dim=64,
                                     d_ff=256),
-        dp=2, dcn_size=2, pp_size=2, microbatches=4,
+        dp=2, pp=2, microbatches=4,
         sync_plan="auto", autotune_profile="fast_ici_slow_dcn")
     resolved, plan = at2.resolve_lm_auto(cfg)
     assert resolved.dcn_compress is None  # int8 excluded, not refused

@@ -220,33 +220,6 @@ def test_bench_decode_uses_hardened_window():
     assert "np.asarray(out)" not in src
 
 
-def test_bench_pp_env_knobs_fail_loudly():
-    """Typo'd BENCH_PP_SIZE / BENCH_MICROBATCHES must raise before any
-    measurement (the BENCH_DCN_* contract); unset/0 skip cleanly, and
-    the knob PAIR is checked through the trainer's own
-    require_pp_schedulable so an unschedulable combo dies pre-bench."""
-    assert bench.canon_pp_size_env(None) == 0
-    assert bench.canon_pp_size_env("") == 0
-    assert bench.canon_pp_size_env("0") == 0
-    assert bench.canon_pp_size_env("2") == 2
-    for bad in ("1", "-2", "two", "2.5"):
-        with pytest.raises(ValueError, match="BENCH_PP_SIZE"):
-            bench.canon_pp_size_env(bad)
-    # default M = 2*pp (the <=1/3-bubble regime)
-    assert bench.canon_microbatches_env(None, 2) == 4
-    assert bench.canon_microbatches_env("8", 2) == 8
-    with pytest.raises(ValueError, match="BENCH_MICROBATCHES"):
-        bench.canon_microbatches_env("four", 2)
-    # schedulability of the PAIR, via the one shared check
-    with pytest.raises(ValueError, match="microbatches"):
-        bench.canon_microbatches_env("1", 2)
-    with pytest.raises(ValueError, match="divide"):
-        bench.canon_pp_size_env("3") and bench.canon_microbatches_env(
-            "6", 3)
-    # pp_size unset: microbatches is accepted unchecked (no pipeline)
-    assert bench.canon_microbatches_env("3", 0) == 3
-
-
 def test_bench_autotune_env_knob_fails_loudly():
     """A typo'd BENCH_AUTOTUNE must raise before any measurement (the
     BENCH_KV_DTYPE contract); unset/''/'0' skip cleanly, '1' runs."""
@@ -416,26 +389,6 @@ def test_bench_json_keys_include_fleet_transport_gate():
     assert "rpc_overhead_ms" in tsrc
 
 
-def test_bench_json_keys_include_pp_gate():
-    """Round-10 schema: the interleaved-1F1B A/B keys ride the JSON, the
-    knobs are canonicalized pre-bench, and the A/B reads its bubble from
-    the schedule inspector (assert_pipeline_schedule re-checks the
-    analytic bound on every bench run) with the hardened-window
-    discipline."""
-    import inspect
-    src = inspect.getsource(bench.main)
-    for key in ("lm_pp_tokens_per_sec", "lm_pp_bubble_fraction",
-                "lm_pp_speedup"):
-        assert key in src, key
-    assert "canon_pp_size_env" in src and "BENCH_PP_SIZE" in src
-    assert "canon_microbatches_env" in src and "BENCH_MICROBATCHES" in src
-    sig = inspect.signature(bench.bench_train_pp)
-    assert sig.parameters["reps"].default >= 5
-    ppsrc = inspect.getsource(bench.bench_train_pp)
-    assert "assert_pipeline_schedule" in ppsrc
-    assert "bubble_fraction" in ppsrc
-
-
 def test_bench_meta_block_schema():
     """Round-15 schema: every bench JSON carries a provenance meta block
     (git sha, jax/jaxlib versions, platform, device kind, hostname, UTC
@@ -544,8 +497,8 @@ def test_bench_compare_rule_table_covers_baseline_keys():
     bc = _compare_mod()
     for key in ("value", "mfu", "lm_tokens_per_sec_per_chip", "lm_mfu",
                 "serving_tokens_per_sec", "train_overlap_speedup",
-                "train_dcn_overlap_speedup", "lm_pp_speedup",
-                "train_autotune_speedup", "serving_overlap_speedup",
+                "train_dcn_overlap_speedup", "train_autotune_speedup",
+                "serving_overlap_speedup",
                 "fleet_tokens_per_sec", "fleet_prefix_hit_rate"):
         assert bc.RULES[key][0] == "higher", key
     for key in ("decode_ms_per_token", "decode_ms_per_token_p95",

@@ -122,7 +122,7 @@ def test_parser_localsgd_flags():
         lm_cli.main(["--staleness", "1"])
     with pytest.raises(SystemExit):  # pipeline owns its own schedule
         lm_cli.main(["--dp", "2", "--dcn-size", "2", "--sync-every", "4",
-                     "--pp-size", "2", "--microbatches", "4"])
+                     "--pp", "2", "--microbatches", "4"])
     with pytest.raises(SystemExit):  # VGG: overlap streams the sync
         cli.main(["--strategy", "hierarchical", "--dcn-size", "2",
                   "--sync-every", "2", "--overlap"])
@@ -209,7 +209,7 @@ def test_parser_memory_flags():
     with pytest.raises(SystemExit):
         lm_cli.main(["--loss-chunk", "64"])  # needs --loss-impl chunked
     with pytest.raises(SystemExit):
-        lm_cli.main(["--remat", "full", "--pp-size", "2"])
+        lm_cli.main(["--remat", "full", "--pp", "2"])
 
 
 def test_init_single_host_is_noop():
@@ -289,35 +289,39 @@ def test_sharded_eval_matches_replicated():
     np.testing.assert_allclose(loss_sh, loss_rep, rtol=1e-4)
 
 
-def test_parser_pp_size_flags():
-    """Round-10 surface: the interleaved-1F1B knobs reach LMTrainConfig
-    (defaults 0/0 so historical invocations are byte-identical), and the
-    incoherent combos refuse through the SAME require_pp_schedulable
-    check the trainer uses."""
+def test_parser_pipeline_flags():
+    """The pipeline knobs (--pp, --microbatches, --interleave) reach
+    LMTrainConfig with defaults that leave historical invocations
+    byte-identical, and what the wave schedule cannot run refuses at
+    config time: in the ONE validate_lm_cfg, or where the trainer cuts
+    the layer stack into stages, before any step is built."""
+    import dataclasses
+
     from distributed_pytorch_tpu import lm_cli
-    from distributed_pytorch_tpu.lm import LMTrainConfig, validate_lm_cfg
-    from distributed_pytorch_tpu.models import transformer as tfm
+    from distributed_pytorch_tpu.lm import (LMTrainConfig, LMTrainer,
+                                            validate_lm_cfg)
 
     lm_args = lm_cli.build_parser().parse_args([])
-    assert lm_args.pp_size == 0 and lm_args.microbatches == 0
+    assert (lm_args.pp, lm_args.microbatches, lm_args.interleave) == (1, 0, 1)
     lm_args = lm_cli.build_parser().parse_args(
-        ["--pp-size", "2", "--microbatches", "4", "--fsdp", "--dp", "2",
-         "--overlap"])
-    assert lm_args.pp_size == 2 and lm_args.microbatches == 4
+        ["--pp", "2", "--microbatches", "4", "--interleave", "2",
+         "--n-layers", "4"])
+    cfg = LMTrainConfig(model=lm_cli.model_config(lm_args), pp=lm_args.pp,
+                        microbatches=lm_args.microbatches,
+                        interleave=lm_args.interleave, compute_dtype=None)
+    assert (cfg.pp, cfg.microbatches, cfg.interleave) == (2, 4, 2)
+    validate_lm_cfg(cfg)
 
-    # the CLI's values flow into the ONE validation path: a pp_size that
-    # does not divide the layer groups, or microbatches < pp_size, is a
-    # loud config-time refusal (never a silently dropped flag)
-    model = tfm.TransformerConfig(vocab_size=64, d_model=32, n_layers=4,
-                                  n_heads=2, head_dim=16, d_ff=64)
-    with pytest.raises(ValueError, match="divide"):
-        validate_lm_cfg(LMTrainConfig(model=model, pp_size=3))
-    with pytest.raises(ValueError, match="microbatches"):
-        validate_lm_cfg(LMTrainConfig(model=model, pp_size=4,
-                                      microbatches=2))
-    with pytest.raises(ValueError, match="one, not both"):
-        validate_lm_cfg(LMTrainConfig(model=model, pp_size=2, pp=2))
-    validate_lm_cfg(LMTrainConfig(model=model, pp_size=2, microbatches=4))
+    for match, kw in (("interleave", dict(interleave=0)),
+                      ("requires pp > 1", dict(pp=1)),
+                      ("grad_accum", dict(grad_accum=2)),
+                      ("remat", dict(remat="full")),
+                      ("expert", dict(ep=2))):
+        with pytest.raises(ValueError, match=match):
+            validate_lm_cfg(dataclasses.replace(cfg, **kw))
+    # 4 layers do not cut into 2 stages x 3 virtual stages
+    with pytest.raises(ValueError, match="do not split"):
+        LMTrainer(dataclasses.replace(cfg, interleave=3))
 
 
 def test_parser_autotune_flags():
@@ -355,7 +359,7 @@ def test_parser_elastic_flags():
     """Round-12 surface: --elastic/--min-nodes/--max-nodes reach both
     CLIs (defaults off so historical invocations are byte-identical),
     and configs that CANNOT resize refuse loudly at parse/validate time
-    — pipeline axes (pp/pp_size > 1), a missing checkpoint dir (the
+    — a pipeline axis (pp > 1), a missing checkpoint dir (the
     drain sync point must flush one), bounds without --elastic, and the
     meshless VGG strategy."""
     from distributed_pytorch_tpu import lm_cli
@@ -377,9 +381,6 @@ def test_parser_elastic_flags():
 
     # refusals (argparse SystemExit, before any jax/rendezvous work)
     with pytest.raises(SystemExit):  # pipeline cannot resize (for now)
-        lm_cli.main(["--elastic", "--checkpoint-dir", "/tmp/x",
-                     "--pp-size", "2", "--microbatches", "4"])
-    with pytest.raises(SystemExit):  # wave-pp either
         lm_cli.main(["--elastic", "--checkpoint-dir", "/tmp/x",
                      "--pp", "2"])
     with pytest.raises(SystemExit):  # no checkpoint dir to drain into

@@ -163,3 +163,29 @@ class TestCifar10Load:
         same = np.corrcoef(a, b)[0, 1]
         diff = np.corrcoef(a, c)[0, 1]
         assert same > 0.5 > diff
+
+
+class TestPrefetch:
+    def test_keeps_order(self):
+        from distributed_pytorch_tpu.data.pipeline import prefetch
+        assert list(prefetch(iter(range(50)), depth=2)) == list(range(50))
+
+    def test_producer_exception_reraises_in_consumer(self):
+        from distributed_pytorch_tpu.data.pipeline import prefetch
+
+        def gen():
+            yield 0
+            raise KeyError("boom")
+
+        it = prefetch(gen())
+        assert next(it) == 0
+        with pytest.raises(KeyError, match="boom"):
+            next(it)
+
+    def test_passes_a_pair_of_arrays_through(self):
+        # (images, labels) chunks are 2-tuples of arrays: the error
+        # sentinel is told by identity, never by comparing to an array
+        from distributed_pytorch_tpu.data.pipeline import prefetch
+        pairs = [(np.full((2, 3), i), np.arange(i + 2)) for i in range(3)]
+        for got, want in zip(prefetch(iter(pairs)), pairs, strict=True):
+            assert got[0] is want[0] and got[1] is want[1]

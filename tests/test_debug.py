@@ -270,108 +270,3 @@ def test_op_schedule_units():
     assert counts["all-reduce"] == 1
     assert counts["collective-permute"] == 1
     assert counts["total"] == 2
-
-
-# --- 1F1B pipeline-schedule inspector (round 10) ----------------------------
-
-
-def test_assert_pipeline_schedule_conforming():
-    """The generated 1F1B timetable passes every well-formedness check
-    and its measured bubble EQUALS the analytic fill/drain bound
-    (pp-1)/(pp-1+M) at interleave=1 — the textbook schedule is tight,
-    not merely under the bound."""
-    from distributed_pytorch_tpu.parallel import pipeline as pp
-
-    clocks = pp.one_f_one_b_schedule(6, 3)
-    stats = dbg.assert_pipeline_schedule(clocks, n_stages=3, n_micro=6)
-    assert stats["f_units"] == stats["b_units"] == 18
-    np.testing.assert_allclose(stats["bubble_fraction"], 2 / 8)
-    np.testing.assert_allclose(stats["analytic_bound"], 2 / 8)
-    # steady state: clocks exist where every stage is busy and F/B mix
-    assert stats["steady_clocks"] >= 1
-    # library helpers agree with the inspector
-    np.testing.assert_allclose(pp.bubble_fraction(clocks, 3),
-                               stats["bubble_fraction"])
-
-
-def test_assert_pipeline_schedule_interleaved():
-    """interleave=2 (virtual stages): the same checks hold with chunks
-    round-robined over stages, and the measured bubble beats the
-    interleave=1 bound (the v-fold fill/drain shrink)."""
-    from distributed_pytorch_tpu.parallel import pipeline as pp
-
-    clocks = pp.one_f_one_b_schedule(4, 2, 2)
-    stats = dbg.assert_pipeline_schedule(clocks, n_stages=2, n_micro=4,
-                                         interleave=2)
-    # strictly beats the interleave=1 bound (the virtual-stage win) but
-    # sits above the idealized v-fold bound — both reported
-    assert stats["bubble_fraction"] < stats["analytic_bound"] == 0.2
-    assert stats["ideal_bound"] < stats["analytic_bound"]
-    assert stats["bubble_fraction"] >= stats["ideal_bound"]
-
-
-def test_assert_pipeline_schedule_bubbled():
-    """A deliberately bubbled schedule — the conforming timetable with
-    idle clocks spliced in (dependencies intact, stages stalled) — must
-    FAIL the bubble bound; and reordered/incomplete timetables must fail
-    well-formedness."""
-    from distributed_pytorch_tpu.parallel import pipeline as pp
-
-    good = pp.one_f_one_b_schedule(6, 3)
-    bubbled = good[:4] + [{}, {}, {}] + good[4:]
-    with pytest.raises(dbg.ConsistencyError, match="bubble"):
-        dbg.assert_pipeline_schedule(bubbled, n_stages=3, n_micro=6)
-    # ... unless the caller raises the acceptable bubble explicitly
-    stats = dbg.assert_pipeline_schedule(bubbled, n_stages=3, n_micro=6,
-                                         max_bubble=0.5)
-    assert stats["bubble_fraction"] > stats["analytic_bound"]
-
-    # per-chunk backwards out of ascending-microbatch order: the grad
-    # accumulation would reassociate vs pp_size=1 — rejected
-    swapped = [{0: ("F", 0, 0)}, {0: ("F", 0, 1)},
-               {0: ("B", 0, 1)}, {0: ("B", 0, 0)}]
-    with pytest.raises(dbg.ConsistencyError, match="order"):
-        dbg.assert_pipeline_schedule(swapped, n_stages=1, n_micro=2)
-
-    # a backward before its own forward
-    early_b = [{0: ("B", 0, 0)}, {0: ("F", 0, 0)}]
-    with pytest.raises(dbg.ConsistencyError, match="before its own F"):
-        dbg.assert_pipeline_schedule(early_b, n_stages=1, n_micro=1)
-
-    # missing units
-    with pytest.raises(dbg.ConsistencyError, match="incomplete"):
-        dbg.assert_pipeline_schedule(good[:-1], n_stages=3, n_micro=6)
-
-    # wrong stage for a chunk (round-robin placement violated)
-    misplaced = [{1: ("F", 0, 0)}]
-    with pytest.raises(dbg.ConsistencyError, match="stage"):
-        dbg.assert_pipeline_schedule(misplaced, n_stages=2, n_micro=1)
-
-
-def test_pipeline_stash_plan_is_bounded():
-    """The 1F1B activation bound: stash depths computed from the
-    timetable stay O(pp), NOT O(clocks) — the memory property that
-    motivates 1F1B over the flat wave scan."""
-    from distributed_pytorch_tpu.parallel import pipeline as pp
-
-    for n, m, v in ((2, 4, 1), (4, 8, 1), (2, 8, 2)):
-        clocks = pp.one_f_one_b_schedule(m, n, v)
-        x_d, c_d = pp.stash_plan(clocks, n, m, v)
-        # x: up to ~pp in-flight microbatch inputs per chunk slot (+fill
-        # slack); cot: consumed the clock after arrival.  The contrast
-        # is with the wave scan's O(num_ticks) stacked carry.
-        assert 1 <= x_d <= 2 * n, (n, m, v, x_d)
-        assert 1 <= c_d <= 2, (n, m, v, c_d)
-        assert x_d < len(clocks), (n, m, v, x_d, len(clocks))
-
-
-def test_assert_pipeline_schedule_accepts_step_fn():
-    """The inspector pulls ``pp_clocks`` off a step function — the
-    emitted-order-is-the-timetable contract the 1F1B builder exposes."""
-    from distributed_pytorch_tpu.parallel import pipeline as pp
-
-    class FakeStep:
-        pp_clocks = pp.one_f_one_b_schedule(4, 2)
-
-    stats = dbg.assert_pipeline_schedule(FakeStep, n_stages=2, n_micro=4)
-    np.testing.assert_allclose(stats["analytic_bound"], 1 / 5)

@@ -596,7 +596,7 @@ def test_lm_rebuild_refuses_pipeline_and_multiprocess_scope():
     from distributed_pytorch_tpu.lm import LMTrainer
     tr = LMTrainer(_tiny_lm_cfg(dp=2, fsdp=True))
     with pytest.raises(ValueError, match="pipeline"):
-        tr.rebuild(pp_size=2, microbatches=4, fsdp=False, dp=1)
+        tr.rebuild(pp=2, microbatches=4, fsdp=False, dp=1)
 
 
 def test_vgg_rebuild_resumes_bitwise(tmp_path):

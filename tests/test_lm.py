@@ -505,7 +505,7 @@ def test_interleave_split_merge_roundtrip():
 
 
 def test_pp_block_remat_bounds_activation_memory():
-    """1F1B-grade memory (round 2): the block-rematted tick scan (default)
+    """O(pp * mb) memory (round 2): the block-rematted tick scan (default)
     must compile to substantially less temp memory than the flat O(num_ticks)
     scan at a microbatch-heavy config, with an identical loss."""
     from distributed_pytorch_tpu.models import transformer as tfm
@@ -1022,162 +1022,4 @@ def test_grad_accum_exact_trajectory():
     with pytest.raises(ValueError, match="does not implement gradient"):
         LMTrainer(LMTrainConfig(model=model, compute_dtype=None,
                                 grad_accum=2)).train_steps(
-            toks[None], tgts[None])
-
-
-# --- interleaved-1F1B pipeline parallelism (round 10) -----------------------
-#
-# The 1F1B step's backward is HAND-EMITTED (one jax.vjp per (chunk,
-# microbatch) unit in timetable order, every reduction explicit), so the
-# schedule reordering is a pure reassociation of the same per-microbatch
-# grads: pp_size=N must train BITWISE-identically to pp_size=1 — params
-# AND Adam state, over a multi-step run, fsdp on and off, grad_accum > 1
-# composed.  (Bitwise regime: chunks of >= 2 layers — see the opt_barrier
-# note in parallel/pipeline.py _chunk; a 4-layer model at pp_size=2 is
-# squarely inside it.)
-
-
-_F1B_MODEL_KW = dict(vocab_size=256, d_model=64, n_layers=4, n_heads=2,
-                     head_dim=32, d_ff=128)
-_F1B_RUN_CACHE: dict = {}
-
-
-def _f1b_run(pp_size, steps=3, **kw):
-    """One (pp_size, **kw) trajectory: 3 train steps on the shared tiny
-    4-layer model, snapshotted params+opt.  Cached per config so the
-    pp_size=1 baselines build once per suite process (wall-time policy)."""
-    from distributed_pytorch_tpu.models import transformer as tfm
-
-    key = (pp_size, steps, tuple(sorted(kw.items())))
-    if key not in _F1B_RUN_CACHE:
-        model = tfm.TransformerConfig(**_F1B_MODEL_KW)
-        tokens, targets = _data(b=8, s=64, vocab=256)
-        tr = LMTrainer(LMTrainConfig(model=model, pp_size=pp_size,
-                                     microbatches=4, compute_dtype=None,
-                                     **kw))
-        losses = [float(tr.train_step(tokens, targets))
-                  for _ in range(steps)]
-        snap = jax.tree.map(lambda x: np.array(x, copy=True),
-                            (tr.params, tr.opt_state))
-        compiles = (tr.step_fn._cache_size()
-                    if hasattr(tr.step_fn, "_cache_size") else None)
-        _F1B_RUN_CACHE[key] = (losses, snap, compiles)
-    return _F1B_RUN_CACHE[key]
-
-
-def _assert_f1b_bitwise(a, b):
-    la, (pa, oa), _ = a
-    lb, (pb, ob), _ = b
-    assert la == lb, (la, lb)
-    for x, y in zip(jax.tree.leaves(pa), jax.tree.leaves(pb)):
-        np.testing.assert_array_equal(x, y)
-    for x, y in zip(jax.tree.leaves(oa), jax.tree.leaves(ob)):
-        np.testing.assert_array_equal(x, y)
-
-
-@pytest.mark.parametrize("fsdp", [False, True])
-def test_1f1b_matches_single_stage_bitwise(fsdp):
-    """pp_size=2 (2-layer chunks over a 2-stage 'pp' axis, M=4 in-flight
-    microbatches) == pp_size=1 (same microbatched accumulation, one
-    stage) BITWISE over a 3-step run — losses, params, Adam state —
-    with ZeRO-3 fsdp-within-stage on and off."""
-    kw = dict(dp=2, fsdp=True) if fsdp else {}
-    _assert_f1b_bitwise(_f1b_run(1, **kw), _f1b_run(2, **kw))
-
-
-def test_1f1b_grad_accum_composes_bitwise():
-    """grad_accum > 1 under pp_size: the schedule runs M = microbatches x
-    grad_accum units per optimizer step (one update), and the 1F1B
-    reordering still reassociates nothing."""
-    _assert_f1b_bitwise(_f1b_run(1, grad_accum=2), _f1b_run(2, grad_accum=2))
-
-
-def test_1f1b_compile_count_parity():
-    """The pp_size=2 step reaches steady state with the SAME compile
-    count as the single-stage step (one program each; the timetable is
-    trace-time data, never a retrace source)."""
-    c1 = _f1b_run(1)[2]
-    c2 = _f1b_run(2)[2]
-    if c1 is None or c2 is None:
-        pytest.skip("no _cache_size on this runtime")
-    assert c1 == c2, (c1, c2)
-
-
-def test_1f1b_overlap_streams_and_is_bitwise():
-    """overlap=True unrolls the clock loop and streams each chunk's
-    ZeRO-3 gathers at its own F/B clocks and its gradient sync right
-    after its LAST backward unit.  Pins: (a) trajectory BITWISE equal to
-    the scanned post-backward path (and hence, transitively, to
-    pp_size=1); (b) the compiled program interleaves >= 2 non-scalar
-    'pp' stage-boundary transfers strictly between backward matmuls
-    (the ISSUE-6 acceptance shape, via the round-8 inspector)."""
-    from distributed_pytorch_tpu.utils import debug as dbg
-
-    kw = dict(dp=2, fsdp=True)
-    base = _f1b_run(2, **kw)
-    over = _f1b_run(2, overlap=True, **kw)
-    _assert_f1b_bitwise(base, over)
-
-    from distributed_pytorch_tpu.models import transformer as tfm
-    from distributed_pytorch_tpu.lm import (
-        make_lm_1f1b_train_step, make_lm_mesh, make_optimizer as lm_opt)
-
-    model = tfm.TransformerConfig(**_F1B_MODEL_KW)
-    cfg = LMTrainConfig(model=model, pp_size=2, microbatches=4,
-                        overlap=True, compute_dtype=None, **kw)
-    step = make_lm_1f1b_train_step(cfg, make_lm_mesh(cfg))
-    params = tfm.init(jax.random.key(0), model)
-    opt = lm_opt(cfg).init(params)
-    tokens, targets = _data(b=8, s=64, vocab=256)
-    sched = dbg.op_schedule(step, params, opt, jnp.asarray(tokens),
-                            jnp.asarray(targets))
-    stats = dbg.assert_overlap_schedule(sched, axes=("pp",),
-                                        min_interleaved=2, min_bytes=1024)
-    assert stats["total"] >= 2 * step.pp_meta["n_micro"], stats
-
-
-def test_1f1b_dcn_composes_bitwise():
-    """pp x factored-dcn: stages on the outermost 'pp' axis, the
-    (data, dcn) two-level sync unchanged within each stage — bitwise vs
-    single-stage on the same factored mesh, overlap on and off."""
-    kw = dict(dp=2, dcn_size=2)
-    base = _f1b_run(1, **kw)
-    _assert_f1b_bitwise(base, _f1b_run(2, **kw))
-    _assert_f1b_bitwise(base, _f1b_run(2, overlap=True, **kw))
-
-
-def test_1f1b_validation_rejections():
-    """require_pp_schedulable + validate_lm_cfg: every incoherent combo
-    refuses loudly at config time (the round-9 require_* consolidation —
-    lm_cli/bench share these exact checks), and the trainer-surface
-    mismatches raise too."""
-    from distributed_pytorch_tpu.lm import validate_lm_cfg
-    from distributed_pytorch_tpu.models import transformer as tfm
-
-    model = tfm.TransformerConfig(**_F1B_MODEL_KW)
-
-    def cfg(**kw):
-        return LMTrainConfig(model=model, compute_dtype=None, **kw)
-
-    # stage count must divide the layer stack into contiguous chunks
-    with pytest.raises(ValueError, match="does not[\\s\\S]*divide"):
-        validate_lm_cfg(cfg(pp_size=3))
-    # fewer in-flight microbatches than stages: never leaves fill/drain
-    with pytest.raises(ValueError, match="microbatches"):
-        validate_lm_cfg(cfg(pp_size=4, microbatches=2))
-    # one pipeline scheduler at a time
-    with pytest.raises(ValueError, match="one, not both"):
-        validate_lm_cfg(cfg(pp_size=2, pp=2))
-    # the dedicated expert axis does not compose
-    with pytest.raises(ValueError, match="expert"):
-        validate_lm_cfg(cfg(pp_size=2, ep=2))
-    # overlap + pp_size is legal WITHOUT fsdp/dcn (the chunk syncs are
-    # the streamable cluster) — must not raise
-    validate_lm_cfg(cfg(pp_size=2, overlap=True))
-    # grad_accum composes with pp_size (unlike the wave scheduler's pp)
-    validate_lm_cfg(cfg(pp_size=2, grad_accum=2))
-    # K-step scan keeps its layout restriction
-    toks, tgts = _data(b=8, s=64, vocab=256)
-    with pytest.raises(ValueError, match="pp"):
-        LMTrainer(cfg(pp_size=2, microbatches=4)).train_steps(
             toks[None], tgts[None])

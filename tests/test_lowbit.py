@@ -523,7 +523,7 @@ def test_lm_matmul_dtype_refusals():
             model=tfm.TransformerConfig(vocab_size=128, d_model=128,
                                         n_layers=4, n_heads=2,
                                         head_dim=64, d_ff=256),
-            dp=2, dcn_size=2, pp_size=2, matmul_dtype="int8"))
+            dp=2, pp=2, matmul_dtype="int8"))
 
 
 # -- the autotuner's quantize-compute-aware chooser -------------------------
