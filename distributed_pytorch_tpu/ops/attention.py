@@ -9,11 +9,12 @@ transformer, built MXU-first:
   einsum).  O(S^2) memory — the oracle the kernel is tested against, and the
   building block of the pure-JAX ring attention (parallel/context.py).
 - ``flash_attention``: Pallas TPU kernel, online-softmax tiling so the S x S
-  score matrix never materializes in HBM; custom VJP with the standard
-  recompute backward (dQ kernel + dK/dV kernel).  Default blocks are
-  512 (q) x 1024 (k) from v5e sweeps, auto-shrunk to the largest 8-aligned
-  divisor of the sequence length; scores/accumulators are f32, inputs may
-  be bf16.
+  score matrix never materializes in HBM; custom VJP with a recompute
+  backward: one kernel that rebuilds a tile's scores once and takes dQ, dK
+  and dV from them, while one sequence's dQ fits VMEM (``_bwd_fuses``),
+  else a dQ kernel and a dK/dV kernel.  Default blocks are 1024 (q) x
+  1024 (k), auto-shrunk to the largest 8-aligned divisor of the sequence
+  length; scores/accumulators are f32, inputs may be bf16.
 
 Shapes follow the (batch, heads, seq, head_dim) convention.
 """
@@ -33,11 +34,15 @@ from ..utils import compat
 
 Array = jax.Array
 
-# Defaults from block-size sweeps on v5e (fwd+bwd at S=1024..8192, plus
-# the end-to-end LM train step): the largest tile wins or ties everywhere
-# measured — grid overhead dominates before VMEM pressure does at these
-# shapes (1024x1024 beat 512x1024 by 9-26% fwd+bwd).  Small block_q
-# (256) with a large grid is pathological in the dK/dV kernel — avoid.
+# The block defaults come from sweeps on a v5e and a JAX this repo no longer
+# has (largest tile won or tied, forward + backward at S=1024..8192;
+# 1024x1024 over 512x1024 by 9-26%; block_q 256 pathological in dK/dV): a
+# claim, not re-measured.  What the chip this repo has says (TPU v5 lite,
+# JAX 0.9.0, PR 31, PERF.md section 6): at these blocks one layer's forward +
+# backward alone, bf16 causal, takes 4.29 ms at (32, 4096, 128) with the
+# fused backward against 5.58 ms with the dQ and dK/dV kernels, 13.14 against
+# 17.43 ms at (28, 8192, 128), 10.72 against 14.03 ms there under a
+# 4,096-key window; the forward is 1.54 / 4.72 / 3.88 ms of each.
 # Short sequences auto-shrink via _fit_block.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
@@ -56,6 +61,9 @@ def _interpret_default() -> bool:
 # A kernel may use 16 MiB of a v5e core's 128 MiB of VMEM unless it asks
 # for more (the compiler's "scoped vmem limit").
 _SCOPED_VMEM_DEFAULT = 16 * 2**20
+# What the fused backward may keep of one whole sequence in VMEM: the budget
+# of ``_bwd_fuses``.
+_FUSED_BWD_VMEM_BUDGET = 16 * 2**20
 
 
 def _decode_compiler_params(q: Array, cache: Array, block_k: int,
@@ -286,7 +294,9 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret,
 
 
 # ---------------------------------------------------------------------------
-# Flash attention: backward kernels (recompute p from q,k + saved lse)
+# Flash attention: backward kernels (recompute p from q,k + saved lse).
+# ``_bwd`` runs the fused one where a sequence's dQ fits VMEM, else the two
+# that follow it; all three do the same arithmetic in the same order.
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -334,17 +344,31 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, sm_scale: float, causal: bool,
-                    block_q: int, block_k: int, window: int | None = None):
-    """Grid (BH, num_k, num_q), q innermost: accumulate dK/dV for one k block."""
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
+                    causal: bool, block_q: int, block_k: int,
+                    window: int | None = None, dq_ref=None, dq_acc=None):
+    """Grid (BH, num_k, num_q), q innermost: accumulate dK/dV for one k
+    block and, given ``dq_ref`` / ``dq_acc``, dQ of the whole sequence: the
+    backward of one (q block i, k block j) tile from one recomputation of
+    its scores.
+
+    dK and dV belong to the k block the inner dimension holds fixed.  dQ of
+    q block i gathers over the outer dimension, so it is summed into rows
+    ``i * block_q`` onwards of a float32 scratch that spans the sequence,
+    in the same order over j as ``_bwd_dq_kernel`` sums it, and is cast and
+    written when the sweep of this BH ends."""
     j, i = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    nk, nq = pl.num_programs(1), pl.num_programs(2)
 
     @pl.when(i == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if dq_acc is not None:
+        @pl.when((j == 0) & (i == 0))
+        def _init_dq():
+            dq_acc[:] = jnp.zeros_like(dq_acc)
 
     live = (i * block_q + block_q - 1 >= j * block_k) if causal else (i >= 0)
     if window is not None:
@@ -352,9 +376,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0]
+        q, k = q_ref[0], k_ref[0]
         s = jax.lax.dot_general(
-            q, k_ref[0], (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         if causal:
             s = _causal_mask(s, i, j, block_q, block_k, window)
@@ -367,15 +391,78 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dp = jax.lax.dot_general(
             do.astype(v_ref.dtype), v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bq, bk)
-        ds = p * (dp - delta) * sm_scale               # (bq, bk)
+        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bk, d)
+        if dq_acc is not None:
+            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # (bq, d)
 
     @pl.when(i == nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    if dq_acc is not None:
+        @pl.when((j == nk - 1) & (i == nq - 1))
+        def _finalize_dq():
+            dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                      **static):
+    """dQ, dK and dV of a tile from one recomputation of its scores: five
+    products and one exp pass, where ``_bwd_dq_kernel`` and
+    ``_bwd_dkv_kernel`` together spend seven and two."""
+    _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_acc, dv_acc, dq_ref=dq_ref,
+                    dq_acc=dq_acc, **static)
+
+
+def _lanes(d: int) -> int:
+    """A row of ``d`` elements as VMEM holds it: whole 128-lane tiles."""
+    return -(-d // 128) * 128
+
+
+def _whole_sequence_bytes(sq: int, d: int, dtype) -> int:
+    """VMEM the fused backward holds for one sequence's dQ: the float32
+    accumulator and the double-buffered output block."""
+    return sq * _lanes(d) * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _bwd_fuses(sq: int, sk: int, d: int, dtype) -> bool:
+    """The backward's plan, read off its shapes: one fused kernel while what
+    it keeps of a whole sequence fits ``_FUSED_BWD_VMEM_BUDGET`` (8 MiB at
+    8,192 queries of 128 in bf16), else the dQ and dK/dV kernels, whose VMEM
+    does not grow with the sequence (ring attention over long local chunks).
+    ``sk`` is part of the plan's key and moves nothing today: the k side is
+    tiled."""
+    del sk
+    return _whole_sequence_bytes(sq, d, dtype) <= _FUSED_BWD_VMEM_BUDGET
+
+
+def _fused_bwd_compiler_params(sq: int, d: int, block_q: int, block_k: int,
+                               dtype):
+    """VMEM request of the fused backward, or None when the default covers
+    it: the whole-sequence dQ buffers, the double-buffered tiles (q, do, k,
+    v in; dk, dv out; lse and delta rows), the two (block_k, d) float32
+    accumulators and two (block_q, block_k) float32 temporaries of the
+    body.  The chip's compiler keeps about one (chip-less compiles, blocks
+    of 1024: the least limit it accepts is 11.9 MiB at 4,096 keys of 128 in
+    bf16, 16.03 at 8,192, 23.9 at 16,384, 23.6 at 8,192 in float32; this
+    comes to 16.1 / 20.1 / 28.1 / 27.1)."""
+    item = jnp.dtype(dtype).itemsize
+    tiles = 2 * ((2 * block_q + 4 * block_k) * _lanes(d) * item
+                 + 2 * 8 * block_q * 4)
+    need = (_whole_sequence_bytes(sq, d, dtype) + tiles
+            + 2 * block_k * _lanes(d) * 4 + 2 * block_q * block_k * 4)
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
 def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
@@ -385,11 +472,10 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
     sk = k.shape[1]
     nq, nk = sq // block_q, sk // block_k
     vma = _vma(q, k, v, o, do, lse)
-    kv_index = _kv_index(block_q, block_k, window)
     if window is None:
         def q_block(j, i):
             return i
-    else:   # the dK/dV grid's q tiles, held inside k block j's band
+    else:   # the (BH, num_k, num_q) grid's q tiles, held inside k block j's band
         def q_block(j, i):
             return jnp.clip(i, *_band_q_blocks(j, block_q, block_k, window,
                                                nq))
@@ -403,9 +489,49 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
         vma = vma | _vma(dlse)
     delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, sq))
 
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, window=window)
+    # tiles of the (BH, num_k, num_q) grid: the fused and the dK/dV kernel
+    kq_in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, q_block(j, i), 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, q_block(j, i), 0)),
+        pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, q_block(j, i))),
+        pl.BlockSpec((1, 8, block_q), lambda b, j, i: (b, 0, q_block(j, i))),
+    ]
+    dkv_specs = [
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+        pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+    ]
+    dkv_shapes = [
+        compat.shape_struct((bh, sk, d), k.dtype, vma=vma),
+        compat.shape_struct((bh, sk, d), v.dtype, vma=vma),
+    ]
+    dkv_scratch = [
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d), jnp.float32),
+    ]
+    dq_shape = compat.shape_struct((bh, sq, d), q.dtype, vma=vma)
+
+    if _bwd_fuses(sq, sk, d, q.dtype):
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_fused_kernel, **static),
+            grid=(bh, nk, nq),
+            in_specs=kq_in_specs,
+            out_specs=[pl.BlockSpec((1, sq, d), lambda b, j, i: (b, 0, 0)),
+                       *dkv_specs],
+            out_shape=[dq_shape, *dkv_shapes],
+            scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32), *dkv_scratch],
+            compiler_params=_fused_bwd_compiler_params(
+                sq, d, block_q, block_k, q.dtype),
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
+        return dq, dk, dv
+
+    kv_index = _kv_index(block_q, block_k, window)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, window=window),
+        functools.partial(_bwd_dq_kernel, **static),
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -416,39 +542,17 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
             pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=compat.shape_struct((bh, sq, d), q.dtype, vma=vma),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, window=window),
+        functools.partial(_bwd_dkv_kernel, **static),
         grid=(bh, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d),
-                         lambda b, j, i: (b, q_block(j, i), 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d),
-                         lambda b, j, i: (b, q_block(j, i), 0)),
-            pl.BlockSpec((1, 8, block_q),
-                         lambda b, j, i: (b, 0, q_block(j, i))),
-            pl.BlockSpec((1, 8, block_q),
-                         lambda b, j, i: (b, 0, q_block(j, i))),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            compat.shape_struct((bh, sk, d), k.dtype, vma=vma),
-            compat.shape_struct((bh, sk, d), v.dtype, vma=vma),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        in_specs=kq_in_specs,
+        out_specs=dkv_specs,
+        out_shape=dkv_shapes,
+        scratch_shapes=dkv_scratch,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -768,7 +872,7 @@ def flash_attention(
     merge needs (the lse cotangent is handled exactly in the backward).
 
     ``window`` (causal only): a query sees the ``window`` newest keys up to
-    and including its own position (key > query - window).  The three
+    and including its own position (key > query - window).  The
     kernels skip the blocks that lie wholly before the band as they skip
     those after the diagonal, compute and copy both, and mask inside the
     blocks the band's edge crosses.  A window that covers the sequence is

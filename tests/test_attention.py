@@ -5,6 +5,8 @@ path that compiles on TPU (tests/conftest.py pins the cpu backend).  The
 oracle is ``attention_reference``, plain XLA attention.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -275,3 +277,89 @@ def test_paged_decode_matches_dense():
                                       jnp.asarray(table), pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# The backward's plan: one fused kernel, or dQ and dK/dV apart
+# ---------------------------------------------------------------------------
+
+def _backward(case, dtype):
+    """Gradients of a loss over ``flash_attention``'s outputs, ``case``
+    naming the keyword arguments and the sequence lengths."""
+    kw, sq, sk = case
+    key = jax.random.key(7)
+    q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, sq, D), dtype)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, H, sk, D), dtype)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, H, sk, D), dtype)
+
+    def loss(q, k, v):
+        out = attn.flash_attention(q, k, v, block_q=128, block_k=128, **kw)
+        if kw.get("with_lse"):   # a non-zero lse cotangent
+            return (jnp.sum(jnp.sin(out[0].astype(jnp.float32)))
+                    + jnp.sum(jnp.cos(out[1])))
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+BACKWARD_CASES = {
+    "noncausal": ({}, 512, 512),
+    "causal": ({"causal": True}, 512, 512),
+    # the band's far edge falls inside a block: keys 200 behind, blocks of 128
+    "window_across_blocks": ({"causal": True, "window": 200}, 512, 512),
+    "rectangular": ({}, 256, 640),
+    "with_lse": ({"causal": True, "with_lse": True}, 512, 512),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(BACKWARD_CASES))
+def test_fused_backward_equals_the_two_kernels_bit_for_bit(
+        monkeypatch, case, dtype):
+    """One recomputation of a tile's scores feeds dQ, dK and dV; the sums
+    run in the order the dQ and the dK/dV kernels run them, so nothing
+    differs, not by a rounding."""
+    shapes = BACKWARD_CASES[case]
+    assert attn._bwd_fuses(shapes[1], shapes[2], D, dtype)
+    fused = _backward(shapes, dtype)
+    monkeypatch.setattr(attn, "_FUSED_BWD_VMEM_BUDGET", 0)
+    assert not attn._bwd_fuses(shapes[1], shapes[2], D, dtype)
+    apart = _backward(shapes, dtype)
+    for a, b in zip(fused, apart):
+        assert a.dtype == b.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_backward_plan_follows_the_shape(monkeypatch):
+    """Fused at both training cells' attention, the two kernels once the
+    whole-sequence dQ buffers pass the budget (ring attention's long local
+    chunks); and past the budget the gradients are still the reference's."""
+    assert attn._bwd_fuses(4096, 4096, 128, jnp.bfloat16)
+    assert attn._bwd_fuses(8192, 8192, 128, jnp.bfloat16)
+    assert not attn._bwd_fuses(32768, 32768, 128, jnp.bfloat16)
+    assert not attn._bwd_fuses(16384, 16384, 128, jnp.float32)
+
+    q, k, v = _qkv()
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v, causal=True)))
+
+    def flash():    # a new function a call: a trace is kept by its function
+        return jax.grad(loss(functools.partial(
+            attn.flash_attention, block_q=128, block_k=128)),
+            argnums=(0, 1, 2))
+
+    def kernels():
+        return str(jax.make_jaxpr(flash())(q, k, v)).count("pallas_call[")
+
+    assert kernels() == 2           # forward, fused backward
+    monkeypatch.setattr(attn, "_FUSED_BWD_VMEM_BUDGET", 0)
+    assert kernels() == 3           # forward, dQ, dK/dV
+    g_ref = jax.grad(loss(attn.attention_reference),
+                     argnums=(0, 1, 2))(q, k, v)
+    g_fl = flash()(q, k, v)
+    for a, b in zip(g_ref, g_fl):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   atol=1e-4, rtol=1e-4)
