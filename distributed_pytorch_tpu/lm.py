@@ -62,6 +62,22 @@ IGNORE = IGNORE_INDEX  # target id excluded from the loss (padding)
 # routing, 1 with every pick held here).
 MOE_METRICS = ("moe.rows_here", "moe.load_max_over_mean", "moe.dropped",
                "moe.live_tile_share")
+# ... and what follows those four where the model has the mechanism: the mean
+# over tokens and routed layers of the picks' summed sigmoid scores before
+# normalisation (``moe_scoring="sigmoid"``), and the mean over heads, tokens
+# and layers of the attention gate (``attn_gate``: one stuck at 0 or 1 shows).
+_STAT_METRICS = {"score_sum_mean": "moe.score_sum_mean",
+                 "gate_mean": "attn.gate_mean"}
+# how a counter is reduced over the chips of a step
+_STAT_REDUCE = {"rows_here": jax.lax.psum, "dropped": jax.lax.psum,
+                "load_max_over_mean": jax.lax.pmax}
+
+
+def step_metric_names(model: tfm.TransformerConfig) -> tuple[str, ...]:
+    """Names of what a step of ``model`` adds to ``LMTrainer.last_metrics``
+    after [grad-norm, param-norm] (``model.stat_names()``, as telemetry
+    names its gauges): ``MOE_METRICS`` first, for a dropless model."""
+    return tuple(_STAT_METRICS.get(k, "moe." + k) for k in model.stat_names())
 
 
 @dataclass
@@ -510,13 +526,14 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
                 "pp supports MoE only for uniform stacks (moe_every=1, "
                 "every layer MoE); a dense/MoE-alternating stack cannot "
                 "stack into homogeneous pipeline stages")
-        if cfg.tp > 1 and (cfg.model.n_heads % cfg.tp
+        if cfg.tp > 1 and (any(h % cfg.tp for h in cfg.model.head_counts())
                            or cfg.model.kv_heads % cfg.tp):
             raise ValueError(f"heads must divide over tp={cfg.tp}")
     elif cfg.tp > 1:
-        if cfg.model.n_heads % cfg.tp:
-            raise ValueError(f"n_heads {cfg.model.n_heads} must divide over "
-                             f"tp={cfg.tp}")
+        for h in sorted(cfg.model.head_counts()):
+            if h % cfg.tp:
+                raise ValueError(f"n_heads {h} must divide over "
+                                 f"tp={cfg.tp}")
         if cfg.model.kv_heads % cfg.tp:
             raise ValueError(
                 f"n_kv_heads {cfg.model.kv_heads} must divide over "
@@ -1262,14 +1279,12 @@ def _build_local_loss(cfg: LMTrainConfig, specs, *, dcn_sync: bool,
         aux = jax.lax.pmean(aux, reduce_axes)  # pmean'd over MODEL
         loss = ce_sum / jnp.maximum(n_total, 1) + aux_w * aux
         if cfg.model.moe_dropless:
-            # the routed layers' counters of the whole step, beside the
-            # loss (value_and_grad's aux): MOE_METRICS' order
+            # the layers' counters of the whole step, beside the loss
+            # (value_and_grad's aux): step_metric_names' order
             st = out[2]
             return loss, jnp.stack([
-                jax.lax.psum(st["rows_here"], reduce_axes),
-                jax.lax.pmax(st["load_max_over_mean"], reduce_axes),
-                jax.lax.psum(st["dropped"], reduce_axes),
-                jax.lax.pmean(st["live_tile_share"], reduce_axes)])
+                _STAT_REDUCE.get(k, jax.lax.pmean)(st[k], reduce_axes)
+                for k in cfg.model.stat_names()])
         return loss
 
     if stateful:
@@ -2503,8 +2518,7 @@ class LMTrainer:
             telemetry.emit_train_steps(
                 tel, t0, self._step - 1, 1, loss, self.last_ok,
                 self.last_metrics, span_name="lm_train_step", defer=True,
-                extra_gauges=MOE_METRICS if self.cfg.model.moe_dropless
-                else ())
+                extra_gauges=step_metric_names(self.cfg.model))
             self._emit_cache_size(tel, self.step_fn)
         return loss
 

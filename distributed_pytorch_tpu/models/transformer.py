@@ -32,6 +32,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..ops import attention as attn_ops
@@ -40,6 +41,54 @@ from ..parallel import context as ctx
 
 Array = jax.Array
 PyTree = Any
+
+
+@dataclass(frozen=True)
+class RopeSpec:
+    """Rotary settings of one attention kind (``TransformerConfig.
+    rope_by_kind``).  The leading ``rotary_share`` of each head's dimensions
+    rotates, the rest pass through.  ``yarn_factor`` > 1 scales the
+    frequencies as YaRN does: those that turn fewer than ``yarn_beta_slow``
+    times over ``yarn_original_len`` positions are divided by the factor,
+    those that turn more than ``yarn_beta_fast`` times are kept, with a
+    linear ramp between; ``attention_factor`` multiplies cos and sin."""
+    theta: float = 10_000.0
+    rotary_share: float = 1.0
+    yarn_factor: float = 1.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if not 0.0 < self.rotary_share <= 1.0:
+            raise ValueError(f"rotary_share {self.rotary_share} not in (0, 1]")
+        if self.yarn_factor != 1.0 and self.yarn_original_len < 1:
+            raise ValueError("YaRN scaling needs yarn_original_len")
+
+    @property
+    def plain(self) -> bool:
+        """Every pair rotates at ``theta ** (-2j / d)``, unscaled."""
+        return (self.rotary_share == 1.0 and self.yarn_factor == 1.0
+                and self.attention_factor == 1.0)
+
+    def inv_freq(self, head_dim: int) -> np.ndarray:
+        """The rotated pairs' frequencies (float64; as many as rotate)."""
+        rot = int(head_dim * self.rotary_share) // 2 * 2
+        inv = self.theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+        if self.yarn_factor == 1.0:
+            return inv
+
+        def turns_at(beta):     # the pair that turns beta times over the
+            return (rot * math.log(self.yarn_original_len    # original length
+                                   / (beta * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        low = max(math.floor(turns_at(self.yarn_beta_fast)), 0)
+        high = min(math.ceil(turns_at(self.yarn_beta_slow)), rot - 1)
+        ramp = np.clip((np.arange(rot // 2) - low)
+                       / (high - low if high > low else 0.001), 0.0, 1.0)
+        return inv * (1.0 - ramp) + inv / self.yarn_factor * ramp
 
 
 @dataclass(frozen=True)
@@ -97,12 +146,58 @@ class TransformerConfig:
     moe_first_expert: int = 0
     moe_act: str = "silu"
     moe_router_input: str = "mlp_norm"
+    # How the dropless router weighs its picks: 'softmax' over the picks'
+    # logits, or 'sigmoid': scores sigmoid(logits), the top ``moe_top_k`` of
+    # them, normalised over the picks and times ``moe_score_scale``.
+    moe_scoring: str = "softmax"
+    moe_score_scale: float = 1.0
+    # A shared expert beside the routed ones (a SwiGLU of this width that
+    # every token passes through, leaves under the layer's "shared"; 0 =
+    # none), and leading dense layers in a dropless model: the first
+    # ``n_dense_layers`` have a dense SwiGLU MLP of width ``d_ff_dense``
+    # (None = ``d_ff``) in place of the routed layer.
+    moe_shared_ff: int = 0
+    n_dense_layers: int = 0
+    d_ff_dense: int | None = None
+    # What differs by attention kind, as (kind, value) pairs; a kind that is
+    # not named takes ``n_heads`` / plain rotary at ``rope_theta``.
+    heads_by_kind: tuple[tuple[str, int], ...] = ()
+    rope_by_kind: tuple[tuple[str, RopeSpec], ...] = ()
+    # A gate on the attention output: head a's output times sigmoid(h wg)[a],
+    # ``wg`` (d_model, heads) beside wq/wk/wv/wo, read from the normed input.
+    attn_gate: bool = False
 
     def __post_init__(self):
         kv = self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
-        if self.n_heads % kv:
-            raise ValueError(f"n_heads {self.n_heads} not divisible by "
-                             f"n_kv_heads {kv}")
+        for h in self.head_counts():
+            if h % kv:
+                raise ValueError(f"n_heads {h} not divisible by "
+                                 f"n_kv_heads {kv}")
+        for name, pairs in (("heads_by_kind", self.heads_by_kind),
+                            ("rope_by_kind", self.rope_by_kind)):
+            unknown = {k for k, _ in pairs} - set(self.attn_kinds or
+                                                  ("global",))
+            if unknown:
+                raise ValueError(f"{name} names {sorted(unknown)}, which no "
+                                 f"layer of attn_kinds is")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring must be 'softmax' or 'sigmoid', "
+                             f"got {self.moe_scoring!r}")
+        if not self.moe_dropless and (
+                self.moe_scoring != "softmax" or self.moe_score_scale != 1.0
+                or self.moe_shared_ff or self.n_dense_layers
+                or self.d_ff_dense is not None):
+            raise ValueError(
+                "moe_scoring, moe_score_scale, moe_shared_ff, n_dense_layers "
+                "and d_ff_dense belong to the dropless routed model "
+                "(moe_dropless=True)")
+        if self.moe_scoring == "softmax" and self.moe_score_scale != 1.0:
+            raise ValueError("moe_score_scale scales the sigmoid router's "
+                             "normalised scores; a softmax over the picks "
+                             "has none")
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError(f"n_dense_layers {self.n_dense_layers} of "
+                             f"{self.n_layers} layers")
         if self.attn_kinds:
             unknown = set(self.attn_kinds) - set(ATTN_KINDS)
             if unknown or len(self.attn_kinds) != self.n_layers:
@@ -135,16 +230,47 @@ class TransformerConfig:
         return self.d_ff if self.d_ff is not None else 4 * self.d_model
 
     @property
+    def dense_ff(self) -> int:
+        """Width of a dense layer's MLP (the leading dense layers of a
+        dropless model may have one of their own)."""
+        return self.d_ff_dense if self.d_ff_dense is not None else self.ff
+
+    @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
 
     def is_moe_layer(self, i: int) -> bool:
         if self.moe_dropless:
-            return True
+            return i >= self.n_dense_layers
         return self.n_experts > 0 and i % self.moe_every == self.moe_every - 1
 
     def attn_kind(self, i: int) -> str:
         return self.attn_kinds[i] if self.attn_kinds else "global"
+
+    def heads(self, kind: str) -> int:
+        """Query heads of a layer of this attention kind."""
+        return dict(self.heads_by_kind).get(kind, self.n_heads)
+
+    def head_counts(self) -> set[int]:
+        """Every query-head count some layer of the model has."""
+        return {self.heads(k) for k in self.attn_kinds or ("global",)}
+
+    def rope(self, kind: str) -> RopeSpec | None:
+        """The kind's rotary settings where they are not the plain
+        ``rotary(x, pos, rope_theta)``; None where they are."""
+        spec = dict(self.rope_by_kind).get(kind)
+        return None if spec is None or (
+            spec.plain and spec.theta == self.rope_theta) else spec
+
+    def stat_names(self) -> tuple[str, ...]:
+        """The counters a step of this model returns beside its loss
+        (``apply(return_stats=True)``), in the order ``lm`` stacks them."""
+        if not self.moe_dropless:
+            return ()
+        return (moe_ops.STATS
+                + (("score_sum_mean",) if self.moe_scoring == "sigmoid"
+                   else ())
+                + (("gate_mean",) if self.attn_gate else ()))
 
     def training_only(self) -> list[str]:
         """The mechanisms of this configuration that only the training
@@ -161,6 +287,23 @@ class TransformerConfig:
         if self.moe_dropless:
             found.append("the dropless routed layer (moe_dropless=True: no "
                          "grouped product in decode)")
+        if self.moe_scoring != "softmax":
+            found.append(f"a router that scores by {self.moe_scoring} "
+                         "(moe_scoring)")
+        if self.moe_shared_ff:
+            found.append("a shared expert beside the routed ones "
+                         "(moe_shared_ff)")
+        if self.n_dense_layers:
+            found.append("leading dense layers before the routed ones "
+                         "(n_dense_layers)")
+        if self.heads_by_kind:
+            found.append("a head count per attention kind (heads_by_kind: "
+                         "the cache has one)")
+        if any(self.rope(k) is not None for k, _ in self.rope_by_kind):
+            found.append("rotary settings per attention kind (rope_by_kind: "
+                         "partial or YaRN-scaled rotary)")
+        if self.attn_gate:
+            found.append("a gate on the attention output (attn_gate)")
         return found
 
 
@@ -194,27 +337,36 @@ PRESETS = {
 def init(key: Array, cfg: TransformerConfig) -> PyTree:
     """Build the parameter pytree (same-seed construction on every replica,
     the reference's init-parity mechanism — SURVEY.md 2.3)."""
-    d, h, dh, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.ff
+    d, dh, f = cfg.d_model, cfg.head_dim, cfg.ff
     kv = cfg.kv_heads
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32)
                 / math.sqrt(fan_in))
 
+    def swiglu(width):
+        return {"w_gate": dense(next(keys), (d, width), d),
+                "w_up": dense(next(keys), (d, width), d),
+                "w_down": dense(next(keys), (width, d), width)}
+
     keys = iter(jax.random.split(
-        key, 2 + 7 * cfg.n_layers + (not cfg.tie_embeddings)))
+        key, 2 + (7 + cfg.attn_gate + 3 * bool(cfg.moe_shared_ff))
+        * cfg.n_layers + (not cfg.tie_embeddings)))
     params: dict = {
         "embed": jax.random.normal(next(keys), (cfg.vocab_size, d),
                                    jnp.float32) * 0.02,
         "final_norm": jnp.ones((d,), jnp.float32),
     }
     for i in range(cfg.n_layers):
+        h = cfg.heads(cfg.attn_kind(i))
         attn = {
             "wq": dense(next(keys), (d, h, dh), d),
             "wk": dense(next(keys), (d, kv, dh), d),
             "wv": dense(next(keys), (d, kv, dh), d),
             "wo": dense(next(keys), (h, dh, d), h * dh),
         }
+        if cfg.attn_gate:
+            attn["wg"] = dense(next(keys), (d, h), d)
         layer = {"attn_norm": jnp.ones((d,), jnp.float32),
                  **_under_kind(cfg, i, attn),
                  "mlp_norm": jnp.ones((d,), jnp.float32)}
@@ -222,12 +374,10 @@ def init(key: Array, cfg: TransformerConfig) -> PyTree:
             layer["moe"] = moe_ops.moe_init(
                 next(keys), d, f, cfg.n_experts,
                 held=cfg.moe_experts_held if cfg.moe_dropless else None)
+            if cfg.moe_shared_ff:
+                layer["shared"] = swiglu(cfg.moe_shared_ff)
         else:
-            layer.update(
-                w_gate=dense(next(keys), (d, f), d),
-                w_up=dense(next(keys), (d, f), d),
-                w_down=dense(next(keys), (f, d), f),
-            )
+            layer.update(swiglu(cfg.dense_ff))
         params[f"layer{i}"] = layer
     if not cfg.tie_embeddings:
         params["lm_head"] = jax.random.normal(
@@ -257,6 +407,8 @@ def shard_specs(cfg: TransformerConfig, *, tp_axis: str = "model",
     specs: dict = {"embed": P(), "final_norm": P()}
     if not cfg.tie_embeddings:
         specs["lm_head"] = P()
+    swiglu = {"w_gate": P(None, tp_axis), "w_up": P(None, tp_axis),
+              "w_down": P(tp_axis, None)}
     for i in range(cfg.n_layers):
         layer = {
             "attn_norm": P(),
@@ -264,10 +416,13 @@ def shard_specs(cfg: TransformerConfig, *, tp_axis: str = "model",
                 "wq": P(None, tp_axis, None),
                 "wk": P(None, tp_axis, None),
                 "wv": P(None, tp_axis, None),
-                "wo": P(tp_axis, None, None)}),
+                "wo": P(tp_axis, None, None),
+                **({"wg": P(None, tp_axis)} if cfg.attn_gate else {})}),
             "mlp_norm": P(),
         }
         if cfg.is_moe_layer(i):
+            if cfg.moe_shared_ff:
+                layer["shared"] = dict(swiglu)
             # the router is replicated everywhere
             if ep_axis is not None:
                 layer["moe"] = {
@@ -284,8 +439,7 @@ def shard_specs(cfg: TransformerConfig, *, tp_axis: str = "model",
                     "w_down": P(tp_axis, None, None),
                 }
         else:
-            layer.update(w_gate=P(None, tp_axis), w_up=P(None, tp_axis),
-                         w_down=P(tp_axis, None))
+            layer.update(swiglu)
         specs[f"layer{i}"] = layer
     return specs
 
@@ -313,22 +467,41 @@ def rms_norm(x: Array, scale: Array, eps: float) -> Array:
     return (x32 * rms * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary(x: Array, pos: Array, theta: float) -> Array:
+def rotary(x: Array, pos: Array, theta: float,
+           spec: RopeSpec | None = None) -> Array:
     """Rotary position embedding over (B, H, S, D); ``pos`` is (S,) absolute
     positions (a sequence-parallel shard passes its global offsets), or
     (B, S) per-sequence positions (ragged decode — every sequence sits at
-    its own depth)."""
+    its own depth).  ``spec``: an attention kind's own settings in place of
+    the plain ones at ``theta`` (``RopeSpec``: the leading share of the head
+    rotates at its frequencies, the rest passes through)."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (D/2,)
+    if spec is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # (D/2,)
+    else:
+        freqs = jnp.asarray(spec.inv_freq(d), jnp.float32)
+        x, rest = x[..., :2 * len(freqs)], x[..., 2 * len(freqs):]
     angles = pos[..., None].astype(jnp.float32) * freqs  # (S|B,S, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if spec is not None and spec.attention_factor != 1.0:
+        cos, sin = cos * spec.attention_factor, sin * spec.attention_factor
     if pos.ndim == 2:  # (B, S, D/2) -> broadcast over heads
         cos, sin = cos[:, None], sin[:, None]
     x1, x2 = x[..., ::2], x[..., 1::2]
     y1 = x1 * cos - x2 * sin
     y2 = x1 * sin + x2 * cos
     out = jnp.stack([y1, y2], axis=-1).reshape(x.shape)
+    if spec is not None and rest.shape[-1]:
+        return jnp.concatenate([out.astype(x.dtype), rest], axis=-1)
     return out.astype(x.dtype)
+
+
+def _swiglu(p: PyTree, h: Array) -> Array:
+    """``down(silu(gate(h)) * up(h))`` over the leaves w_gate, w_up, w_down
+    of ``p``."""
+    gate = jax.nn.silu(h @ p["w_gate"].astype(h.dtype))
+    up = h @ p["w_up"].astype(h.dtype)
+    return (gate * up) @ p["w_down"].astype(h.dtype)
 
 
 def block(
@@ -374,9 +547,11 @@ def block(
     the MLP recomputes.  ``False`` traces the historical kernel call.
 
     ``kind``: the layer's attention kind (``ATTN_KINDS``: rotary or not,
-    windowed or not; ``cfg.attn_kind(i)``).  ``with_stats``: also return
-    the dropless routed layer's counters (``ops/moe.py``; None for any
-    other layer) as a third result.
+    windowed or not; ``cfg.attn_kind(i)``; its head count and rotary
+    settings are ``cfg.heads(kind)`` and ``cfg.rope(kind)``).
+    ``with_stats``: also return the layer's counters as a third result: the
+    dropless routed layer's (``ops/moe.py``) and, with ``cfg.attn_gate``,
+    ``gate_mean``; None for a layer with neither.
     """
     b, s, d = x.shape
     q8 = matmul_dtype == "int8"
@@ -409,9 +584,9 @@ def block(
         k = jnp.einsum("bsd,dhk->bhsk", h, ap["wk"].astype(h.dtype))
         v = jnp.einsum("bsd,dhk->bhsk", h, ap["wv"].astype(h.dtype))
     if rope:
-        q = rotary(q, pos, cfg.rope_theta)
-        k = rotary(k, pos, cfg.rope_theta)
-    if cfg.kv_heads != cfg.n_heads:
+        q = rotary(q, pos, cfg.rope_theta, cfg.rope(kind))
+        k = rotary(k, pos, cfg.rope_theta, cfg.rope(kind))
+    if cfg.kv_heads != cfg.heads(kind):
         # GQA: q heads share repeated K/V heads (params and decode cache stay
         # kv_heads-sized; the repeat is a view XLA folds into the attention)
         rep = q.shape[1] // k.shape[1]  # local head counts (same under TP)
@@ -429,6 +604,16 @@ def block(
             o = attn_ops.flash_attention(q, k, v, causal=True, window=window)
     else:
         o = attn_ops.attention_reference(q, k, v, causal=True, window=window)
+    stats = None
+    if cfg.attn_gate:
+        # one gate a head and token, read from the layer's normed input
+        gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bhs", h,
+                                         ap["wg"].astype(h.dtype)))
+        o = o * gate[..., None].astype(o.dtype)
+        gate_mean = jnp.mean(gate.astype(jnp.float32))
+        if tp_axis is not None:     # each tensor rank gates its own heads
+            gate_mean = lax.pmean(gate_mean, tp_axis)
+        stats = {"gate_mean": gate_mean}
     if q8:
         of = o.transpose(0, 2, 1, 3).reshape(b * s, -1)
         o = proj2d(of, ap["wo"].reshape(-1, d).astype(o.dtype)
@@ -442,7 +627,6 @@ def block(
     h_attn = h    # what a router placed before attention reads
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    stats = None
     if is_moe and cfg.moe_dropless:
         if ep_axis is not None or (tp_axis is not None
                                    and lax.axis_size(tp_axis) > 1):
@@ -452,11 +636,17 @@ def block(
                 "it with ep=1 and tp=1")
         router_in = (h_attn.reshape(b * s, d)
                      if cfg.moe_router_input == "attn_norm" else None)
-        down, stats = moe_ops.moe_dropless_apply(
+        down, routed = moe_ops.moe_dropless_apply(
             lp["moe"], h.reshape(b * s, d), top_k=cfg.moe_top_k,
             first_expert=cfg.moe_first_expert, router_input=router_in,
-            act=cfg.moe_act)
+            act=cfg.moe_act, scoring=cfg.moe_scoring,
+            score_scale=cfg.moe_score_scale)
+        stats = {**routed, **(stats or {})}
         down = down.reshape(b, s, d)
+        if cfg.moe_shared_ff:
+            # every token passes through the shared expert: a plain SwiGLU
+            # beside the routed call, outside whatever exchange wraps that
+            down = down + _swiglu(lp["shared"], h)
     elif is_moe:
         hf = h.reshape(b * s, d)
         if ep_axis is not None:
@@ -515,9 +705,7 @@ def block(
         down = proj2d(gate * up, lp["w_down"].astype(h.dtype)
                       ).reshape(b, s, d)
     else:
-        gate = jax.nn.silu(h @ lp["w_gate"].astype(h.dtype))
-        up = h @ lp["w_up"].astype(h.dtype)
-        down = (gate * up) @ lp["w_down"].astype(h.dtype)
+        down = _swiglu(lp, h)
     if tp_axis is not None:
         down = lax.psum(down, tp_axis)  # Megatron reduction 2
     if with_stats:
@@ -587,8 +775,9 @@ def apply(
     (``tie_embeddings=False``).
 
     ``return_stats`` (with ``return_aux``): the result is ``(logits, aux,
-    stats)``, ``stats`` the dropless routed layers' counters merged over
-    the layers (``ops/moe.merge_stats``), None for a model without them.
+    stats)``, ``stats`` the layers' counters merged over the layers
+    (``ops/moe.merge_stats``; ``cfg.stat_names()``), None for a model
+    without them.
     """
     if remat not in (None, "none", "full", "selective"):
         raise ValueError(
@@ -629,7 +818,8 @@ def apply(
                                  prevent_cse=False)
         if cfg.moe_dropless:
             x, aux, stats = run(params[f"layer{i}"], x, pos)
-            layer_stats.append(stats)
+            if stats is not None:   # a plain leading dense layer has none
+                layer_stats.append(stats)
         else:
             x, aux = run(params[f"layer{i}"], x, pos)
         aux_total = aux_total + aux

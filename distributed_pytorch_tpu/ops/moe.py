@@ -497,15 +497,21 @@ def _layout(top_idx, first_expert, held):
     return lay, here, sizes
 
 
+# the counters of one dropless layer, in the order ``lm`` reports them
+STATS = ("rows_here", "load_max_over_mean", "dropped", "live_tile_share")
+
+
 def merge_stats(layers: list) -> dict:
-    """The counters of several dropless layers as one set: rows and drops
-    add up, the load figure is the worst layer's, the share of the buffer
-    that was worked over is the layers' mean."""
-    each = {k: jnp.stack([s[k] for s in layers]) for k in layers[0]}
-    return {"rows_here": each["rows_here"].sum(),
-            "dropped": each["dropped"].sum(),
-            "load_max_over_mean": each["load_max_over_mean"].max(),
-            "live_tile_share": each["live_tile_share"].mean()}
+    """The counters of a model's layers as one set, each over the layers
+    that report it (a leading dense layer reports none of the routed
+    ones): rows and drops add up, the load figure is the worst layer's, and
+    the share of the buffer that was worked over, the picks' summed scores
+    and the attention gate are the layers' means."""
+    names = dict.fromkeys(k for s in layers for k in s)
+    each = {k: jnp.stack([s[k] for s in layers if k in s]) for k in names}
+    how = {"rows_here": jnp.sum, "dropped": jnp.sum,
+           "load_max_over_mean": jnp.max}
+    return {k: how.get(k, jnp.mean)(v) for k, v in each.items()}
 
 
 def moe_dropless_apply(
@@ -516,6 +522,8 @@ def moe_dropless_apply(
     first_expert: int = 0,         # index of the first expert held here
     router_input: Array | None = None,   # (T, D) what the router reads
     act: str = "silu",             # the gate branch: 'silu' | 'relu'
+    scoring: str = "softmax",      # the picks' weights: 'softmax' | 'sigmoid'
+    score_scale: float = 1.0,      # 'sigmoid': times the normalised scores
     axis: str | None = None,
 ) -> tuple[Array, dict]:
     """The part of a routed layer's result that the experts held here give:
@@ -524,7 +532,9 @@ def moe_dropless_apply(
     ``params["router"]`` is (D, E) over all E published experts;
     ``params["w_gate" | "w_up" | "w_down"]`` stack the ``held`` experts
     ``first_expert .. first_expert + held - 1``.  Per token: the router's
-    logits in float32, the ``top_k`` largest, a softmax over those k; each
+    logits in float32, the ``top_k`` largest, a softmax over those k (or,
+    with ``scoring="sigmoid"``, the ``top_k`` largest of sigmoid(logits),
+    each over their sum and times ``score_scale``); each
     pick of an expert held here contributes ``weight * down(act(gate(x)) *
     up(x))``.  The picks of held experts are laid out by expert in a row
     buffer, each expert's rows from a tile boundary on (``ROW_TILE``), so
@@ -544,8 +554,10 @@ def moe_dropless_apply(
     ``stats``: ``rows_here`` (picks routed to held experts),
     ``load_max_over_mean`` (largest group over the mean group), ``dropped``
     (picks of held experts that no live row holds: 0) and
-    ``live_tile_share`` (the tiles the loops worked over / the buffer's),
-    float32 scalars of this call.
+    ``live_tile_share`` (the tiles the loops worked over / the buffer's)
+    and, with sigmoid scoring, ``score_sum_mean`` (the picks' scores summed
+    a token, before normalisation, the tokens' mean), float32 scalars of
+    this call.
     """
     if axis is not None:
         raise NotImplementedError(
@@ -554,6 +566,9 @@ def moe_dropless_apply(
             f"moe_apply for expert parallelism over an axis")
     if act not in ("silu", "relu"):
         raise ValueError(f"act must be 'silu' or 'relu', got {act!r}")
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
+                         f"{scoring!r}")
     e = params["router"].shape[-1]
     held = params["w_gate"].shape[0]
     if not 1 <= top_k <= e:
@@ -565,12 +580,17 @@ def moe_dropless_apply(
     logits = jnp.dot(xr.astype(jnp.float32),
                      params["router"].astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)           # (T, E)
-    # the picks' logits read back through a one-hot select, whose backward
+    # the picks' scores read back through a one-hot select, whose backward
     # is a select too (top_k's own is a scatter into (T, E))
-    _, top_idx = lax.top_k(lax.stop_gradient(logits), top_k)    # (T, K)
-    top_logits = jnp.sum(jnp.where(top_idx[..., None] == jnp.arange(e),
-                                   logits[:, None, :], 0), axis=-1)
-    weights = jax.nn.softmax(top_logits, axis=-1)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else logits
+    _, top_idx = lax.top_k(lax.stop_gradient(scores), top_k)    # (T, K)
+    top_scores = jnp.sum(jnp.where(top_idx[..., None] == jnp.arange(e),
+                                   scores[:, None, :], 0), axis=-1)
+    if scoring == "sigmoid":
+        score_sum = jnp.sum(top_scores, axis=-1, keepdims=True)
+        weights = score_scale * top_scores / score_sum
+    else:
+        weights = jax.nn.softmax(top_scores, axis=-1)
 
     lay, here, sizes = _layout(top_idx, first_expert, held)
     n_rows, n_live = lay["pick"].shape[0], lay["n_live"]
@@ -588,4 +608,6 @@ def moe_dropless_apply(
     stats = {"rows_here": rows_here, "dropped": rows_here - reached,
              "load_max_over_mean": jnp.max(sizes) / mean,
              "live_tile_share": (n_live + CHUNK - 1) // CHUNK * CHUNK / n_rows}
+    if scoring == "sigmoid":
+        stats["score_sum_mean"] = jnp.mean(score_sum)
     return out, {k: v.astype(jnp.float32) for k, v in stats.items()}

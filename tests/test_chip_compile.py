@@ -91,6 +91,10 @@ def test_flash_at_the_training_cells_rows_compiles(chip, shape, window):
     pipeline has (``jnp.clip`` of a block index), and the fused backward
     keeps one sequence's dQ in VMEM and asks for what that takes: VMEM and
     tiling as the chip's compiler sees them, two kernels a layer."""
+    _fused_forward_backward_compiles(chip, shape, window)
+
+
+def _fused_forward_backward_compiles(chip, shape, window):
     assert att._bwd_fuses(shape[2], shape[2], D, jnp.bfloat16)
     qkv = (shape, jnp.bfloat16)
 
@@ -101,6 +105,16 @@ def test_flash_at_the_training_cells_rows_compiles(chip, shape, window):
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         qkv, qkv, qkv)
     assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                         ids=["global_48_heads", "window_64_heads"])
+def test_flash_at_two_head_counts_in_one_model_compiles(chip, heads, window):
+    """``laguna_train_8k``'s attention: one 8,192-token row at 48 heads
+    without a window (the global layers) and at 64 heads over a 512-key
+    window, half a block of 1,024 (the windowed layers): the fused backward
+    at both, two kernels a layer."""
+    _fused_forward_backward_compiles(chip, (1, heads, 8192, D), window)
 
 
 def _entry_results(text, shape):
