@@ -14,7 +14,9 @@ transformer, built MXU-first:
   and dV from them, while one sequence's dQ fits VMEM (``_bwd_fuses``),
   else a dQ kernel and a dK/dV kernel.  Default blocks are 1024 (q) x
   1024 (k), auto-shrunk to the largest 8-aligned divisor of the sequence
-  length; scores/accumulators are f32, inputs may be bf16.
+  length; scores/accumulators are f32, inputs may be bf16.  A causal call's
+  tile that lies wholly outside the band (``_tile_live``) costs neither
+  compute nor copy.
 
 Shapes follow the (batch, heads, seq, head_dim) convention.
 """
@@ -23,6 +25,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -138,44 +141,93 @@ def attention_reference(
 
 
 # ---------------------------------------------------------------------------
-# Flash attention: forward kernel
+# Flash attention: a call's tiles, live and dead, then the forward kernel
 # ---------------------------------------------------------------------------
 
-def _causal_mask(s, i, j, block_q: int, block_k: int, window: int | None):
+class _Tiling(NamedTuple):
+    """What a kernel knows of its call's tiles besides their indices."""
+    causal: bool
+    block_q: int
+    block_k: int
+    window: int | None
+
+
+def _causal_mask(s, i, j, tiles: _Tiling):
     """Scores of q block i against k block j with the keys a query may not
-    see at NEG_INF: those after it and, with ``window``, those more than
+    see at NEG_INF: those after it and, with a window, those more than
     ``window - 1`` positions before it."""
-    qi = i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kj = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+    qi = i * tiles.block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    kj = j * tiles.block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(qi >= kj, s, NEG_INF)
-    if window is not None:
-        s = jnp.where(kj > qi - window, s, NEG_INF)
+    if tiles.window is not None:
+        s = jnp.where(kj > qi - tiles.window, s, NEG_INF)
     return s
 
 
-def _in_band(i, j, block_q: int, block_k: int, window: int):
-    """Whether k block j holds a key inside the window of some query of q
-    block i (the causal side is the kernels' own predicate)."""
-    return j * block_k + block_k - 1 > i * block_q - window
+def _tile_live(i, j, tiles: _Tiling):
+    """Whether some query of q block i sees some key of k block j; block
+    indices traced or plain integers.  A causal call's tile that is not,
+    wholly above the diagonal or wholly before the band, is dead: no compute
+    and, by the index maps below, no copy.  The one statement of the band's
+    geometry by tile: the four kernels and ``tile_census`` read it, and
+    ``_live_k_block`` and ``_live_q_block`` are it solved for one index."""
+    if not tiles.causal:
+        # Traced-true rather than literal True: pl.when(True) inlines the
+        # body, and the Pallas HLO interpreter's vma check then rejects
+        # block loads on shard_map-varying inputs (a traced cond keeps CPU
+        # interpret tests working; Mosaic folds it on TPU).
+        return j >= 0
+    q0, k0 = i * tiles.block_q, j * tiles.block_k
+    live = k0 <= q0 + tiles.block_q - 1
+    if tiles.window is not None:
+        live &= k0 + tiles.block_k - 1 > q0 - tiles.window
+    return live
 
 
-def _band_k_blocks(i, block_q: int, block_k: int, window: int):
-    """First and last k block that q block i's band touches."""
-    lo = jnp.maximum(i * block_q - window + 1, 0) // block_k
-    return lo, (i * block_q + block_q - 1) // block_k
+def _live_k_block(i, j, tiles: _Tiling):
+    """k block j, or the live one nearest to it against q block i: the K/V
+    tile a step of the (BH, num_q, num_k) grid names.  A dead step so names
+    the tile the pipeline already holds and its copy is skipped, as the
+    decode kernel skips dead pages."""
+    if not tiles.causal:
+        return j
+    block_q, block_k, window = tiles.block_q, tiles.block_k, tiles.window
+    lo = 0 if window is None else (
+        jnp.maximum(i * block_q - window + 1, 0) // block_k)
+    return jnp.clip(j, lo, (i * block_q + block_q - 1) // block_k)
 
 
-def _band_q_blocks(j, block_q: int, block_k: int, window: int, nq: int):
-    """First and last q block whose band touches k block j."""
-    hi = jnp.minimum((j * block_k + block_k - 2 + window) // block_q, nq - 1)
-    return (j * block_k) // block_q, hi
+def _live_q_block(j, i, tiles: _Tiling, nq: int):
+    """q block i, or the live one nearest to it against k block j: the q,
+    dO, lse and delta tiles a step of the (BH, num_k, num_q) grid names."""
+    if not tiles.causal:
+        return i
+    block_q, block_k, window = tiles.block_q, tiles.block_k, tiles.window
+    hi = nq - 1 if window is None else jnp.minimum(
+        (j * block_k + block_k - 2 + window) // block_q, nq - 1)
+    return jnp.clip(i, (j * block_k) // block_q, hi)
+
+
+class TileCensus(NamedTuple):
+    """Tiles of one (batch x head) slice of a call."""
+    dead: int
+    live: int
+
+
+def tile_census(sq: int, sk: int, block_q: int, block_k: int, causal: bool,
+                window: int | None = None) -> TileCensus:
+    """How many tiles of a ``flash_attention`` call are dead (neither
+    computed nor copied) and live, by the predicate the kernels trace.  A
+    call that is not causal has no dead tile."""
+    tiles = _Tiling(causal, block_q, block_k, window)
+    nq, nk = sq // block_q, sk // block_k
+    live = sum(bool(_tile_live(i, j, tiles))
+               for i in range(nq) for j in range(nk))
+    return TileCensus(nq * nk - live, live)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, sm_scale: float, causal: bool,
-                block_q: int, block_k: int, window: int | None = None):
+                *, sm_scale: float, tiles: _Tiling):
     """Grid (BH, num_q, num_k); the k dimension is innermost/sequential, so
     the VMEM scratch (acc/m/l) carries the online-softmax state across k
     blocks of one q block."""
@@ -188,24 +240,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # Causal: the whole k block is masked iff its first key comes after the
-    # last query of this q block — skip the compute (the grid still visits).
-    # The non-causal predicate is traced-true rather than literal True:
-    # pl.when(True) inlines the body, and the Pallas HLO interpreter's vma
-    # check then rejects block loads on shard_map-varying inputs (a traced
-    # cond keeps CPU interpret tests working; Mosaic folds it on TPU).
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else (j >= 0)
-    if window is not None:   # the band's far side: dead blocks are skipped
-        live &= _in_band(i, j, block_q, block_k, window)
-
-    @pl.when(live)
+    @pl.when(_tile_live(i, j, tiles))
     def _compute():
         q = q_ref[0]  # (block_q, d)
         s = jax.lax.dot_general(
             q, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale  # (bq, bk)
-        if causal:
-            s = _causal_mask(s, i, j, block_q, block_k, window)
+        if tiles.causal:
+            s = _causal_mask(s, i, j, tiles)
         m_prev = m_ref[:, :1]                          # (bq, 1)
         l_prev = l_ref[:, :1]                          # (bq, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)      # (bq, 1)
@@ -242,19 +284,9 @@ def _vma(*arrays):
     return out
 
 
-def _kv_index(block_q: int, block_k: int, window: int | None):
-    """Index map of a K/V tile on the (BH, num_q, num_k) grid.  With a
-    window the k index is held inside q block i's band, so a dead step
-    names the tile the pipeline already holds and its copy is skipped, as
-    the decode kernel skips dead pages."""
-    if window is None:
-        return lambda b, i, j: (b, j, 0)
-
-    def index(b, i, j):
-        lo, hi = _band_k_blocks(i, block_q, block_k, window)
-        return b, jnp.clip(j, lo, hi), 0
-
-    return index
+def _kv_index(tiles: _Tiling):
+    """Index map of a K/V tile on the (BH, num_q, num_k) grid."""
+    return lambda b, i, j: (b, _live_k_block(i, j, tiles), 0)
 
 
 def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret,
@@ -263,10 +295,9 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret,
     sk = k.shape[1]
     nq, nk = sq // block_q, sk // block_k
     vma = _vma(q, k, v)
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, window=window)
-    kv_index = _kv_index(block_q, block_k, window)
+    tiles = _Tiling(causal, block_q, block_k, window)
+    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, tiles=tiles)
+    kv_index = _kv_index(tiles)
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
@@ -300,8 +331,7 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, sm_scale: float, causal: bool,
-                   block_q: int, block_k: int, window: int | None = None):
+                   acc_ref, *, sm_scale: float, tiles: _Tiling):
     """Grid (BH, num_q, num_k), k innermost: accumulate dQ for one q block.
 
     ``delta`` is precomputed outside the kernel as rowsum(do*o) - dlse, so
@@ -315,18 +345,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else (j >= 0)
-    if window is not None:   # the band's far side: dead blocks are skipped
-        live &= _in_band(i, j, block_q, block_k, window)
-
-    @pl.when(live)
+    @pl.when(_tile_live(i, j, tiles))
     def _compute():
         q = q_ref[0]
         s = jax.lax.dot_general(
             q, k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, i, j, block_q, block_k, window)
+        if tiles.causal:
+            s = _causal_mask(s, i, j, tiles)
         p = jnp.exp(s - lse_ref[0, 0][:, None])        # (bq, bk)
         do = do_ref[0].astype(jnp.float32)
         delta = delta_ref[0, 0][:, None]               # (bq, 1)
@@ -345,8 +371,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
-                    causal: bool, block_q: int, block_k: int,
-                    window: int | None = None, dq_ref=None, dq_acc=None):
+                    tiles: _Tiling, dq_ref=None, dq_acc=None):
     """Grid (BH, num_k, num_q), q innermost: accumulate dK/dV for one k
     block and, given ``dq_ref`` / ``dq_acc``, dQ of the whole sequence: the
     backward of one (q block i, k block j) tile from one recomputation of
@@ -370,18 +395,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         def _init_dq():
             dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    live = (i * block_q + block_q - 1 >= j * block_k) if causal else (i >= 0)
-    if window is not None:
-        live &= _in_band(i, j, block_q, block_k, window)
-
-    @pl.when(live)
+    @pl.when(_tile_live(i, j, tiles))
     def _compute():
         q, k = q_ref[0], k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            s = _causal_mask(s, i, j, block_q, block_k, window)
+        if tiles.causal:
+            s = _causal_mask(s, i, j, tiles)
         p = jnp.exp(s - lse_ref[0, 0][:, None])        # (bq, bk)
         do = do_ref[0].astype(jnp.float32)
         delta = delta_ref[0, 0][:, None]               # (bq, 1)
@@ -396,7 +417,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # (bk, d)
         if dq_acc is not None:
-            rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+            rows = pl.ds(pl.multiple_of(i * tiles.block_q, tiles.block_q),
+                         tiles.block_q)
             dq_acc[rows, :] += jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)    # (bq, d)
@@ -453,7 +475,7 @@ def _fused_bwd_compiler_params(sq: int, d: int, block_q: int, block_k: int,
     accumulators and two (block_q, block_k) float32 temporaries of the
     body.  The chip's compiler keeps about one (chip-less compiles, blocks
     of 1024: the least limit it accepts is 11.9 MiB at 4,096 keys of 128 in
-    bf16, 16.03 at 8,192, 23.9 at 16,384, 23.6 at 8,192 in float32; this
+    bf16, 16.9 at 8,192, 23.9 at 16,384, 23.3 at 8,192 in float32; this
     comes to 16.1 / 20.1 / 28.1 / 27.1)."""
     item = jnp.dtype(dtype).itemsize
     tiles = 2 * ((2 * block_q + 4 * block_k) * _lanes(d) * item
@@ -472,13 +494,10 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
     sk = k.shape[1]
     nq, nk = sq // block_q, sk // block_k
     vma = _vma(q, k, v, o, do, lse)
-    if window is None:
-        def q_block(j, i):
-            return i
-    else:   # the (BH, num_k, num_q) grid's q tiles, held inside k block j's band
-        def q_block(j, i):
-            return jnp.clip(i, *_band_q_blocks(j, block_q, block_k, window,
-                                               nq))
+    tiles = _Tiling(causal, block_q, block_k, window)
+
+    def q_block(j, i):
+        return _live_q_block(j, i, tiles, nq)
 
     # delta = rowsum(do*o) - dlse, packed (bh, 8, sq) like lse.  Folding the
     # lse cotangent here is exact: d s from lse is dlse*p, so
@@ -489,8 +508,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
         vma = vma | _vma(dlse)
     delta = jnp.broadcast_to(delta[:, None, :], (bh, 8, sq))
 
-    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, window=window)
+    static = dict(sm_scale=sm_scale, tiles=tiles)
     # tiles of the (BH, num_k, num_q) grid: the fused and the dK/dV kernel
     kq_in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, q_block(j, i), 0)),
@@ -529,7 +547,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 
-    kv_index = _kv_index(block_q, block_k, window)
+    kv_index = _kv_index(tiles)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **static),
         grid=(bh, nq, nk),
@@ -874,9 +892,10 @@ def flash_attention(
     ``window`` (causal only): a query sees the ``window`` newest keys up to
     and including its own position (key > query - window).  The
     kernels skip the blocks that lie wholly before the band as they skip
-    those after the diagonal, compute and copy both, and mask inside the
-    blocks the band's edge crosses.  A window that covers the sequence is
-    plain causal attention; ``None`` traces the kernels without a window.
+    those after the diagonal, compute and copy both (``tile_census`` counts
+    them), and mask inside the blocks the band's edge crosses.  A window
+    that covers the sequence is plain causal attention; ``None`` traces the
+    kernels without a window.
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, H, S, D) q, got {q.shape}")

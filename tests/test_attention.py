@@ -283,14 +283,18 @@ def test_paged_decode_matches_dense():
 # The backward's plan: one fused kernel, or dQ and dK/dV apart
 # ---------------------------------------------------------------------------
 
+def _rectangular_qkv(sq, sk, dtype):
+    key = jax.random.key(7)
+    return tuple(jax.random.normal(jax.random.fold_in(key, i),
+                                   (B, H, s, D), dtype)
+                 for i, s in enumerate((sq, sk, sk)))
+
+
 def _backward(case, dtype):
     """Gradients of a loss over ``flash_attention``'s outputs, ``case``
     naming the keyword arguments and the sequence lengths."""
     kw, sq, sk = case
-    key = jax.random.key(7)
-    q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, sq, D), dtype)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (B, H, sk, D), dtype)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (B, H, sk, D), dtype)
+    q, k, v = _rectangular_qkv(sq, sk, dtype)
 
     def loss(q, k, v):
         out = attn.flash_attention(q, k, v, block_q=128, block_k=128, **kw)
@@ -363,3 +367,86 @@ def test_backward_plan_follows_the_shape(monkeypatch):
     for a, b in zip(g_ref, g_fl):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# A causal call's dead tiles: no compute, and no copy either
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("keys,window,counts", [
+    (4096, None, (6, 10)),          # lm_train_4k / lm_train_dp4
+    (8192, None, (28, 36)),         # the global layers at 8,192 keys
+    (8192, 4096, (34, 30)),         # SmallThinker's windowed layers
+    (8192, 512, (49, 15)),          # Laguna's
+    (8192, 2500, (38, 26)),         # no multiple of the block: 1 + 2 + 3 + 5 x 4
+    (4096, 4096, (6, 10)),          # a window over everything is none
+])
+def test_tile_census_at_the_cells_shapes(keys, window, counts):
+    census = attn.tile_census(keys, keys, 1024, 1024, True, window)
+    assert census == counts and sum(census) == (keys // 1024) ** 2
+    assert attn.tile_census(keys, keys, 1024, 1024, False) == (
+        0, (keys // 1024) ** 2)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 192), (192, 128)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("window", [None, 1, 128, 200, 384, 767])
+def test_the_index_maps_name_the_nearest_live_tile(blocks, window):
+    """``_live_k_block`` and ``_live_q_block`` are ``_tile_live`` solved for
+    one index: a live tile names itself, a dead one the nearest live tile of
+    its row (column) of the grid, at every tile."""
+    s, (bq, bk) = 768, blocks
+    nq, nk = s // bq, s // bk
+    tiles = attn._Tiling(True, bq, bk, window)
+    live = np.array([[attn._tile_live(i, j, tiles) for j in range(nk)]
+                     for i in range(nq)])
+    assert live.any(axis=0).all() and live.any(axis=1).all()
+    for i in range(nq):
+        for j in range(nk):
+            nearest_k = min(np.flatnonzero(live[i]), key=lambda t: abs(t - j))
+            nearest_q = min(np.flatnonzero(live[:, j]),
+                            key=lambda t: abs(t - i))
+            assert int(attn._live_k_block(i, j, tiles)) == nearest_k
+            assert int(attn._live_q_block(j, i, tiles, nq)) == nearest_q
+
+
+DEAD_TILE_CASES = [
+    (plan, window, with_lse)
+    for plan in ("forward", "fused", "two_kernels")
+    for window in (None, 128, 200, 384)
+    for with_lse in (False, True)]
+
+
+@pytest.mark.parametrize(
+    "plan,window,with_lse", DEAD_TILE_CASES,
+    ids=[f"{p}-w{w}-{'lse' if l else 'o'}" for p, w, l in DEAD_TILE_CASES])
+def test_a_dead_step_reads_nothing_of_the_tile_it_names(
+        monkeypatch, plan, window, with_lse):
+    """512 keys in blocks of 128: 6 dead tiles of 16 without a window, 9 / 7
+    / 6 under 128 / 200 / 384.  Outputs, lse and the three gradients equal,
+    bit for bit, those of the same call with every step naming its own tile
+    (the identity index maps, the program before dead steps were held): a
+    live step is handed its own tile and a dead one reads none."""
+    kw = {"causal": True, "window": window, "with_lse": with_lse}
+    dtype = jnp.bfloat16
+    assert attn.tile_census(512, 512, 128, 128, True, window).dead == {
+        None: 6, 128: 9, 200: 7, 384: 6}[window]
+    if plan == "two_kernels":
+        monkeypatch.setattr(attn, "_FUSED_BWD_VMEM_BUDGET", 0)
+
+    def run():      # a new function a call: a trace is kept by its function
+        if plan != "forward":
+            return _backward((kw, 512, 512), dtype)
+        out = attn.flash_attention(*_rectangular_qkv(512, 512, dtype),
+                                   block_q=128, block_k=128, **kw)
+        return out if with_lse else (out,)
+
+    got = run()
+    monkeypatch.setattr(attn, "_live_k_block", lambda i, j, tiles: j)
+    monkeypatch.setattr(attn, "_live_q_block", lambda j, i, tiles, nq: i)
+    want = run()
+    assert len(got) == len(want) == (
+        3 if plan != "forward" else 1 + with_lse)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))

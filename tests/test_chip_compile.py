@@ -91,11 +91,11 @@ def test_flash_at_the_training_cells_rows_compiles(chip, shape, window):
     pipeline has (``jnp.clip`` of a block index), and the fused backward
     keeps one sequence's dQ in VMEM and asks for what that takes: VMEM and
     tiling as the chip's compiler sees them, two kernels a layer."""
-    _fused_forward_backward_compiles(chip, shape, window)
+    _forward_backward_compiles(chip, shape, window)
 
 
-def _fused_forward_backward_compiles(chip, shape, window):
-    assert att._bwd_fuses(shape[2], shape[2], D, jnp.bfloat16)
+def _forward_backward_compiles(chip, shape, window, kernels=2):
+    assert att._bwd_fuses(shape[2], shape[2], D, jnp.bfloat16) == (kernels == 2)
     qkv = (shape, jnp.bfloat16)
 
     def loss(q, k, v):
@@ -104,7 +104,18 @@ def _fused_forward_backward_compiles(chip, shape, window):
 
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         qkv, qkv, qkv)
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.as_text().count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("window", [None, 4096, 512])
+def test_the_two_kernel_backward_compiles_with_dead_steps_held(
+        monkeypatch, chip, window):
+    """Past the fused plan's budget (here: the budget at nought) the dQ and
+    the dK/dV kernel run, the one with K/V and the other with q, dO, lse and
+    delta held on the nearest live tile through a dead step, with a window
+    and without: three kernels a layer."""
+    monkeypatch.setattr(att, "_FUSED_BWD_VMEM_BUDGET", 0)
+    _forward_backward_compiles(chip, (1, 28, 8192, D), window, kernels=3)
 
 
 @pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
@@ -114,7 +125,7 @@ def test_flash_at_two_head_counts_in_one_model_compiles(chip, heads, window):
     without a window (the global layers) and at 64 heads over a 512-key
     window, half a block of 1,024 (the windowed layers): the fused backward
     at both, two kernels a layer."""
-    _fused_forward_backward_compiles(chip, (1, heads, 8192, D), window)
+    _forward_backward_compiles(chip, (1, heads, 8192, D), window)
 
 
 def _entry_results(text, shape):

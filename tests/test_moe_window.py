@@ -377,16 +377,22 @@ def test_a_layer_of_the_wrong_kind_is_caught(bench):
         assert close(got, want, 1e-1) != off
 
 
-# sha256 of the lowered text of the dense model's train step below.  With the
-# backward of flash attention as a dQ and a dK/dV kernel it is the one taken
-# at the parent of the PR that brought windows, kinds, the dropless layer and
-# the untied head (commit 2b1df82): with the new configuration fields at
-# their defaults the program is today's.  PR 31 moved the step the trainer
-# builds, and only by its one fused backward kernel: the second digest is
-# that step's, and with the fused plan's budget at nought the first holds.
+# sha256 of the lowered text of the dense model's train step below, with the
+# backward of flash attention as one fused kernel (the step the trainer
+# builds) and, with the fused plan's budget at nought, as a dQ and a dK/dV
+# kernel.  The digests were first taken at the parent of the PR that brought
+# windows, kinds, the dropless layer and the untied head (commit 2b1df82):
+# with the new configuration fields at their defaults the program is today's.
+# Moved since, each time by ``ops/attention.py`` alone: PR 31, the fused
+# backward kernel (the second digest; the first held with the budget at
+# nought); PR 34, both: a tile's liveness is one predicate
+# (``_tile_live``) where each kernel wrote its own, and the plain causal
+# side's index maps hold a dead step on the nearest live tile (a ``clip``
+# where the identity was).  The kernels' bodies are the parent's
+# (6dce840a... fused, d3f6e824... two kernels).
 DENSE_STEP_TWO_KERNELS = (
-    "d3f6e824bfe5e2d56d0a54313b05d83088220290b3da116f08871e08ab870645")
-DENSE_STEP = "6dce840ad18cf9c989d451acffd0070c7f6670154b56c97c2b00609ad5d1c3e3"
+    "f0484966b2623be9482a873777b420d8781c15c53d8a7633de8f5a1d1af737d4")
+DENSE_STEP = "a679c416db240a75f2797fde1fc1d1d473331ecc10fc8a588c0c3b64345016c3"
 
 
 def test_the_dense_models_step_program_is_unchanged(monkeypatch):

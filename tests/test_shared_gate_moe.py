@@ -355,11 +355,14 @@ def test_the_published_yarn_ramp():
 # -- what stays as it was, and what refuses the new -------------------------------
 
 # sha256 of the lowered text of SmallThinker's tiny train step
-# (``test_moe_window.TINY`` through ``program.build_trainer``), taken at the
-# parent of the PR that brought the mechanisms above (commit a2698a2): with the
-# new configuration fields at their defaults that program is today's, as the
-# dense model's is (``test_moe_window.DENSE_STEP``).
-ROUTED_STEP = "e5035883777f70e263c54456b35dbe1ad52b548afa48dd60d1e451126e6a3133"
+# (``test_moe_window.TINY`` through ``program.build_trainer``), first taken at
+# the parent of the PR that brought the mechanisms above (commit a2698a2): with
+# the new configuration fields at their defaults that program is today's, as
+# the dense model's is (``test_moe_window.DENSE_STEP``).  Moved by PR 34, by
+# ``ops/attention.py`` alone (e5035883... before): the flash kernels read a
+# tile's liveness off one predicate and the global layer's index maps hold a
+# dead step on the nearest live tile; the kernels' bodies are the parent's.
+ROUTED_STEP = "c74d38eb79b320a5b5d501b76f7e3b5078a3ac2cb6188c0f844ae3c8a16dc5bc"
 
 
 def test_the_routed_models_step_program_is_unchanged(bench):
