@@ -66,11 +66,20 @@ MOE_METRICS = ("moe.rows_here", "moe.load_max_over_mean", "moe.dropped",
 # over tokens and routed layers of the picks' summed sigmoid scores before
 # normalisation (``moe_scoring="sigmoid"``), and the mean over heads, tokens
 # and layers of the attention gate (``attn_gate``: one stuck at 0 or 1 shows).
+# Then the shared expert's sigmoid gate (``moe_shared_gate``), and for a
+# model with linear-attention layers the mean of e^g (the decay a token) and
+# of beta over tokens, value heads and linear layers, and the largest
+# Frobenius norm of a state carried out of a chunk (a blow-up shows there
+# first).
 _STAT_METRICS = {"score_sum_mean": "moe.score_sum_mean",
-                 "gate_mean": "attn.gate_mean"}
+                 "gate_mean": "attn.gate_mean",
+                 "shared_gate_mean": "moe.shared_gate_mean",
+                 "decay_mean": "gdn.decay_mean", "beta_mean": "gdn.beta_mean",
+                 "state_norm_max": "gdn.state_norm_max"}
 # how a counter is reduced over the chips of a step
 _STAT_REDUCE = {"rows_here": jax.lax.psum, "dropped": jax.lax.psum,
-                "load_max_over_mean": jax.lax.pmax}
+                "load_max_over_mean": jax.lax.pmax,
+                "state_norm_max": jax.lax.pmax}
 
 
 def step_metric_names(model: tfm.TransformerConfig) -> tuple[str, ...]:
@@ -478,6 +487,16 @@ def validate_lm_cfg(cfg: LMTrainConfig) -> None:
     if "window" in cfg.model.attn_kinds and cfg.sp > 1:
         raise ValueError("windowed attention layers do not compose with "
                          "sp > 1: ring attention has no window")
+    if cfg.model.has_linear:
+        bad = [f"{k}={getattr(cfg, k)}" for k in ("sp", "tp", "ep")
+               if getattr(cfg, k) > 1]
+        if cfg.matmul_dtype is not None:
+            bad.append(f"matmul_dtype={cfg.matmul_dtype!r}")
+        if bad:
+            raise ValueError(
+                "linear-attention layers (attn_kinds 'linear') run with "
+                "sp=1, tp=1, ep=1 and full-precision matmuls: the gated "
+                "delta rule's state has no exchange; got " + ", ".join(bad))
     if cfg.model.moe_dropless:
         # one chip's share, on the plain step: the dropless routed layer
         # has no exchange yet, and its counters ride the plain grad step

@@ -36,6 +36,7 @@ import numpy as np
 from jax import lax
 
 from ..ops import attention as attn_ops
+from ..ops import gated_delta as gd_ops
 from ..ops import moe as moe_ops
 from ..parallel import context as ctx
 
@@ -166,6 +167,30 @@ class TransformerConfig:
     # A gate on the attention output: head a's output times sigmoid(h wg)[a],
     # ``wg`` (d_model, heads) beside wq/wk/wv/wo, read from the normed input.
     attn_gate: bool = False
+    # ... or, with ``attn_gate_form="element"``, one gate a dimension: ``wq``
+    # (d_model, heads, 2 head_dim) projects each head's query and its gate
+    # side by side, and o * sigmoid(gate) is taken element-wise (no ``wg``).
+    attn_gate_form: str = "head"
+    # RMSNorm over each head's dimensions of q and k before rotary (leaves
+    # ``q_norm`` / ``k_norm`` (head_dim,) beside wq/wk).
+    qk_norm: bool = False
+    # Norm scales stored as an offset from this: a norm applies
+    # ``norm_offset + w`` (1.0: zero-centred scales, stored as 0 at init).
+    norm_offset: float = 0.0
+    # A sigmoid gate on the shared expert, one scalar a token:
+    # sigmoid(h w_sg) * shared(h), ``w_sg`` (d_model, 1) under "shared".
+    moe_shared_gate: bool = False
+    # Linear-attention layers (attn_kinds "linear", ops/gated_delta.py): the
+    # gated delta rule over ``linear_v_heads`` value heads of
+    # ``linear_v_dim``, each key head of ``linear_k_dim`` serving
+    # linear_v_heads / linear_k_heads consecutive value heads, after a
+    # causal depthwise convolution of ``linear_conv`` taps over q, k and v;
+    # leaves under "attn_linear" (no wq/wk/wv/wo).
+    linear_k_heads: int = 0
+    linear_v_heads: int = 0
+    linear_k_dim: int = 128
+    linear_v_dim: int = 128
+    linear_conv: int = 4
 
     def __post_init__(self):
         kv = self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
@@ -224,6 +249,21 @@ class TransformerConfig:
         if self.moe_a2a_chunks < 1:
             raise ValueError(
                 f"moe_a2a_chunks must be >= 1, got {self.moe_a2a_chunks}")
+        if self.attn_gate_form not in ("head", "element"):
+            raise ValueError(f"attn_gate_form must be 'head' or 'element', "
+                             f"got {self.attn_gate_form!r}")
+        if self.attn_gate_form != "head" and not self.attn_gate:
+            raise ValueError("attn_gate_form shapes the gate of attn_gate=True")
+        if self.moe_shared_gate and not self.moe_shared_ff:
+            raise ValueError("moe_shared_gate gates the shared expert "
+                             "(moe_shared_ff > 0)")
+        if self.has_linear and not (
+                self.linear_k_heads >= 1
+                and self.linear_v_heads % self.linear_k_heads == 0):
+            raise ValueError(
+                f"linear layers need linear_k_heads >= 1 dividing "
+                f"linear_v_heads, got {self.linear_k_heads} / "
+                f"{self.linear_v_heads}")
 
     @property
     def ff(self) -> int:
@@ -243,6 +283,11 @@ class TransformerConfig:
         if self.moe_dropless:
             return i >= self.n_dense_layers
         return self.n_experts > 0 and i % self.moe_every == self.moe_every - 1
+
+    @property
+    def has_linear(self) -> bool:
+        """Some layer is a linear-attention layer (attn_kinds "linear")."""
+        return "linear" in self.attn_kinds
 
     def attn_kind(self, i: int) -> str:
         return self.attn_kinds[i] if self.attn_kinds else "global"
@@ -270,7 +315,10 @@ class TransformerConfig:
         return (moe_ops.STATS
                 + (("score_sum_mean",) if self.moe_scoring == "sigmoid"
                    else ())
-                + (("gate_mean",) if self.attn_gate else ()))
+                + (("gate_mean",) if self.attn_gate else ())
+                + (("shared_gate_mean",) if self.moe_shared_gate else ())
+                + (("decay_mean", "beta_mean", "state_norm_max")
+                   if self.has_linear else ()))
 
     def training_only(self) -> list[str]:
         """The mechanisms of this configuration that only the training
@@ -304,12 +352,28 @@ class TransformerConfig:
                          "partial or YaRN-scaled rotary)")
         if self.attn_gate:
             found.append("a gate on the attention output (attn_gate)")
+        if self.attn_gate_form != "head":
+            found.append("an element-wise gate on the attention output from "
+                         "the second half of each head's query projection "
+                         "(attn_gate_form 'element')")
+        if self.qk_norm:
+            found.append("QK-norm (qk_norm)")
+        if self.norm_offset:
+            found.append("norm scales stored as an offset (norm_offset)")
+        if self.moe_shared_gate:
+            found.append("a sigmoid gate on the shared expert "
+                         "(moe_shared_gate)")
+        if self.has_linear:
+            found.append("linear-attention layers (attn_kinds 'linear': the "
+                         "gated delta rule's recurrent state and the causal "
+                         "convolution's; no per-slot state in decode)")
         return found
 
 
-# attention kind -> (rotary on q and k, windowed)
+# attention kind -> (rotary on q and k, windowed); "linear" is the gated
+# delta rule (ops/gated_delta.py), no softmax attention at all
 ATTN_KINDS = {"global": (True, False), "global_nope": (False, False),
-              "window": (True, True)}
+              "window": (True, True), "linear": (False, False)}
 
 
 def require_servable(cfg: TransformerConfig, where: str) -> None:
@@ -349,33 +413,45 @@ def init(key: Array, cfg: TransformerConfig) -> PyTree:
                 "w_up": dense(next(keys), (d, width), d),
                 "w_down": dense(next(keys), (width, d), width)}
 
+    def norm(width):    # a norm's scale: 1 as it is applied
+        return jnp.full((width,), 1.0 - cfg.norm_offset, jnp.float32)
+
     keys = iter(jax.random.split(
-        key, 2 + (7 + cfg.attn_gate + 3 * bool(cfg.moe_shared_ff))
-        * cfg.n_layers + (not cfg.tie_embeddings)))
+        key, 2 + (7 + cfg.attn_gate + 3 * bool(cfg.moe_shared_ff)
+                  + cfg.moe_shared_gate) * cfg.n_layers
+        + 5 * cfg.attn_kinds.count("linear") + (not cfg.tie_embeddings)))
     params: dict = {
         "embed": jax.random.normal(next(keys), (cfg.vocab_size, d),
                                    jnp.float32) * 0.02,
-        "final_norm": jnp.ones((d,), jnp.float32),
+        "final_norm": norm(d),
     }
     for i in range(cfg.n_layers):
         h = cfg.heads(cfg.attn_kind(i))
-        attn = {
-            "wq": dense(next(keys), (d, h, dh), d),
-            "wk": dense(next(keys), (d, kv, dh), d),
-            "wv": dense(next(keys), (d, kv, dh), d),
-            "wo": dense(next(keys), (h, dh, d), h * dh),
-        }
-        if cfg.attn_gate:
-            attn["wg"] = dense(next(keys), (d, h), d)
-        layer = {"attn_norm": jnp.ones((d,), jnp.float32),
+        if cfg.attn_kind(i) == "linear":
+            attn = _linear_init(keys, cfg, dense)
+        else:
+            q_width = 2 * dh if cfg.attn_gate_form == "element" else dh
+            attn = {
+                "wq": dense(next(keys), (d, h, q_width), d),
+                "wk": dense(next(keys), (d, kv, dh), d),
+                "wv": dense(next(keys), (d, kv, dh), d),
+                "wo": dense(next(keys), (h, dh, d), h * dh),
+            }
+            if cfg.attn_gate and cfg.attn_gate_form == "head":
+                attn["wg"] = dense(next(keys), (d, h), d)
+            if cfg.qk_norm:
+                attn["q_norm"], attn["k_norm"] = norm(dh), norm(dh)
+        layer = {"attn_norm": norm(d),
                  **_under_kind(cfg, i, attn),
-                 "mlp_norm": jnp.ones((d,), jnp.float32)}
+                 "mlp_norm": norm(d)}
         if cfg.is_moe_layer(i):
             layer["moe"] = moe_ops.moe_init(
                 next(keys), d, f, cfg.n_experts,
                 held=cfg.moe_experts_held if cfg.moe_dropless else None)
             if cfg.moe_shared_ff:
                 layer["shared"] = swiglu(cfg.moe_shared_ff)
+                if cfg.moe_shared_gate:
+                    layer["shared"]["w_sg"] = dense(next(keys), (d, 1), d)
         else:
             layer.update(swiglu(cfg.dense_ff))
         params[f"layer{i}"] = layer
@@ -383,6 +459,33 @@ def init(key: Array, cfg: TransformerConfig) -> PyTree:
         params["lm_head"] = jax.random.normal(
             next(keys), (cfg.vocab_size, d), jnp.float32) * 0.02
     return params
+
+
+def _linear_widths(cfg: TransformerConfig) -> tuple[int, int]:
+    """Channels of a linear layer's q (and of its k), and of its v (and of
+    its output gate z)."""
+    return (cfg.linear_k_heads * cfg.linear_k_dim,
+            cfg.linear_v_heads * cfg.linear_v_dim)
+
+
+def _linear_init(keys, cfg: TransformerConfig, dense) -> dict:
+    """A linear-attention layer's leaves: ``w_qkvz`` (d, [q | k | v | z]),
+    ``w_ba`` (d, [beta logits | decay inputs], one a value head), the
+    convolution's taps over [q | k | v], ``A_log`` and ``dt_bias`` (one a
+    value head: log U(1, 16) and 1), the gated norm's plain scale and
+    ``w_out``."""
+    d, (nk, nv), hv = cfg.d_model, _linear_widths(cfg), cfg.linear_v_heads
+    return {
+        "w_qkvz": dense(next(keys), (d, 2 * nk + 2 * nv), d),
+        "w_ba": dense(next(keys), (d, 2 * hv), d),
+        "conv": dense(next(keys), (cfg.linear_conv, 2 * nk + nv),
+                      cfg.linear_conv),
+        "A_log": jnp.log(jax.random.uniform(next(keys), (hv,), jnp.float32,
+                                            1.0, 16.0)),
+        "dt_bias": jnp.ones((hv,), jnp.float32),
+        "norm": jnp.ones((cfg.linear_v_dim,), jnp.float32),
+        "w_out": dense(next(keys), (nv, d), nv),
+    }
 
 
 def _under_kind(cfg: TransformerConfig, i: int, attn: dict) -> dict:
@@ -410,19 +513,29 @@ def shard_specs(cfg: TransformerConfig, *, tp_axis: str = "model",
     swiglu = {"w_gate": P(None, tp_axis), "w_up": P(None, tp_axis),
               "w_down": P(tp_axis, None)}
     for i in range(cfg.n_layers):
-        layer = {
-            "attn_norm": P(),
-            **_under_kind(cfg, i, {
+        if cfg.attn_kind(i) == "linear":     # replicated: no tensor split
+            attn = dict.fromkeys(("w_qkvz", "w_ba", "conv", "A_log",
+                                  "dt_bias", "norm", "w_out"), P())
+        else:
+            attn = {
                 "wq": P(None, tp_axis, None),
                 "wk": P(None, tp_axis, None),
                 "wv": P(None, tp_axis, None),
                 "wo": P(tp_axis, None, None),
-                **({"wg": P(None, tp_axis)} if cfg.attn_gate else {})}),
+                **({"wg": P(None, tp_axis)}
+                   if cfg.attn_gate and cfg.attn_gate_form == "head"
+                   else {}),
+                **({"q_norm": P(), "k_norm": P()} if cfg.qk_norm else {})}
+        layer = {
+            "attn_norm": P(),
+            **_under_kind(cfg, i, attn),
             "mlp_norm": P(),
         }
         if cfg.is_moe_layer(i):
             if cfg.moe_shared_ff:
                 layer["shared"] = dict(swiglu)
+                if cfg.moe_shared_gate:
+                    layer["shared"]["w_sg"] = P()
             # the router is replicated everywhere
             if ep_axis is not None:
                 layer["moe"] = {
@@ -496,6 +609,126 @@ def rotary(x: Array, pos: Array, theta: float,
     return out.astype(x.dtype)
 
 
+def _softmax_attention(ap: PyTree, h: Array, *, cfg: TransformerConfig,
+                       pos: Array, kind: str, attn_impl: str,
+                       seq_axis: str | None, seq_layout: str,
+                       tp_axis: str | None, proj2d, save_attn: bool,
+                       norm) -> tuple[Array, dict | None]:
+    """A softmax-attention layer's mixer on the normed input ``h`` (B, S, D)
+    up to and including the output projection (``block`` adds the tensor
+    reduction and the residual); ``proj2d``: the int8 projection, or None.
+    Returns the result and the gate's counter (None without a gate)."""
+    b, s, d = h.shape
+    dh = cfg.head_dim
+    rope, windowed = ATTN_KINDS[kind]
+    window = cfg.attn_window if windowed else None
+    if proj2d is not None:
+        hf = h.reshape(b * s, d)
+
+        def head_proj(w):
+            heads, width = w.shape[1], w.shape[2]
+            out = proj2d(hf, w.reshape(d, heads * width).astype(h.dtype))
+            return out.reshape(b, s, heads, width).transpose(0, 2, 1, 3)
+
+        q, k, v = (head_proj(ap["wq"]), head_proj(ap["wk"]),
+                   head_proj(ap["wv"]))
+    else:
+        q = jnp.einsum("bsd,dhk->bhsk", h, ap["wq"].astype(h.dtype))
+        k = jnp.einsum("bsd,dhk->bhsk", h, ap["wk"].astype(h.dtype))
+        v = jnp.einsum("bsd,dhk->bhsk", h, ap["wv"].astype(h.dtype))
+    element_gate = cfg.attn_gate and cfg.attn_gate_form == "element"
+    if element_gate:     # each head's query and its gate, side by side
+        q, q_gate = q[..., :dh], q[..., dh:]
+    if cfg.qk_norm:
+        q, k = norm(q, ap["q_norm"]), norm(k, ap["k_norm"])
+    if rope:
+        q = rotary(q, pos, cfg.rope_theta, cfg.rope(kind))
+        k = rotary(k, pos, cfg.rope_theta, cfg.rope(kind))
+    if cfg.kv_heads != cfg.heads(kind):
+        # GQA: q heads share repeated K/V heads (params and decode cache stay
+        # kv_heads-sized; the repeat is a view XLA folds into the attention)
+        rep = q.shape[1] // k.shape[1]  # local head counts (same under TP)
+        k = jnp.repeat(k, rep, axis=1)
+        v = jnp.repeat(v, rep, axis=1)
+    if seq_axis is not None:
+        o = ctx.ring_attention(
+            q, k, v, seq_axis, causal=True, layout=seq_layout,
+            impl="flash" if attn_impl == "flash" else "reference")
+    elif attn_impl == "flash":
+        if save_attn:
+            o, _ = attn_ops.flash_attention(q, k, v, causal=True,
+                                            with_lse=True, window=window)
+        else:
+            o = attn_ops.flash_attention(q, k, v, causal=True, window=window)
+    else:
+        o = attn_ops.attention_reference(q, k, v, causal=True, window=window)
+    stats = None
+    if element_gate:
+        gate = jax.nn.sigmoid(q_gate.astype(jnp.float32))
+        o = o * gate.astype(o.dtype)
+    elif cfg.attn_gate:
+        # one gate a head and token, read from the layer's normed input
+        gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bhs", h,
+                                         ap["wg"].astype(h.dtype)))
+        o = o * gate[..., None].astype(o.dtype)
+    if cfg.attn_gate:
+        gate_mean = jnp.mean(gate.astype(jnp.float32))
+        if tp_axis is not None:     # each tensor rank gates its own heads
+            gate_mean = lax.pmean(gate_mean, tp_axis)
+        stats = {"gate_mean": gate_mean}
+    if proj2d is not None:
+        of = o.transpose(0, 2, 1, 3).reshape(b * s, -1)
+        return proj2d(of, ap["wo"].reshape(-1, d).astype(o.dtype)
+                      ).reshape(b, s, d), stats
+    return jnp.einsum("bhsk,hkd->bsd", o, ap["wo"].astype(o.dtype)), stats
+
+
+def _l2norm(x: Array, eps: float = 1e-6) -> Array:
+    return x * lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def _gated_delta_mixer(ap: PyTree, h: Array, cfg: TransformerConfig
+                       ) -> tuple[Array, dict]:
+    """A linear-attention layer's mixer on the normed input ``h`` (B, S, D):
+    ``[q, k, v, z] = h w_qkvz``, ``[b, a] = h w_ba``; q, k, v through the
+    causal convolution and silu (float32); q and k l2-normed per key head
+    (q then over sqrt(key dim)), each key head serving its consecutive value
+    heads; ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``
+    in float32; the gated delta rule (ops/gated_delta.py, chunked); per
+    value head ``rms(o) * norm * silu(z)``; then ``w_out``.  Returns the
+    result and the layer's counters."""
+    b, s, _ = h.shape
+    (nk, nv), hv = _linear_widths(cfg), cfg.linear_v_heads
+    dk, dv = cfg.linear_k_dim, cfg.linear_v_dim
+    qkvz = h @ ap["w_qkvz"].astype(h.dtype)
+    ba = (h @ ap["w_ba"].astype(h.dtype)).astype(jnp.float32)
+    # the convolution in float32 (its taps' gradient sums over every token)
+    c = jax.nn.silu(gd_ops.causal_conv(qkvz[..., :2 * nk + nv],
+                                       ap["conv"].astype(jnp.float32))
+                    ).astype(h.dtype)
+
+    def heads(x, width):    # (B, S, n * width) -> (B, S, n, width)
+        return x.reshape(b, s, -1, width)
+
+    def l2(x, scale=1.0):   # in float32, handed on in the step's type
+        return (_l2norm(x.astype(jnp.float32)) * scale).astype(h.dtype)
+
+    rep = hv // cfg.linear_k_heads
+    q = jnp.repeat(l2(heads(c[..., :nk], dk), 1 / math.sqrt(dk)), rep, axis=2)
+    k = jnp.repeat(l2(heads(c[..., nk:2 * nk], dk)), rep, axis=2)
+    v = heads(c[..., 2 * nk:], dv)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(ap["A_log"]) * jax.nn.softplus(ba[..., hv:] + ap["dt_bias"])
+    o, _, state_norm_max = gd_ops.gated_delta_chunked(q, k, v, g, beta)
+    z = heads(qkvz[..., 2 * nk + nv:], dv).astype(jnp.float32)
+    o = rms_norm(o, ap["norm"], cfg.norm_eps) * jax.nn.silu(z)
+    out = o.reshape(b, s, nv).astype(h.dtype) @ ap["w_out"].astype(h.dtype)
+    # counters ride the loss's aux output: no tangent (pmax has no rule)
+    return out, lax.stop_gradient({"decay_mean": jnp.mean(jnp.exp(g)),
+                                   "beta_mean": jnp.mean(beta),
+                                   "state_norm_max": state_norm_max})
+
+
 def _swiglu(p: PyTree, h: Array) -> Array:
     """``down(silu(gate(h)) * up(h))`` over the leaves w_gate, w_up, w_down
     of ``p``."""
@@ -548,16 +781,17 @@ def block(
 
     ``kind``: the layer's attention kind (``ATTN_KINDS``: rotary or not,
     windowed or not; ``cfg.attn_kind(i)``; its head count and rotary
-    settings are ``cfg.heads(kind)`` and ``cfg.rope(kind)``).
+    settings are ``cfg.heads(kind)`` and ``cfg.rope(kind)``); "linear" is
+    the gated delta rule's mixer in place of softmax attention.
     ``with_stats``: also return the layer's counters as a third result: the
-    dropless routed layer's (``ops/moe.py``) and, with ``cfg.attn_gate``,
-    ``gate_mean``; None for a layer with neither.
+    dropless routed layer's (``ops/moe.py``), with ``cfg.attn_gate``
+    ``gate_mean``, with ``cfg.moe_shared_gate`` ``shared_gate_mean``, and a
+    linear layer's ``decay_mean``, ``beta_mean`` and ``state_norm_max``;
+    None for a layer with none of them.
     """
     b, s, d = x.shape
     q8 = matmul_dtype == "int8"
-    rope, windowed = ATTN_KINDS[kind]
-    window = cfg.attn_window if windowed else None
-    if window is not None and seq_axis is not None:
+    if ATTN_KINDS[kind][1] and seq_axis is not None:
         raise NotImplementedError(
             "windowed attention layers under sequence parallelism: ring "
             "attention has no window")
@@ -567,65 +801,34 @@ def block(
         from ..ops import quantized as qz
         return qz.quantized_matmul(h2, w2)
 
+    def norm(y: Array, w: Array) -> Array:
+        return rms_norm(y, w + cfg.norm_offset if cfg.norm_offset else w,
+                        cfg.norm_eps)
+
     # -- attention ---------------------------------------------------------
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    if q8:
-        hf = h.reshape(b * s, d)
-
-        def head_proj(w):
-            heads, dh = w.shape[1], w.shape[2]
-            out = proj2d(hf, w.reshape(d, heads * dh).astype(h.dtype))
-            return out.reshape(b, s, heads, dh).transpose(0, 2, 1, 3)
-
-        q, k, v = (head_proj(ap["wq"]), head_proj(ap["wk"]),
-                   head_proj(ap["wv"]))
+    h = norm(x, lp["attn_norm"])
+    if kind == "linear":
+        if seq_axis is not None or q8 or (tp_axis is not None
+                                          and lax.axis_size(tp_axis) > 1):
+            raise NotImplementedError(
+                "linear-attention layers under sequence or tensor "
+                "parallelism or int8 matmuls: the gated delta rule's state "
+                "has no exchange, its projections no quantized path")
+        # checkpointed: its backward recomputes the mixer's forward rather
+        # than keep its float32 intermediates beside the routed layer's
+        o, stats = jax.checkpoint(_gated_delta_mixer, static_argnums=(2,),
+                                  prevent_cse=False)(ap, h, cfg)
     else:
-        q = jnp.einsum("bsd,dhk->bhsk", h, ap["wq"].astype(h.dtype))
-        k = jnp.einsum("bsd,dhk->bhsk", h, ap["wk"].astype(h.dtype))
-        v = jnp.einsum("bsd,dhk->bhsk", h, ap["wv"].astype(h.dtype))
-    if rope:
-        q = rotary(q, pos, cfg.rope_theta, cfg.rope(kind))
-        k = rotary(k, pos, cfg.rope_theta, cfg.rope(kind))
-    if cfg.kv_heads != cfg.heads(kind):
-        # GQA: q heads share repeated K/V heads (params and decode cache stay
-        # kv_heads-sized; the repeat is a view XLA folds into the attention)
-        rep = q.shape[1] // k.shape[1]  # local head counts (same under TP)
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
-    if seq_axis is not None:
-        o = ctx.ring_attention(
-            q, k, v, seq_axis, causal=True, layout=seq_layout,
-            impl="flash" if attn_impl == "flash" else "reference")
-    elif attn_impl == "flash":
-        if save_attn:
-            o, _ = attn_ops.flash_attention(q, k, v, causal=True,
-                                            with_lse=True, window=window)
-        else:
-            o = attn_ops.flash_attention(q, k, v, causal=True, window=window)
-    else:
-        o = attn_ops.attention_reference(q, k, v, causal=True, window=window)
-    stats = None
-    if cfg.attn_gate:
-        # one gate a head and token, read from the layer's normed input
-        gate = jax.nn.sigmoid(jnp.einsum("bsd,dh->bhs", h,
-                                         ap["wg"].astype(h.dtype)))
-        o = o * gate[..., None].astype(o.dtype)
-        gate_mean = jnp.mean(gate.astype(jnp.float32))
-        if tp_axis is not None:     # each tensor rank gates its own heads
-            gate_mean = lax.pmean(gate_mean, tp_axis)
-        stats = {"gate_mean": gate_mean}
-    if q8:
-        of = o.transpose(0, 2, 1, 3).reshape(b * s, -1)
-        o = proj2d(of, ap["wo"].reshape(-1, d).astype(o.dtype)
-                   ).reshape(b, s, d)
-    else:
-        o = jnp.einsum("bhsk,hkd->bsd", o, ap["wo"].astype(o.dtype))
+        o, stats = _softmax_attention(
+            ap, h, cfg=cfg, pos=pos, kind=kind, attn_impl=attn_impl,
+            seq_axis=seq_axis, seq_layout=seq_layout, tp_axis=tp_axis,
+            proj2d=proj2d if q8 else None, save_attn=save_attn, norm=norm)
     if tp_axis is not None:
         o = lax.psum(o, tp_axis)  # Megatron row-parallel reduction 1
     x = x + o
     # -- MLP ---------------------------------------------------------------
     h_attn = h    # what a router placed before attention reads
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    h = norm(x, lp["mlp_norm"])
     aux = jnp.zeros((), jnp.float32)
     if is_moe and cfg.moe_dropless:
         if ep_axis is not None or (tp_axis is not None
@@ -646,7 +849,13 @@ def block(
         if cfg.moe_shared_ff:
             # every token passes through the shared expert: a plain SwiGLU
             # beside the routed call, outside whatever exchange wraps that
-            down = down + _swiglu(lp["shared"], h)
+            shared = _swiglu(lp["shared"], h)
+            if cfg.moe_shared_gate:     # one sigmoid gate a token
+                sg = jax.nn.sigmoid((h @ lp["shared"]["w_sg"].astype(
+                    h.dtype)).astype(jnp.float32))
+                shared = shared * sg.astype(shared.dtype)
+                stats["shared_gate_mean"] = lax.stop_gradient(jnp.mean(sg))
+            down = down + shared
     elif is_moe:
         hf = h.reshape(b * s, d)
         if ep_axis is not None:
@@ -826,7 +1035,8 @@ def apply(
 
     if boundary is not None:
         params = boundary(cfg.n_layers + 1, params)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    x = rms_norm(x, params["final_norm"] + cfg.norm_offset
+                 if cfg.norm_offset else params["final_norm"], cfg.norm_eps)
     table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     if head_fn is not None:
         out = head_fn(x, table)

@@ -506,11 +506,12 @@ def merge_stats(layers: list) -> dict:
     that report it (a leading dense layer reports none of the routed
     ones): rows and drops add up, the load figure is the worst layer's, and
     the share of the buffer that was worked over, the picks' summed scores
-    and the attention gate are the layers' means."""
+    and the gates are the layers' means; of the linear layers' counters the
+    largest state is the worst layer's, decay and beta the layers' means."""
     names = dict.fromkeys(k for s in layers for k in s)
     each = {k: jnp.stack([s[k] for s in layers if k in s]) for k in names}
     how = {"rows_here": jnp.sum, "dropped": jnp.sum,
-           "load_max_over_mean": jnp.max}
+           "load_max_over_mean": jnp.max, "state_norm_max": jnp.max}
     return {k: how.get(k, jnp.mean)(v) for k, v in each.items()}
 
 

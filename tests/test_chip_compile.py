@@ -128,6 +128,45 @@ def test_flash_at_two_head_counts_in_one_model_compiles(chip, heads, window):
     _forward_backward_compiles(chip, (1, heads, 8192, D), window)
 
 
+def test_flash_at_head_256_compiles_with_the_fused_backward(chip):
+    """``qwen3next_train_8k``'s full-attention layer: one 8,192-token row of
+    16 heads x 256 (K/V repeated from 2).  One sequence's dQ at 256 wide in
+    bfloat16 is 8,192 x 256 x (4 + 2 x 2) B = 16 MiB, the fused plan's
+    budget exactly, so the fused backward runs (asking the compiler for
+    ~33 MiB of VMEM): two kernels a layer."""
+    shape = (1, 16, 8192, 256)
+    assert att._bwd_fuses(8192, 8192, 256, jnp.bfloat16)
+    qkv = (shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return att.flash_attention(q, k, v, causal=True,
+                                   interpret=False).astype(jnp.float32).sum()
+
+    compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
+                        qkv, qkv, qkv)
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+def test_the_chunked_delta_rule_compiles_at_the_cells_shapes(chip):
+    """``qwen3next_train_8k``'s linear layer: the chunked gated delta rule
+    over one 8,192-token row of 32 value heads x 128 (bfloat16 q, k, v;
+    float32 g and beta), forward and backward: the triangular solve and the
+    scan over 128 chunks as the chip's compiler lowers them, no kernel of
+    the program's own."""
+    from distributed_pytorch_tpu.ops import gated_delta as gd
+
+    qkv = ((1, 8192, 32, 128), jnp.bfloat16)
+    gb = ((1, 8192, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        o, _, norm_max = gd.gated_delta_chunked(q, k, v, g, beta)
+        return jnp.sum(o * o) + norm_max
+
+    text = _compile(chip, jax.grad(loss, argnums=range(5)),
+                    qkv, qkv, qkv, gb, gb).as_text()
+    assert "tpu_custom_call" not in text and " while(" in text
+
+
 def _entry_results(text, shape):
     """Opcodes of the entry computation's operations whose result (or an
     element of it) has ``shape``: what runs once a call, outside every loop
