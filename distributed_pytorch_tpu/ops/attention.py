@@ -56,6 +56,26 @@ DEFAULT_BLOCK_K = 1024
 DEFAULT_DECODE_BLOCK_K = 256
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/max() NaN-free
 
+# The name each attention kernel passes to ``pallas_call``.  The compiled
+# program keeps it in the kernel's instruction name, inside the
+# transformations the kernel was traced under (``%jvp_flash_fwd_.1`` and
+# ``%transpose_jvp_flash_bwd__.1`` in a training step), so a device trace
+# tells the kernels apart.  Every forward flash name starts ``flash_fwd``
+# and every backward one ``flash_bwd``; the kernels of a call with a window
+# add ``_window`` (``_flash_name``).
+KERNEL_NAMES = {
+    "fwd": "flash_fwd",
+    "bwd": "flash_bwd",              # the fused backward
+    "bwd_dq": "flash_bwd_dq",        # the two-kernel backward
+    "bwd_dkv": "flash_bwd_dkv",
+    "decode": "decode_attn",
+    "decode_paged": "decode_attn_paged",
+}
+
+
+def _flash_name(kernel: str, window: int | None) -> str:
+    return KERNEL_NAMES[kernel] + ("" if window is None else "_window")
+
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
@@ -320,6 +340,7 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
         ],
         interpret=interpret,
+        name=_flash_name("fwd", window),
     )(q, k, v)
     return o, lse
 
@@ -544,6 +565,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
             compiler_params=_fused_bwd_compiler_params(
                 sq, d, block_q, block_k, q.dtype),
             interpret=interpret,
+            name=_flash_name("bwd", window),
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -563,6 +585,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
         out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=_flash_name("bwd_dq", window),
     )(q, k, v, do, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **static),
@@ -572,6 +595,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, residuals, do,
         out_shape=dkv_shapes,
         scratch_shapes=dkv_scratch,
         interpret=interpret,
+        name=_flash_name("bwd_dkv", window),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -848,6 +872,7 @@ def decode_attention(
         out_shape=compat.shape_struct((b, h, d), q.dtype, vma=vma),
         compiler_params=_decode_compiler_params(qf, k_cache, block_k, quant),
         interpret=interpret,
+        name=KERNEL_NAMES["decode"],
     )(pos_arr, *inputs)
     return o.reshape(b, h, 1, d)
 
@@ -1054,5 +1079,6 @@ def decode_attention_paged(
         out_shape=compat.shape_struct((b, h, d), q.dtype, vma=vma),
         compiler_params=_decode_compiler_params(qf, k_pool, page, quant),
         interpret=interpret,
+        name=KERNEL_NAMES["decode_paged"],
     )(pos_arr, table, *inputs)
     return o.reshape(b, h, 1, d)

@@ -158,6 +158,7 @@ def _bwd_pallas(dr, a, mean, rstd, gamma, beta, *, interpret: bool):
         out_specs=[chan, chan],
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32)] * 2,
         interpret=interpret,
+        name="bn_bwd_sums",
     )(dr, a, mean, rstd, gamma, beta)
     # true per-channel totals: under folding each lane column held only
     # its own rows' partial sum — collapse the fold, then re-tile so the
@@ -172,6 +173,7 @@ def _bwd_pallas(dr, a, mean, rstd, gamma, beta, *, interpret: bool):
         out_specs=row,
         out_shape=jax.ShapeDtypeStruct((m, c), a.dtype),
         interpret=interpret,
+        name="bn_bwd_dx",
     )(dr, a, mean, rstd, gamma, beta,
       jnp.tile(s1, fold)[None], jnp.tile(s2, fold)[None])
     if fold > 1:

@@ -157,6 +157,7 @@ def int8_matmul(x: Array, w: Array, *,
         out_shape=compat.shape_struct((m, n), jnp.float32, vma=vma),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul",
     )(qx, qw, sx, sw)
 
 

@@ -66,6 +66,24 @@ def _compile(chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernels(text):
+    """The compiled Pallas kernels' instruction names without their serial
+    numbers: each kernel's own name (``pallas_call(..., name=...)``) as the
+    name stack leaves it, wrapped in the transformations the kernel was
+    traced under (``jvp(flash_fwd)`` compiles to ``%jvp_flash_fwd_.1``)."""
+    return sorted(
+        re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", line).group(1)
+        for line in text.split("\n") if "tpu_custom_call" in line)
+
+
+def _under_grad(window, *backward):
+    """``_kernels`` of one flash call's forward and ``backward`` kernels
+    under ``jax.grad``."""
+    return sorted([f"jvp_{att._flash_name('fwd', window)}_"]
+                  + [f"transpose_jvp_{att._flash_name(b, window)}__"
+                     for b in backward])
+
+
 def test_flash_forward_backward_compiles(chip):
     qkv = ((B, H, SEQ, D), jnp.bfloat16)
 
@@ -75,8 +93,10 @@ def test_flash_forward_backward_compiles(chip):
 
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         qkv, qkv, qkv)
-    # the forward kernel and the fused backward
+    # the forward kernel and the fused backward, each under its own name
     assert compiled.as_text().count("tpu_custom_call") == 2
+    assert _kernels(compiled.as_text()) == [
+        "jvp_flash_fwd_", "transpose_jvp_flash_bwd__"]
 
 
 @pytest.mark.parametrize("window", [None, 4096])
@@ -105,6 +125,10 @@ def _forward_backward_compiles(chip, shape, window, kernels=2):
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         qkv, qkv, qkv)
     assert compiled.as_text().count("tpu_custom_call") == kernels
+    # a window that covers the sequence is none
+    named = None if window is None or window >= shape[2] else window
+    assert _kernels(compiled.as_text()) == _under_grad(
+        named, *(["bwd"] if kernels == 2 else ["bwd_dq", "bwd_dkv"]))
 
 
 @pytest.mark.parametrize("window", [None, 4096, 512])
@@ -145,6 +169,7 @@ def test_flash_at_head_256_compiles_with_the_fused_backward(chip):
     compiled = _compile(chip, jax.grad(loss, argnums=(0, 1, 2)),
                         qkv, qkv, qkv)
     assert compiled.as_text().count("tpu_custom_call") == 2
+    assert _kernels(compiled.as_text()) == _under_grad(None, "bwd")
 
 
 def test_the_chunked_delta_rule_compiles_at_the_cells_shapes(chip):
@@ -267,6 +292,8 @@ def test_decode_attention_compiles(chip, case):
                     q, k, v, t, p, interpret=False),
                 q, pool, pool, table, pos)
     assert compiled.as_text().count("tpu_custom_call") == 1
+    assert _kernels(compiled.as_text()) == [att.KERNEL_NAMES[
+        "decode" if case.startswith("dense") else "decode_paged"]]
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
@@ -275,6 +302,7 @@ def test_int8_matmul_compiles(chip):
         chip, lambda x, w: quantized.int8_matmul(x, w, interpret=False),
         ((8192, 2048), jnp.bfloat16), ((2048, 8192), jnp.bfloat16))
     assert compiled.as_text().count("tpu_custom_call") == 1
+    assert _kernels(compiled.as_text()) == ["int8_matmul"]
 
 
 # the chat cell's server (benchmarks/deployments/paged_64x4096.json) at
