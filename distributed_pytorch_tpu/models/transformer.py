@@ -694,7 +694,7 @@ def _gated_delta_mixer(ap: PyTree, h: Array, cfg: TransformerConfig
     causal convolution and silu (float32); q and k l2-normed per key head
     (q then over sqrt(key dim)), each key head serving its consecutive value
     heads; ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a + dt_bias)``
-    in float32; the gated delta rule (ops/gated_delta.py, chunked); per
+    in float32; the gated delta rule (ops/gated_delta.py, its kernels); per
     value head ``rms(o) * norm * silu(z)``; then ``w_out``.  Returns the
     result and the layer's counters."""
     b, s, _ = h.shape
@@ -713,13 +713,12 @@ def _gated_delta_mixer(ap: PyTree, h: Array, cfg: TransformerConfig
     def l2(x, scale=1.0):   # in float32, handed on in the step's type
         return (_l2norm(x.astype(jnp.float32)) * scale).astype(h.dtype)
 
-    rep = hv // cfg.linear_k_heads
-    q = jnp.repeat(l2(heads(c[..., :nk], dk), 1 / math.sqrt(dk)), rep, axis=2)
-    k = jnp.repeat(l2(heads(c[..., nk:2 * nk], dk)), rep, axis=2)
+    q = l2(heads(c[..., :nk], dk), 1 / math.sqrt(dk))
+    k = l2(heads(c[..., nk:2 * nk], dk))
     v = heads(c[..., 2 * nk:], dv)
     beta = jax.nn.sigmoid(ba[..., :hv])
     g = -jnp.exp(ap["A_log"]) * jax.nn.softplus(ba[..., hv:] + ap["dt_bias"])
-    o, _, state_norm_max = gd_ops.gated_delta_chunked(q, k, v, g, beta)
+    o, _, state_norm_max = gd_ops.gated_delta(q, k, v, g, beta)
     z = heads(qkvz[..., 2 * nk + nv:], dv).astype(jnp.float32)
     o = rms_norm(o, ap["norm"], cfg.norm_eps) * jax.nn.silu(z)
     out = o.reshape(b, s, nv).astype(h.dtype) @ ap["w_out"].astype(h.dtype)

@@ -192,6 +192,29 @@ def test_the_chunked_delta_rule_compiles_at_the_cells_shapes(chip):
     assert "tpu_custom_call" not in text and " while(" in text
 
 
+def test_the_delta_rule_kernels_compile_at_the_cells_shapes(chip):
+    """The same rule as the training step runs it: the forward and the
+    backward kernel, each under its own name, over the row layout's blocks
+    of 64 positions, q and k at 16 key heads and v at 32 value heads; no
+    loop is left of the rule (the chunk scan lives in the kernels' grids)
+    and VMEM and tiling are as the chip's compiler sees them."""
+    from distributed_pytorch_tpu.ops import gated_delta as gd
+
+    qk = ((1, 8192, 16, 128), jnp.bfloat16)
+    v = ((1, 8192, 32, 128), jnp.bfloat16)
+    gb = ((1, 8192, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        o, _, norm_max = gd.gated_delta(q, k, v, g, beta, interpret=False)
+        return jnp.sum(o * o) + norm_max
+
+    text = _compile(chip, jax.grad(loss, argnums=range(5)),
+                    qk, qk, v, gb, gb).as_text()
+    assert _kernels(text) == ["jvp_gated_delta_fwd_",
+                              "transpose_jvp_gated_delta_bwd__"]
+    assert " while(" not in text
+
+
 def _entry_results(text, shape):
     """Opcodes of the entry computation's operations whose result (or an
     element of it) has ``shape``: what runs once a call, outside every loop

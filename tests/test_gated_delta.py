@@ -116,6 +116,131 @@ def test_the_long_memory_case_lives_on_the_carried_state(monkeypatch):
                      [:, 64:66], 1e-3)
 
 
+@pytest.mark.parametrize("setting", ["shared", "upstream", "long"])
+@pytest.mark.parametrize("t", [128, 150])
+def test_the_kernels_are_the_recurrence(setting, t):
+    """The two kernels (interpret mode) against the recurrence token by
+    token: output, final state, the largest state norm and every input's
+    gradient, through the output and the final state alike."""
+    args = rule_inputs(setting, t)
+    w = jax.random.normal(jax.random.key(9), (1, 3, 16, 8))
+
+    def through_both(fn):
+        def loss(*a):
+            o, s = fn(*a)[:2]
+            return summed(lambda *_: (o,))() + jnp.sum(s * w)
+        return loss
+
+    with jax.default_matmul_precision("highest"):
+        want, want_s = gd.gated_delta_recurrent(*args)
+        got, got_s, norm_max = gd.gated_delta(*args)
+        assert got.shape == want.shape and close(got, want)
+        assert close(got_s, want_s)
+        _, chunked_s, chunked_max = gd.gated_delta_chunked(*args)
+        assert abs(float(norm_max) - float(chunked_max)) <= 1e-5 * float(
+            chunked_max)
+        for g, w_ in zip(
+                jax.grad(through_both(gd.gated_delta), range(5))(*args),
+                jax.grad(through_both(gd.gated_delta_recurrent), range(5))(
+                    *args)):
+            assert close(g, w_, 1e-4)
+
+
+def test_the_kernels_long_memory_lives_on_the_carried_state(monkeypatch):
+    """Under a long memory the forward kernel with the state entering each
+    chunk forced to zero is far from the recurrence after the first
+    chunk."""
+    args = rule_inputs("long", 192)
+    want, _ = gd.gated_delta_recurrent(*args)
+    assert close(gd.gated_delta(*args)[0], want)
+    kernel = gd._fwd_kernel
+
+    def cut(q, k, v, g, b, o, s_out, *rest):
+        s_out[...] = jnp.zeros_like(s_out)
+        kernel(q, k, v, g, b, o, s_out, *rest)
+
+    monkeypatch.setattr(gd, "_fwd_kernel", cut)
+    got = gd.gated_delta(*args)[0]
+    assert close(got[:, :64], want[:, :64])
+    assert not close(got[:, 64:], want[:, 64:], 0.1)
+
+
+def test_the_kernels_at_the_cells_widths_are_the_chunked_form():
+    """Key and value heads of 128, each of 8 key heads serving two of 16
+    value heads (the kernels take 8 value heads a pass in float32, so the
+    key heads are read and their gradients summed a pass at a time), three
+    chunks: the kernels, handed q and k at the key heads' count, against
+    the XLA chunked form of q and k repeated, output, final state, the
+    largest state norm and every input's gradient."""
+    q, k, v, g, beta = rule_inputs("upstream", 192, h=8, dk=128, dv=128)
+    v = jnp.concatenate([v, -v[..., ::-1]], axis=2)
+    g, beta = (jnp.concatenate([x, x[:, ::-1]], axis=2) for x in (g, beta))
+    args = q, k, v, g, beta
+
+    def repeated(q, k, *rest):
+        return gd.gated_delta_chunked(jnp.repeat(q, 2, axis=2),
+                                      jnp.repeat(k, 2, axis=2), *rest)
+
+    with jax.default_matmul_precision("highest"):
+        for a, b in zip(gd.gated_delta(*args), repeated(*args)):
+            assert close(a, b)
+        for a, b in zip(
+                jax.grad(summed(gd.gated_delta, 128), range(5))(*args),
+                jax.grad(summed(repeated, 128), range(5))(*args)):
+            assert a.shape == b.shape and close(a, b, 1e-4)
+
+
+def test_the_kernels_inverse_holds_where_a_chunks_keys_are_alike():
+    """The inverse of ``I + A`` the kernels take, against float64: where
+    every key of a chunk is one key (beta 0.99, no decay) or keys share
+    most of their direction, the powers of A reach 1e13, and a sum of them
+    (the unipotent product) would cancel its own digits away; the inverse
+    from blocks along the diagonal is as close as a float32 solve."""
+    rng = np.random.default_rng(0)
+    base = rng.normal(size=128)
+    keys = [np.tile(base, (64, 1)),
+            base + 0.3 * rng.normal(size=(64, 128)),
+            rng.normal(size=(64, 128))]
+    a = []
+    for k in keys:
+        k = k / np.linalg.norm(k, axis=1, keepdims=True)
+        a.append(np.tril(0.99 * (k @ k.T), -1))
+    a = np.stack(a).astype(np.float32)
+    assert np.abs(np.linalg.matrix_power(a[0].astype(np.float64), 16)
+                  ).max() > 1e12
+    got = np.asarray(gd._unit_lower_inverse(jnp.asarray(a)), np.float64)
+    want = np.linalg.inv(np.eye(64) + a.astype(np.float64))
+    for g, w in zip(got, want):
+        assert np.abs(g - w).max() <= 1e-6 * np.abs(w).max()
+
+
+def test_a_bfloat16_operand_against_the_state_keeps_float32s_precision():
+    """The kernels' products at float32's precision where one operand is
+    bfloat16 (the WY factor ``w`` against the state, ``Q e^G`` against a
+    cotangent): the float32 operand in three bfloat16 pieces gives the
+    product to float32's rounding, either operand first; one bfloat16
+    pass would be a thousand times further off."""
+    rng = np.random.default_rng(0)
+    a = jnp.asarray(rng.normal(size=(4, 64, 128)), jnp.bfloat16)
+    s = jnp.asarray(10 * rng.normal(size=(4, 128, 128)), jnp.float32)
+    want = np.einsum("hik,hkj->hij", np.asarray(a, np.float64),
+                     np.asarray(s, np.float64))
+    scale = np.abs(want).max()
+    for got in (gd._mm(a, s, 2, 1), jnp.swapaxes(gd._mm(s, a, 1, 2), 1, 2)):
+        assert np.abs(np.asarray(got, np.float64) - want).max() < 1e-6 * scale
+    one_pass = np.asarray(gd._mm1(a, s, 2, 1, jnp.bfloat16), np.float64)
+    assert np.abs(one_pass - want).max() > 1e-4 * scale
+
+
+def test_the_kernels_refuse_widths_the_chip_cannot_tile():
+    args = rule_inputs("shared", 64)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        gd.gated_delta(*args, interpret=False)
+    q, k, v, g, beta = args
+    with pytest.raises(ValueError, match="3 value heads cannot share 2"):
+        gd.gated_delta(q[:, :, :2], k[:, :, :2], v, g, beta)
+
+
 def test_causal_conv_by_hand():
     """Two channels, three taps: position t reads t-2, t-1, t with zeros
     before 0, the last tap on the current position."""

@@ -22,12 +22,12 @@ import pytest
 
 import distributed_pytorch_tpu
 from distributed_pytorch_tpu.ops import attention as att
-from distributed_pytorch_tpu.ops import fused_bn, quantized
+from distributed_pytorch_tpu.ops import fused_bn, gated_delta, quantized
 
 PACKAGE = pathlib.Path(distributed_pytorch_tpu.__file__).parent
 
 # kernel -> its name: the call sites of ops/attention.py (with and without a
-# window), ops/fused_bn.py and ops/quantized.py
+# window), ops/fused_bn.py, ops/quantized.py and ops/gated_delta.py
 NAMES = {
     "flash forward": "flash_fwd",
     "flash forward, window": "flash_fwd_window",
@@ -42,6 +42,8 @@ NAMES = {
     "batch norm backward, sums": "bn_bwd_sums",
     "batch norm backward, dx": "bn_bwd_dx",
     "int8 matmul": "int8_matmul",
+    "gated delta forward": "gated_delta_fwd",
+    "gated delta backward": "gated_delta_bwd",
 }
 
 
@@ -62,8 +64,9 @@ def pallas_calls():
 def test_every_pallas_call_in_the_source_passes_a_name():
     calls = pallas_calls()
     assert {f for f, _, _ in calls} == {
-        "ops/attention.py", "ops/fused_bn.py", "ops/quantized.py"}
-    assert len(calls) == 9        # 6 attention, 2 batch norm, 1 int8
+        "ops/attention.py", "ops/fused_bn.py", "ops/quantized.py",
+        "ops/gated_delta.py"}
+    assert len(calls) == 11   # 6 attention, 2 batch norm, 1 int8, 2 delta
     unnamed = [(f, line) for f, line, kw in calls if "name" not in kw]
     assert not unnamed
 
@@ -138,6 +141,12 @@ def traced_names(monkeypatch) -> dict:
     got["int8 matmul"] = kernel_names(
         lambda x, w: quantized.int8_matmul(x, w, interpret=True),
         jnp.ones((64, 256), jnp.float32), jnp.ones((256, 256), jnp.float32))
+    qkv = jnp.ones((1, 64, 2, 128), jnp.float32)
+    gb = jnp.ones((1, 64, 2), jnp.float32)
+    fwd, bwd = kernel_names(jax.grad(
+        lambda *a: gated_delta.gated_delta(*a, interpret=True)[0].sum(),
+        argnums=range(5)), qkv, qkv, qkv, gb, gb)
+    got["gated delta forward"], got["gated delta backward"] = [fwd], [bwd]
     return got
 
 
